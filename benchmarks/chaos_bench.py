@@ -521,6 +521,8 @@ def run(smoke: bool = False, json_out: str | None = None):
 
 
 def main():
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     run(smoke="--smoke" in argv, json_out=flag_value(argv, "--json"))
 

@@ -364,6 +364,8 @@ def run(batch: int = 2, autotune: bool = True,
 
 
 def main():
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     run(json_out=flag_value(sys.argv[1:], "--json"))
 
 

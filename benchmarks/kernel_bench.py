@@ -170,6 +170,8 @@ def run(json_out: str | None = None):
 
 
 def main():
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.obs import flag_value
     run(json_out=flag_value(sys.argv[1:], "--json"))
 

@@ -114,6 +114,8 @@ def main() -> None:
     if "--compare-ledger" in sys.argv:
         _compare_main(sys.argv)     # light path: no benchmark imports
         return
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     t0 = time.time()
     from benchmarks import fig6_utilization, kernel_bench, roofline, \
         table2_comparison

@@ -192,6 +192,8 @@ def run(smoke: bool = False, trace_path: str | None = None,
 
 
 def main():
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     run(smoke="--smoke" in argv,
         trace_path=flag_value(argv, "--trace"),

@@ -78,12 +78,13 @@ def quantize_act(x, *, keep_fp: bool = False, bits: int = 8) -> QTensor:
     return QTensor(q, scale, x if keep_fp else None)
 
 
-def quantize_tensor(x, axis=None, bits: int = 8):
-    """Symmetric quantization.  axis=None -> per-tensor scale."""
+def quantize_tensor(x, axis=None, bits: int = 8, keepdims: bool = False):
+    """Symmetric quantization.  axis=None -> per-tensor scale (a scalar,
+    or size-1 dims of ``x``'s rank with ``keepdims``)."""
     qmax = 2 ** (bits - 1) - 1
     xf = x.astype(jnp.float32)
     if axis is None:
-        absmax = jnp.max(jnp.abs(xf))
+        absmax = jnp.max(jnp.abs(xf), keepdims=keepdims)
     else:
         red = tuple(i for i in range(x.ndim) if i != axis % x.ndim)
         absmax = jnp.max(jnp.abs(xf), axis=red, keepdims=True)

@@ -117,7 +117,7 @@ def data_parallel_specs(mesh: Mesh, params, *, batch_axis: str = "batch"):
     EfficientViT at serving batch sizes is activation-bound, so the
     serving mesh shards only the batch axis: every param is replicated
     on every device, activations split along ``batch_axis``.  Returns
-    ``(param_specs, act_spec)`` ready for ``compat.shard_map``'s
+    ``(param_specs, act_spec)`` ready for ``jax.shard_map``'s
     in/out specs.  Built through the same rule machinery as the LLM
     meshes (an empty rule set — everything falls through to replicated)
     so a future tensor-parallel vision mesh only adds rules here.

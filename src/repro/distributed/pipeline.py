@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
-
 
 def pipelined_apply(stage_fn: Callable, stage_params, x_micro, *,
                     mesh, pipe_axis: str = "pod", extra_specs=P(),
@@ -85,11 +83,11 @@ def pipelined_apply(stage_fn: Callable, stage_params, x_micro, *,
 
     stage_specs = jax.tree_util.tree_map(
         lambda _: P(pipe_axis), stage_params)
-    return shard_map(
+    manual = {} if manual_axes is None else {"axis_names": set(manual_axes)}
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(stage_specs, extra_specs),
-        out_specs=extra_specs, check_vma=False,
-        axis_names=set(manual_axes) if manual_axes is not None else None,
+        out_specs=extra_specs, check_vma=False, **manual,
     )(stage_params, x_micro)
 
 

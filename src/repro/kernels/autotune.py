@@ -17,6 +17,9 @@ Inside a ``jax.jit`` trace there is nothing to time, so callers that may
 be under tracing pass ``bench=None`` and get the cached choice or the
 first (heuristic-default) candidate.  ``repro.core.fusion.build_plan``
 tunes ahead of time, outside jit, which is where the sweeps actually run.
+A sweep in which every candidate fails raises on a compiled backend (the
+chip's compiler refused every tile) and falls back to the default only
+in the interpreter.
 
 The module also owns ``pad_to_multiple`` — the supported way to handle
 ragged shapes.  Kernels used to silently fall back to one full-tensor
@@ -34,6 +37,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.compat import default_interpret
 
 __all__ = ["autotune", "shape_key", "pad_to_multiple", "tile_work",
            "cache_path", "clear_memory_cache", "set_fault_hook",
@@ -195,7 +200,8 @@ def _time_once(fn: Callable[[], object], reps: int = 3) -> float:
 
 
 def autotune(kind: str, key: Sequence, candidates: Sequence[dict],
-             bench: Callable[[dict], object] | None = None) -> dict:
+             bench: Callable[[dict], object] | None = None, *,
+             interpret: bool | None = None) -> dict:
     """Pick the fastest candidate block config for (kind, key).
 
     kind:       kernel family, e.g. "relu_attn" / "mbconv" / "int8_matmul"
@@ -204,9 +210,17 @@ def autotune(kind: str, key: Sequence, candidates: Sequence[dict],
     bench:      callable(candidate) -> result; timed via block_until_ready.
                 None (e.g. under jit tracing) -> cached choice or
                 candidates[0] without sweeping.
+    interpret:  whether ``bench`` runs the Pallas interpreter; None
+                resolves it from the backend (``default_interpret``), so
+                an omitted flag on a TPU never selects the quiet path.
 
-    A candidate whose bench raises is disqualified, so candidate lists can
-    include tiles that exceed VMEM for some shapes.
+    A candidate whose bench raises is disqualified with a warning that
+    names it and its exception, so candidate lists can include tiles
+    that exceed VMEM for some shapes.  When every candidate fails, the
+    interpreter falls back to ``candidates[0]`` (uncached); a compiled
+    backend raises ``PlanError`` instead: there the failures are the
+    chip's compiler refusing every tile, which a default would only
+    hide until the first launch.
     """
     global SWEEP_COUNT
     assert candidates, "autotune needs at least one candidate"
@@ -223,15 +237,23 @@ def autotune(kind: str, key: Sequence, candidates: Sequence[dict],
 
     SWEEP_COUNT += 1
     best_t, best_c = float("inf"), None
+    failures = []
     for cand in candidates:
         try:
             t = _time_once(lambda: bench(cand))
-        except Exception:
+        except Exception as e:
+            failures.append(f"{cand}: {e!r}")
+            warnings.warn(f"autotune {ck}: candidate {cand} disqualified: "
+                          f"{e!r}", RuntimeWarning, stacklevel=2)
             continue
         if t < best_t:
             best_t, best_c = t, dict(cand)
-    if best_c is None:       # every candidate failed: fall back, don't cache
-        return dict(candidates[0])
+    if best_c is None:
+        if not default_interpret(interpret):
+            from repro.common.errors import PlanError
+            raise PlanError(f"autotune {ck}: every candidate failed on the "
+                            f"compiled backend: " + "; ".join(failures))
+        return dict(candidates[0])   # interpreter: fall back, don't cache
     _MEM[ck] = best_c
     _save_disk(path)
     return dict(best_c)

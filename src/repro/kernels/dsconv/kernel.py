@@ -23,26 +23,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compat import default_interpret, tpu_compiler_params
-from repro.kernels.quant import requantize_i8, xs_per_batch
+from repro.kernels.quant import int8_dot, requantize_i8, xs_per_batch
+from repro.kernels.taps import dw_taps, fill, tap_scratch
 
 
 def _dsconv_kernel(x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, o_ref,
-                   dw_scratch, *, stride: int, act: bool):
+                   x_scratch, dw_scratch, *, stride: int, act: bool):
     j = pl.program_id(1)
-    Hp, Wp, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    H, W = Hp - 2, Wp - 2
+    H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
     Ho, Wo = H // stride, W // stride
 
     @pl.when(j == 0)
     def _dw():  # VPU stage: depthwise 3x3 + bias (+ Hardswish)
-        x = x_ref[0].astype(jnp.float32)               # (Hp, Wp, C)
-        acc = jnp.zeros((H, W, C), jnp.float32)
-        for dy in range(3):
-            for dx in range(3):
-                acc += x[dy:dy + H, dx:dx + W, :] * dww_ref[dy, dx][None, None, :]
-        acc += dwb_ref[0][None, None, :]
-        if stride > 1:
-            acc = acc[::stride, ::stride, :]
+        fill(x_scratch, x_ref[0], row0=1, col0=1)      # SAME zero pad
+        acc = dw_taps(x_scratch, lambda dy, dx, lo, hi: dww_ref[dy, dx, lo:hi],
+                      rows=Ho, cols=Wo, stride=stride)  # anchored at 0
+        acc += dwb_ref[...][None]
         if act:
             acc = jax.nn.hard_swish(acc)
         dw_scratch[...] = acc.reshape(Ho * Wo, C)
@@ -50,7 +46,7 @@ def _dsconv_kernel(x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, o_ref,
     # MXU stage: pointwise conv over the VMEM-resident DW output
     out = jnp.dot(dw_scratch[...], pww_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
-    out += pwb_ref[0][None, :]
+    out += pwb_ref[...]
     o_ref[0] = out.reshape(Ho, Wo, -1)
 
 
@@ -70,13 +66,12 @@ def dsconv_fused(x, dw_w, dw_b, pw_w, pw_b, *, stride: int = 1,
     pw_b, _ = pad_to_multiple(pw_b, 0, bf)
     Fp = pw_w.shape[1]
     nf = Fp // bf
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_dsconv_kernel, stride=stride, act=act),
         grid=(B, nf),
         in_specs=[
-            pl.BlockSpec((1, H + 2, W + 2, C), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, H, W, C), lambda b, j: (b, 0, 0, 0)),
             pl.BlockSpec((3, 3, C), lambda b, j: (0, 0, 0)),
             pl.BlockSpec((1, C), lambda b, j: (0, 0)),
             pl.BlockSpec((C, bf), lambda b, j: (0, j)),
@@ -84,11 +79,12 @@ def dsconv_fused(x, dw_w, dw_b, pw_w, pw_b, *, stride: int = 1,
         ],
         out_specs=pl.BlockSpec((1, Ho, Wo, bf), lambda b, j: (b, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Fp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((Ho * Wo, C), jnp.float32)],
+        scratch_shapes=[tap_scratch(H + 2, W + 2, C),
+                        pltpu.VMEM((Ho * Wo, C), jnp.float32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xp, dw_w, dw_b.reshape(1, C), pw_w, pw_b.reshape(1, Fp))
+    )(x, dw_w, dw_b.reshape(1, C), pw_w, pw_b.reshape(1, Fp))
     return out[..., :F]
 
 
@@ -96,42 +92,47 @@ def dsconv_fused(x, dw_w, dw_b, pw_w, pw_b, *, stride: int = 1,
 # FIX8 variant: int8 weights, int32 MACs, in-kernel requant before the PW
 # ---------------------------------------------------------------------------
 
+def _int8_dw(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref, x_scratch, *,
+             stride: int, act: bool):
+    """VPU stage for one batch element: int32 depthwise 3x3 over the
+    int8 input (SAME, anchored at ``stride - 1`` like lax.conv's SAME
+    grid), dequant, Hardswish, in-kernel requant.  Returns the int8 DW
+    output (Ho*Wo, C) and its scale."""
+    H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
+    Ho, Wo = H // stride, W // stride
+    fill(x_scratch, x_ref[0], row0=1, col0=1)
+    acc = dw_taps(x_scratch,
+                  lambda dy, dx, lo, hi: dww_ref[dy, dx, lo:hi].astype(
+                      jnp.int32),
+                  rows=Ho, cols=Wo, stride=stride,
+                  row0=stride - 1, col0=stride - 1)
+    y = acc.astype(jnp.float32) * (xs_ref[0] * dws_ref[...])[None] \
+        + dwb_ref[...][None]
+    if act:
+        y = jax.nn.hard_swish(y)
+    return requantize_i8(y.reshape(Ho * Wo, C))
+
+
 def _dsconv_int8_kernel(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref,
                         pww_ref, pws_ref, pwb_ref, o_ref,
-                        dwq_scratch, sdw_scratch, *, stride: int, act: bool):
+                        x_scratch, dwq_scratch, sdw_scratch, *, stride: int,
+                        act: bool):
     j = pl.program_id(1)
-    Hp, Wp, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    H, W = Hp - 2, Wp - 2
+    H, W = x_ref.shape[1], x_ref.shape[2]
     Ho, Wo = H // stride, W // stride
 
     @pl.when(j == 0)
     def _dw_requant():
-        # VPU stage: depthwise 3x3 in int32 over the int8 input block
-        xp = x_ref[0].astype(jnp.int32)
-        acc = jnp.zeros((H, W, C), jnp.int32)
-        for dy in range(3):
-            for dx in range(3):
-                acc += xp[dy:dy + H, dx:dx + W, :] \
-                    * dww_ref[dy, dx].astype(jnp.int32)[None, None, :]
-        y = acc.astype(jnp.float32) * (xs_ref[0, 0] * dws_ref[0])[None, None, :] \
-            + dwb_ref[0][None, None, :]
-        if stride > 1:
-            # SAME anchoring for even H, W: offset stride-1, as in the
-            # int8 mbconv kernel and lax.conv's SAME stride-2 grid
-            y = y[stride - 1::stride, stride - 1::stride, :]
-        if act:
-            y = jax.nn.hard_swish(y)
-        # in-kernel requantization: the DW output stays int8 in scratch
-        dq, s_dw = requantize_i8(y.reshape(Ho * Wo, C))
-        sdw_scratch[0] = s_dw
+        # the DW output stays int8 in scratch
+        dq, s_dw = _int8_dw(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref,
+                            x_scratch, stride=stride, act=act)
+        sdw_scratch[...] = s_dw
         dwq_scratch[...] = dq
 
     # MXU stage: int8 pointwise conv over the requantized scratch
-    acc2 = jax.lax.dot_general(dwq_scratch[...], pww_ref[...],
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    out = acc2.astype(jnp.float32) * (sdw_scratch[0] * pws_ref[0])[None, :] \
-        + pwb_ref[0][None, :]
+    acc2 = int8_dot(dwq_scratch[...], pww_ref[...])
+    out = acc2.astype(jnp.float32) * (sdw_scratch[...] * pws_ref[...]) \
+        + pwb_ref[...]
     o_ref[0] = out.reshape(Ho, Wo, -1)
 
 
@@ -160,15 +161,14 @@ def dsconv_fused_int8(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b, *,
     pw_bp, _ = pad_to_multiple(pw_b.reshape(1, F), 1, bf)
     Fp = pw_q.shape[1]
     nf = Fp // bf
-    xp = jnp.pad(x_q, ((0, 0), (1, 1), (1, 1), (0, 0)))
     xs = xs_per_batch(x_scale, B)
 
     out = pl.pallas_call(
         functools.partial(_dsconv_int8_kernel, stride=stride, act=act),
         grid=(B, nf),
         in_specs=[
-            pl.BlockSpec((1, H + 2, W + 2, C), lambda b, j: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, H, W, C), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((3, 3, C), lambda b, j: (0, 0, 0)),
             pl.BlockSpec((1, C), lambda b, j: (0, 0)),
             pl.BlockSpec((1, C), lambda b, j: (0, 0)),
@@ -179,13 +179,14 @@ def dsconv_fused_int8(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b, *,
         out_specs=pl.BlockSpec((1, Ho, Wo, bf), lambda b, j: (b, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Fp), jnp.float32),
         scratch_shapes=[
+            tap_scratch(H + 2, W + 2, C, jnp.int32),
             pltpu.VMEM((Ho * Wo, C), jnp.int8),
-            pltpu.SMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xp, xs, dw_q, dw_s.reshape(1, C), dw_b.reshape(1, C), pw_q, pw_sp,
+    )(x_q, xs, dw_q, dw_s.reshape(1, C), dw_b.reshape(1, C), pw_q, pw_sp,
       pw_bp)
     return out[..., :F]
 
@@ -199,36 +200,23 @@ def _dsconv_int8_emit_kernel(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref,
                              stride: int, act: bool, keep_fp: bool):
     oq_ref, os_ref = refs[0], refs[1]
     ofp_ref = refs[2] if keep_fp else None
-    Hp, Wp, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    H, W = Hp - 2, Wp - 2
+    x_scratch = refs[-1]
+    H, W = x_ref.shape[1], x_ref.shape[2]
     Ho, Wo = H // stride, W // stride
 
     # VPU stage + in-kernel requant: identical arithmetic to
     # _dsconv_int8_kernel's j == 0 branch
-    xp = x_ref[0].astype(jnp.int32)
-    acc = jnp.zeros((H, W, C), jnp.int32)
-    for dy in range(3):
-        for dx in range(3):
-            acc += xp[dy:dy + H, dx:dx + W, :] \
-                * dww_ref[dy, dx].astype(jnp.int32)[None, None, :]
-    y = acc.astype(jnp.float32) * (xs_ref[0, 0] * dws_ref[0])[None, None, :] \
-        + dwb_ref[0][None, None, :]
-    if stride > 1:
-        y = y[stride - 1::stride, stride - 1::stride, :]
-    if act:
-        y = jax.nn.hard_swish(y)
-    dq, s_dw = requantize_i8(y.reshape(Ho * Wo, C))
+    dq, s_dw = _int8_dw(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref, x_scratch,
+                        stride=stride, act=act)
 
     # MXU stage over the FULL c_out extent, then the act-quant epilogue
-    acc2 = jax.lax.dot_general(dq, pww_ref[...], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    out = acc2.astype(jnp.float32) * (s_dw * pws_ref[0])[None, :] \
-        + pwb_ref[0][None, :]
+    acc2 = int8_dot(dq, pww_ref[...])
+    out = acc2.astype(jnp.float32) * (s_dw * pws_ref[...]) + pwb_ref[...]
     if keep_fp:
         ofp_ref[0] = out.reshape(Ho, Wo, -1)
     q, s_out = requantize_i8(out)
     oq_ref[0] = q.reshape(Ho, Wo, -1)
-    os_ref[0, 0] = s_out
+    os_ref[0] = s_out
 
 
 def dsconv_fused_int8_emit(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b,
@@ -250,13 +238,12 @@ def dsconv_fused_int8_emit(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b,
     assert x_q.dtype == jnp.int8 and pw_q.dtype == jnp.int8
     assert H % stride == 0 and W % stride == 0
     Ho, Wo = H // stride, W // stride
-    xp = jnp.pad(x_q, ((0, 0), (1, 1), (1, 1), (0, 0)))
     xs = xs_per_batch(x_scale, B)
 
     out_shape = [jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.int8),
-                 jax.ShapeDtypeStruct((B, 1), jnp.float32)]
+                 jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)]
     out_specs = [pl.BlockSpec((1, Ho, Wo, F), lambda b: (b, 0, 0, 0)),
-                 pl.BlockSpec((1, 1), lambda b: (b, 0))]
+                 pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0))]
     if keep_fp:
         out_shape.append(jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.float32))
         out_specs.append(pl.BlockSpec((1, Ho, Wo, F), lambda b: (b, 0, 0, 0)))
@@ -266,8 +253,8 @@ def dsconv_fused_int8_emit(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b,
                           keep_fp=keep_fp),
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H + 2, W + 2, C), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
+            pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
             pl.BlockSpec((3, 3, C), lambda b: (0, 0, 0)),
             pl.BlockSpec((1, C), lambda b: (0, 0)),
             pl.BlockSpec((1, C), lambda b: (0, 0)),
@@ -277,10 +264,11 @@ def dsconv_fused_int8_emit(x_q, x_scale, dw_q, dw_s, dw_b, pw_q, pw_s, pw_b,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[tap_scratch(H + 2, W + 2, C, jnp.int32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(xp, xs, dw_q, dw_s.reshape(1, C), dw_b.reshape(1, C), pw_q,
+    )(x_q, xs, dw_q, dw_s.reshape(1, C), dw_b.reshape(1, C), pw_q,
       pw_s.reshape(1, F), pw_b.reshape(1, F))
     if keep_fp:
         return outs[0], outs[1].reshape(B), outs[2]

@@ -17,15 +17,16 @@ import jax.numpy as jnp
 
 from repro.core.quantization import QTensor, fold_bn_into_conv, quantize_act
 from repro.kernels.autotune import autotune, shape_key
-from repro.kernels.compat import default_interpret
+from repro.kernels.compat import VMEM_BUDGET_BYTES, default_interpret
 from repro.kernels.dsconv.kernel import (
     dsconv_fused, dsconv_fused_int8, dsconv_fused_int8_emit)
 from repro.kernels.dsconv.ref import dsconv_int8_ref, dsconv_ref
 from repro.kernels.registry import KernelBase, register
 
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
-
-BLOCK_F_CANDIDATES = ({"block_f": 64}, {"block_f": 128}, {"block_f": 256})
+# c_out tiles: a tile is the lane dim of the weight/output blocks, so
+# Mosaic takes a multiple of 128 (or the whole extent, which the kernel
+# uses whenever F <= block_f)
+BLOCK_F_CANDIDATES = ({"block_f": 128}, {"block_f": 256})
 
 
 def dsconv_vmem_bytes(h: int, w: int, c: int, stride: int = 1, *,
@@ -68,7 +69,8 @@ def tune_block_f(x_shape, f: int, *, stride: int = 1,
             interpret=interpret)
 
     choice = autotune("dsconv", key, BLOCK_F_CANDIDATES,
-                      bench if allow_sweep else None)
+                      bench if allow_sweep else None,
+                      interpret=interpret)
     return choice["block_f"]
 
 

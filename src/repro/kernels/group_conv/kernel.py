@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.compat import default_interpret, tpu_compiler_params
-from repro.kernels.quant import requantize_i8, xs_per_batch
+from repro.kernels.quant import int8_dot, requantize_i8, xs_per_batch
 
 
 def _group_agg_int8_kernel(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref,
@@ -43,17 +43,15 @@ def _group_agg_int8_kernel(x_ref, xs_ref, dww_ref, dws_ref, dwb_ref,
         for dx in range(s):
             acc += xp[dy:dy + H, dx:dx + W, :] \
                 * dww_ref[dy, dx].astype(jnp.int32)[None, None, :]
-    y = acc.astype(jnp.float32) * (xs_ref[0, 0] * dws_ref[0])[None, None, :] \
-        + dwb_ref[0][None, None, :]
+    y = acc.astype(jnp.float32) * (xs_ref[0] * dws_ref[...])[None] \
+        + dwb_ref[...][None]
     # in-kernel requantization (dynamic per batch element, same
     # arithmetic as the reference conv2d_int8 chain at batch 1)
     yq, sy = requantize_i8(y.reshape(H * W, C))
 
     # MXU stage: grouped 1x1 as one dense block-diagonal int8 matmul
-    acc2 = jax.lax.dot_general(yq, pww_ref[...], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    out = acc2.astype(jnp.float32) * (sy * pws_ref[0])[None, :] \
-        + pwb_ref[0][None, :]
+    acc2 = int8_dot(yq, pww_ref[...])
+    out = acc2.astype(jnp.float32) * (sy * pws_ref[...]) + pwb_ref[...]
     o_ref[0] = out.reshape(H, W, -1)
 
 
@@ -83,7 +81,7 @@ def group_agg_int8(x_q, x_scale, dw_q, dw_s, dw_b, pw_dense_q, pw_s, pw_b,
         in_specs=[
             pl.BlockSpec((1, H + 2 * p, W + 2 * p, C),
                          lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
             pl.BlockSpec((s, s, C), lambda b: (0, 0, 0)),
             pl.BlockSpec((1, C), lambda b: (0, 0)),
             pl.BlockSpec((1, C), lambda b: (0, 0)),

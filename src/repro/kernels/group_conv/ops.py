@@ -23,9 +23,8 @@ import jax.numpy as jnp
 
 from repro.core.quantization import QTensor, quantize_act
 from repro.kernels.group_conv.kernel import group_agg_int8, group_agg_int8_ref
+from repro.kernels.compat import VMEM_BUDGET_BYTES
 from repro.kernels.registry import KernelBase, register
-
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 def _block_diag(pw_q):

@@ -20,7 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compat import default_interpret, tpu_compiler_params
-from repro.kernels.quant import requantize_i8, xs_per_batch
+from repro.kernels.quant import int8_dot, requantize_i8, xs_per_batch
 
 
 def _int8_mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref):
@@ -30,9 +30,7 @@ def _int8_mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    acc_ref[...] += int8_dot(x_ref[...], w_ref[...])
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
@@ -66,7 +64,8 @@ def int8_matmul(x_q, w_q, x_scale, w_scale, *, block_m: int = 256,
     w_q, _ = pad_to_multiple(w_q, 1, bn)
     Mp, Kp = x_q.shape
     Np = w_q.shape[1]
-    xs = xs_per_batch(x_scale, M)     # per-ROW scale column here
+    xs = jnp.broadcast_to(                # per-ROW scale column here
+        jnp.asarray(x_scale, jnp.float32).reshape(-1, 1), (M, 1))
     xs, _ = pad_to_multiple(xs, 0, bm)
     ws, _ = pad_to_multiple(
         jnp.asarray(w_scale, jnp.float32).reshape(1, N), 1, bn)
@@ -105,23 +104,20 @@ def _int8_mm_emit_kernel(x_ref, w_ref, xs_ref, ws_ref, b_ref, *refs,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    acc_ref[...] += int8_dot(x_ref[0], w_ref[...])
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _epilogue():
-        o = (acc_ref[...].astype(jnp.float32)
-             * xs_ref[0, 0] * ws_ref[0][None, :])
-        o = o + b_ref[0][None, :]
+        o = acc_ref[...].astype(jnp.float32) * xs_ref[0] * ws_ref[...]
+        o = o + b_ref[...]
         if keep_fp:
-            ofp_ref[...] = o
+            ofp_ref[0] = o
         # act-quant epilogue: the whole row group (= one batch element's
         # tokens) is this grid step's block, so its per-batch absmax is
         # local — quantized before the activation ever leaves VMEM
         q, s = requantize_i8(o)
-        oq_ref[...] = q
-        os_ref[0, 0] = s
+        oq_ref[0] = q
+        os_ref[0] = s
 
 
 def int8_matmul_emit(x_q, w_q, x_scale, w_scale, *, rows_per_group: int,
@@ -135,7 +131,9 @@ def int8_matmul_emit(x_q, w_q, x_scale, w_scale, *, rows_per_group: int,
     extent resident, so the group absmax is computed in-kernel at the
     last K step.  Returns ``(q (M, N) int8, scales (M // rows_per_group,)
     fp32)``, plus the fp output when ``keep_fp``.  ``bias`` (N,) is
-    added before quantization (it is part of the activation).
+    added before quantization (it is part of the activation).  The rows
+    are blocked as ``(groups, rows_per_group, K)`` so a group of any
+    size (B1's 196 or 49 tokens) is a whole-dim block.
     """
     from repro.kernels.autotune import pad_to_multiple
 
@@ -143,7 +141,7 @@ def int8_matmul_emit(x_q, w_q, x_scale, w_scale, *, rows_per_group: int,
     M, K = x_q.shape
     N = w_q.shape[1]
     assert M % rows_per_group == 0, (M, rows_per_group)
-    G = M // rows_per_group
+    G, R = M // rows_per_group, rows_per_group
     bk = min(block_k, K)
     x_q, _ = pad_to_multiple(x_q, 1, bk)
     w_q, _ = pad_to_multiple(w_q, 0, bk)
@@ -153,32 +151,32 @@ def int8_matmul_emit(x_q, w_q, x_scale, w_scale, *, rows_per_group: int,
     b = (jnp.zeros((1, N), jnp.float32) if bias is None
          else jnp.asarray(bias, jnp.float32).reshape(1, N))
 
-    out_shape = [jax.ShapeDtypeStruct((M, N), jnp.int8),
-                 jax.ShapeDtypeStruct((G, 1), jnp.float32)]
-    out_specs = [pl.BlockSpec((rows_per_group, N), lambda i, k: (i, 0)),
-                 pl.BlockSpec((1, 1), lambda i, k: (i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((G, R, N), jnp.int8),
+                 jax.ShapeDtypeStruct((G, 1, 1), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, R, N), lambda i, k: (i, 0, 0)),
+                 pl.BlockSpec((1, 1, 1), lambda i, k: (i, 0, 0))]
     if keep_fp:
-        out_shape.append(jax.ShapeDtypeStruct((M, N), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((rows_per_group, N), lambda i, k: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((G, R, N), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, R, N), lambda i, k: (i, 0, 0)))
 
     outs = pl.pallas_call(
         functools.partial(_int8_mm_emit_kernel, keep_fp=keep_fp),
         grid=(G, Kp // bk),
         in_specs=[
-            pl.BlockSpec((rows_per_group, bk), lambda i, k: (i, k)),
+            pl.BlockSpec((1, R, bk), lambda i, k: (i, 0, k)),
             pl.BlockSpec((bk, N), lambda i, k: (k, 0)),
-            pl.BlockSpec((1, 1), lambda i, k: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, k: (i, 0, 0)),
             pl.BlockSpec((1, N), lambda i, k: (0, 0)),
             pl.BlockSpec((1, N), lambda i, k: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((rows_per_group, N), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((R, N), jnp.int32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x_q, w_q, xs, ws, b)
+    )(x_q.reshape(G, R, Kp), w_q, xs, ws, b)
+    q, scales = outs[0].reshape(M, N), outs[1].reshape(G)
     if keep_fp:
-        return outs[0], outs[1].reshape(G), outs[2]
-    return outs[0], outs[1].reshape(G)
+        return q, scales, outs[2].reshape(M, N)
+    return q, scales

@@ -28,14 +28,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import pad_to_multiple
 from repro.kernels.compat import default_interpret, tpu_compiler_params
-from repro.kernels.quant import requantize_i8, xs_per_batch
+from repro.kernels.quant import int8_dot, requantize_i8, xs_per_batch
+from repro.kernels.taps import dw_taps, fill, tap_scratch
 
 
 def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
                    o_ref, mid_scratch, dw_scratch, *, stride: int):
     j = pl.program_id(1)
     H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    M = mid_scratch.shape[2]
+    M = w1_ref.shape[1]
     Ho, Wo = H // stride, W // stride
 
     @pl.when(j == 0)
@@ -44,25 +45,22 @@ def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
         x = x_ref[0].astype(jnp.float32).reshape(H * W, C)
         mid = jnp.dot(x, w1_ref[...].astype(jnp.float32),
                       preferred_element_type=jnp.float32)
-        mid = jax.nn.hard_swish(mid + b1_ref[0][None, :])
-        mid_scratch[...] = jnp.zeros((H + 2, W + 2, M), jnp.float32)
-        mid_scratch[1:H + 1, 1:W + 1, :] = mid.reshape(H, W, M)
+        mid = jax.nn.hard_swish(mid + b1_ref[...])
+        fill(mid_scratch, mid.reshape(H, W, M), row0=1, col0=1)
 
-        # VPU stage: depthwise 3x3 over the scratch (SAME semantics)
-        mp = mid_scratch[...]
-        acc = jnp.zeros((H, W, M), jnp.float32)
-        for dy in range(3):
-            for dx in range(3):
-                acc += mp[dy:dy + H, dx:dx + W, :] * dww_ref[dy, dx][None, None, :]
-        acc += dwb_ref[0][None, None, :]
-        if stride > 1:
-            acc = acc[stride - 1::stride, stride - 1::stride, :]
+        # VPU stage: depthwise 3x3 (SAME, anchored at stride-1) over the
+        # scratch, read strided so only the kept outputs are computed
+        acc = dw_taps(mid_scratch,
+                      lambda dy, dx, lo, hi: dww_ref[dy, dx, lo:hi],
+                      rows=Ho, cols=Wo, stride=stride,
+                      row0=stride - 1, col0=stride - 1)
+        acc += dwb_ref[...][None]
         dw_scratch[...] = jax.nn.hard_swish(acc).reshape(Ho * Wo, M)
 
     # MXU stage 2: 1x1 projection of the VMEM-resident DW output
     out = jnp.dot(dw_scratch[...], w2_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
-    out += b2_ref[0][None, :]
+    out += b2_ref[...]
     o_ref[0] = out.reshape(Ho, Wo, -1)
 
 
@@ -101,7 +99,7 @@ def mbconv_fused(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
         out_specs=pl.BlockSpec((1, Ho, Wo, bf), lambda b, j: (b, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Fp), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((H + 2, W + 2, M), jnp.float32),
+            tap_scratch(H + 2, W + 2, M),
             pltpu.VMEM((Ho * Wo, M), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
@@ -116,52 +114,55 @@ def mbconv_fused(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
 # FIX8 variant: int8 weights, int32 MXU accumulation, in-kernel requant
 # ---------------------------------------------------------------------------
 
+def _int8_expand_dw(x_ref, xs_ref, w1_ref, s1_ref, b1_ref, dww_ref,
+                    dws_ref, dwb_ref, mid_scratch, *, stride: int):
+    """Stages 1 + 2 of the FIX8 block for one batch element: int8 1x1
+    expansion, in-kernel requant, int32 depthwise 3x3, requant.  Returns
+    the int8 DW output (Ho*Wo, M) and its scale."""
+    H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
+    M = w1_ref.shape[1]
+    Ho, Wo = H // stride, W // stride
+    # MXU stage 1: int8 x int8 -> int32 expansion, fp32 dequant epilogue
+    xq = x_ref[0].reshape(H * W, C)
+    acc = int8_dot(xq, w1_ref[...])
+    mid = acc.astype(jnp.float32) * (xs_ref[0] * s1_ref[...]) + b1_ref[...]
+    mid = jax.nn.hard_swish(mid)
+    # in-kernel requantization: the 4x-expanded mid tensor is int8 (held
+    # widened to int32 in VMEM scratch for the strided tap reads)
+    mq, s_mid = requantize_i8(mid)
+    fill(mid_scratch, mq.reshape(H, W, M), row0=1, col0=1)
+    # VPU stage: depthwise 3x3 in int32, strided to the kept outputs
+    acc2 = dw_taps(mid_scratch,
+                   lambda dy, dx, lo, hi:
+                       dww_ref[dy, dx, lo:hi].astype(jnp.int32),
+                   rows=Ho, cols=Wo, stride=stride,
+                   row0=stride - 1, col0=stride - 1)
+    dw = acc2.astype(jnp.float32) * (s_mid * dws_ref[...])[None] \
+        + dwb_ref[...][None]
+    dw = jax.nn.hard_swish(dw)
+    return requantize_i8(dw.reshape(Ho * Wo, M))
+
+
 def _mbconv_int8_kernel(x_ref, xs_ref, w1_ref, s1_ref, b1_ref,
                         dww_ref, dws_ref, dwb_ref, w2_ref, s2_ref, b2_ref,
-                        o_ref, midq_scratch, dwq_scratch, sdw_scratch,
+                        o_ref, mid_scratch, dwq_scratch, sdw_scratch,
                         *, stride: int):
     j = pl.program_id(1)
-    H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    M = midq_scratch.shape[2]
+    H, W = x_ref.shape[1], x_ref.shape[2]
     Ho, Wo = H // stride, W // stride
 
     @pl.when(j == 0)
     def _expand_dw_requant():
-        # MXU stage 1: int8 x int8 -> int32 expansion, fp32 dequant epilogue
-        xq = x_ref[0].reshape(H * W, C)
-        acc = jax.lax.dot_general(xq, w1_ref[...], (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
-        mid = acc.astype(jnp.float32) * (xs_ref[0, 0] * s1_ref[0])[None, :] \
-            + b1_ref[0][None, :]
-        mid = jax.nn.hard_swish(mid)
-        # in-kernel requantization: the 4x-expanded mid tensor stays int8
-        # in VMEM scratch (the paper's fixed-point inter-stage pipeline)
-        mq, s_mid = requantize_i8(mid)
-        midq_scratch[...] = jnp.zeros((H + 2, W + 2, M), jnp.int8)
-        midq_scratch[1:H + 1, 1:W + 1, :] = mq.reshape(H, W, M)
-
-        # VPU stage: depthwise 3x3 in int32 over the int8 scratch
-        mp = midq_scratch[...].astype(jnp.int32)
-        acc2 = jnp.zeros((H, W, M), jnp.int32)
-        for dy in range(3):
-            for dx in range(3):
-                acc2 += mp[dy:dy + H, dx:dx + W, :] \
-                    * dww_ref[dy, dx].astype(jnp.int32)[None, None, :]
-        dw = acc2.astype(jnp.float32) * (s_mid * dws_ref[0])[None, None, :] \
-            + dwb_ref[0][None, None, :]
-        if stride > 1:
-            dw = dw[stride - 1::stride, stride - 1::stride, :]
-        dw = jax.nn.hard_swish(dw)
-        dq, s_dw = requantize_i8(dw.reshape(Ho * Wo, M))
-        sdw_scratch[0] = s_dw
+        dq, s_dw = _int8_expand_dw(x_ref, xs_ref, w1_ref, s1_ref, b1_ref,
+                                   dww_ref, dws_ref, dwb_ref, mid_scratch,
+                                   stride=stride)
+        sdw_scratch[...] = s_dw
         dwq_scratch[...] = dq
 
     # MXU stage 2: int8 projection of the VMEM-resident requantized DW out
-    acc3 = jax.lax.dot_general(dwq_scratch[...], w2_ref[...],
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    out = acc3.astype(jnp.float32) * (sdw_scratch[0] * s2_ref[0])[None, :] \
-        + b2_ref[0][None, :]
+    acc3 = int8_dot(dwq_scratch[...], w2_ref[...])
+    out = acc3.astype(jnp.float32) * (sdw_scratch[...] * s2_ref[...]) \
+        + b2_ref[...]
     o_ref[0] = out.reshape(Ho, Wo, -1)
 
 
@@ -175,11 +176,12 @@ def mbconv_fused_int8(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
     scales; b*: fp32 biases (BN folded).
 
     Returns (B, Ho, Wo, F) fp32.  Both intermediates are requantized
-    in-kernel and stay **int8** in VMEM scratch (~4x less scratch than the
-    fp32 megakernel).  The inter-stage activation scales are dynamic
-    per batch element — identical to the reference FIX8 path
-    (``core.quantization.conv2d_int8`` chain) at batch 1, and within
-    quantization noise of it for larger batches.
+    in-kernel and never leave VMEM (the DW output as int8 scratch; the
+    expanded mid map widened to int32 for the strided tap reads, which
+    Mosaic only supports on 32-bit data).  The inter-stage activation
+    scales are dynamic per batch element — identical to the reference
+    FIX8 path (``core.quantization.conv2d_int8`` chain) at batch 1, and
+    within quantization noise of it for larger batches.
     """
     interpret = default_interpret(interpret)
     B, H, W, C = x_q.shape
@@ -201,7 +203,7 @@ def mbconv_fused_int8(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
         grid=(B, nf),
         in_specs=[
             pl.BlockSpec((1, H, W, C), lambda b, j: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((C, M), lambda b, j: (0, 0)),
             pl.BlockSpec((1, M), lambda b, j: (0, 0)),
             pl.BlockSpec((1, M), lambda b, j: (0, 0)),
@@ -215,9 +217,9 @@ def mbconv_fused_int8(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
         out_specs=pl.BlockSpec((1, Ho, Wo, bf), lambda b, j: (b, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Fp), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((H + 2, W + 2, M), jnp.int8),
+            tap_scratch(H + 2, W + 2, M, jnp.int32),
             pltpu.VMEM((Ho * Wo, M), jnp.int8),
-            pltpu.SMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
@@ -236,48 +238,27 @@ def _mbconv_int8_emit_kernel(x_ref, xs_ref, w1_ref, s1_ref, b1_ref,
                              b2_ref, *refs, stride: int, keep_fp: bool):
     oq_ref, os_ref = refs[0], refs[1]
     ofp_ref = refs[2] if keep_fp else None
-    midq_scratch = refs[-1]
-    H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    M = midq_scratch.shape[2]
+    mid_scratch = refs[-1]
+    H, W = x_ref.shape[1], x_ref.shape[2]
     Ho, Wo = H // stride, W // stride
 
     # MXU stage 1 + VPU stage + in-kernel requant: identical arithmetic
     # to _mbconv_int8_kernel's j == 0 branch
-    xq = x_ref[0].reshape(H * W, C)
-    acc = jax.lax.dot_general(xq, w1_ref[...], (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    mid = acc.astype(jnp.float32) * (xs_ref[0, 0] * s1_ref[0])[None, :] \
-        + b1_ref[0][None, :]
-    mid = jax.nn.hard_swish(mid)
-    mq, s_mid = requantize_i8(mid)
-    midq_scratch[...] = jnp.zeros((H + 2, W + 2, M), jnp.int8)
-    midq_scratch[1:H + 1, 1:W + 1, :] = mq.reshape(H, W, M)
-    mp = midq_scratch[...].astype(jnp.int32)
-    acc2 = jnp.zeros((H, W, M), jnp.int32)
-    for dy in range(3):
-        for dx in range(3):
-            acc2 += mp[dy:dy + H, dx:dx + W, :] \
-                * dww_ref[dy, dx].astype(jnp.int32)[None, None, :]
-    dw = acc2.astype(jnp.float32) * (s_mid * dws_ref[0])[None, None, :] \
-        + dwb_ref[0][None, None, :]
-    if stride > 1:
-        dw = dw[stride - 1::stride, stride - 1::stride, :]
-    dw = jax.nn.hard_swish(dw)
-    dq, s_dw = requantize_i8(dw.reshape(Ho * Wo, M))
+    dq, s_dw = _int8_expand_dw(x_ref, xs_ref, w1_ref, s1_ref, b1_ref,
+                               dww_ref, dws_ref, dwb_ref, mid_scratch,
+                               stride=stride)
 
     # MXU stage 2 over the FULL c_out extent (the epilogue's per-batch
     # absmax needs the whole projection before anything is written)
-    acc3 = jax.lax.dot_general(dq, w2_ref[...], (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
-    out = acc3.astype(jnp.float32) * (s_dw * s2_ref[0])[None, :] \
-        + b2_ref[0][None, :]
+    acc3 = int8_dot(dq, w2_ref[...])
+    out = acc3.astype(jnp.float32) * (s_dw * s2_ref[...]) + b2_ref[...]
     if keep_fp:
         ofp_ref[0] = out.reshape(Ho, Wo, -1)
     # the act-quant epilogue: exactly what the consumer used to run in
     # XLA after a round-trip through HBM, now fused into the producer
     q, s_out = requantize_i8(out)
     oq_ref[0] = q.reshape(Ho, Wo, -1)
-    os_ref[0, 0] = s_out
+    os_ref[0] = s_out
 
 
 def mbconv_fused_int8_emit(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
@@ -305,9 +286,9 @@ def mbconv_fused_int8_emit(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
     xs = xs_per_batch(x_scale, B)
 
     out_shape = [jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.int8),
-                 jax.ShapeDtypeStruct((B, 1), jnp.float32)]
+                 jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)]
     out_specs = [pl.BlockSpec((1, Ho, Wo, F), lambda b: (b, 0, 0, 0)),
-                 pl.BlockSpec((1, 1), lambda b: (b, 0))]
+                 pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0))]
     if keep_fp:
         out_shape.append(jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.float32))
         out_specs.append(pl.BlockSpec((1, Ho, Wo, F), lambda b: (b, 0, 0, 0)))
@@ -318,7 +299,7 @@ def mbconv_fused_int8_emit(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
             pl.BlockSpec((C, M), lambda b: (0, 0)),
             pl.BlockSpec((1, M), lambda b: (0, 0)),
             pl.BlockSpec((1, M), lambda b: (0, 0)),
@@ -331,7 +312,7 @@ def mbconv_fused_int8_emit(x_q, x_scale, w1_q, s1, b1, dw_q, s_dw, dw_b,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((H + 2, W + 2, M), jnp.int8)],
+        scratch_shapes=[tap_scratch(H + 2, W + 2, M, jnp.int32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,

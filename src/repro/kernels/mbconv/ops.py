@@ -20,24 +20,26 @@ import jax.numpy as jnp
 
 from repro.core.quantization import QTensor, fold_bn_into_conv, quantize_act
 from repro.kernels.autotune import autotune, shape_key
-from repro.kernels.compat import default_interpret
+from repro.kernels.compat import VMEM_BUDGET_BYTES, default_interpret
 from repro.kernels.mbconv.kernel import (
     mbconv_fused, mbconv_fused_int8, mbconv_fused_int8_emit)
 from repro.kernels.mbconv.ref import mbconv_int8_ref, mbconv_ref
 from repro.kernels.registry import KernelBase, register
 
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
-
-BLOCK_F_CANDIDATES = ({"block_f": 64}, {"block_f": 128}, {"block_f": 256})
+# c_out tiles: a tile is the lane dim of the weight/output blocks, so
+# Mosaic takes a multiple of 128 (or the whole extent, which the kernel
+# uses whenever F <= block_f)
+BLOCK_F_CANDIDATES = ({"block_f": 128}, {"block_f": 256})
 
 
 def mbconv_vmem_bytes(h: int, w: int, c_in: int, mid: int,
                       stride: int = 1, *, dtype: str = "f32") -> int:
     """Analytic per-grid-step VMEM: input block + both fused scratches.
 
-    ``dtype="i8"`` is the FIX8 kernel: int8 input block and int8
-    requantized scratches — 4x less VMEM pressure than fp32, which is
-    what shrinks the ``"vmem"`` fallback set for quantized models.
+    ``dtype="i8"`` is the FIX8 kernel, counted at 1 byte per element
+    (int8 input block and requantized values).  The planner's model of
+    logical bytes: the compiled kernel widens the tap scratch to int32
+    and pads lanes (``kernels.compat`` sizes the compiler's limit).
     """
     per = 1 if dtype == "i8" else 4
     return per * (h * w * c_in + (h + 2) * (w + 2) * mid
@@ -78,7 +80,8 @@ def tune_block_f(x_shape, mid: int, f: int, *, stride: int = 1,
             stride=stride, block_f=cand["block_f"], interpret=interpret)
 
     choice = autotune("mbconv", key, BLOCK_F_CANDIDATES,
-                      bench if allow_sweep else None)
+                      bench if allow_sweep else None,
+                      interpret=interpret)
     return choice["block_f"]
 
 

@@ -59,7 +59,8 @@ def tune_block_n(bh: int, n: int, d: int, *, allow_sweep: bool = True,
                                    interpret=interpret)
 
     choice = autotune("relu_attn", key, BLOCK_N_CANDIDATES,
-                      bench if allow_sweep else None)
+                      bench if allow_sweep else None,
+                      interpret=interpret)
     return choice["block_n"]
 
 

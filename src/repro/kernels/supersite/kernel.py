@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.compat import default_interpret, tpu_compiler_params
-from repro.kernels.quant import requantize_i8
+from repro.kernels.quant import int8_dot, requantize_i8
+from repro.kernels.taps import dw_taps, fill, tap_scratch
 
 
 class MemberGeom(NamedTuple):
@@ -99,19 +100,33 @@ def band_geometry(members: Tuple[MemberGeom, ...], block_rows: int,
     return n_bands, tuple(reversed(out))
 
 
-def _take(w_ref, off: int, shape):
-    """Static slice of the flat resident weight block."""
-    n = 1
-    for d in shape:
-        n *= d
-    return w_ref[0, off:off + n].reshape(shape)
+def _take(w_ref, off: int, rows: int, cols: int):
+    """Static ``(rows, cols)`` block of the resident weight pack: every
+    tensor starts on an aligned row, at lane 0 (``pack.pack_weights``)."""
+    return w_ref[off:off + rows, :cols]
+
+
+def _tap_weights(w_ref, off: int, cast=None):
+    """Depthwise tap rows of a pack (the (3, 3, C) kernel stored as 9
+    rows) as the ``weight`` callable of ``taps.dw_taps``."""
+    def weight(dy, dx, lo, hi):
+        w = w_ref[off + 3 * dy + dx, lo:hi]
+        return w if cast is None else w.astype(cast)
+    return weight
+
+
+def member_scratch(m: MemberGeom, rows: int, dtype):
+    """The tap scratch of one chain member: its input window (``rows``
+    tall) plus the SAME column padding, at the depthwise width."""
+    width = m.mid if m.kind == "mbconv" else m.c_in
+    return tap_scratch(rows, m.w_in + 2, width, dtype)
 
 
 # ---------------------------------------------------------------------------
 # fp32: spatially-banded chain
 # ---------------------------------------------------------------------------
 
-def _fp_member(cur, j, m: MemberGeom, w_ref):
+def _fp_member(cur, j, m: MemberGeom, w_ref, scr):
     """One fp chain member on a band: cur (length, W, C) -> (n, Wo, F).
 
     Arithmetic is element-for-element the per-site megakernel's
@@ -126,51 +141,36 @@ def _fp_member(cur, j, m: MemberGeom, w_ref):
     rows = (m.c0 + m.c1 * j) \
         + jax.lax.broadcasted_iota(jnp.int32, (L, 1, 1), 0)
     valid = (rows >= 0) & (rows < m.h_in)
+    o = m.fp_offs
 
     if m.kind == "mbconv":
         M, F = m.mid, m.f_out
-        o = m.fp_offs
-        w1 = _take(w_ref, o[0], (C, M))
-        b1 = _take(w_ref, o[1], (1, M))
-        dww = _take(w_ref, o[2], (3, 3, M))
-        dwb = _take(w_ref, o[3], (1, M))
-        w2 = _take(w_ref, o[4], (M, F))
-        b2 = _take(w_ref, o[5], (1, F))
+        w1 = _take(w_ref, o[0], C, M)
+        b1 = _take(w_ref, o[1], 1, M)
+        dwb = _take(w_ref, o[3], 1, M)
+        w2 = _take(w_ref, o[4], M, F)
+        b2 = _take(w_ref, o[5], 1, F)
         mid = jnp.dot(cur.reshape(L * W, C), w1,
                       preferred_element_type=jnp.float32)
         mid = jax.nn.hard_swish(mid + b1).reshape(L, W, M)
         # the reference zero-pads MID: rows outside the feature map must
         # contribute zero to the DW taps, and hardswish(b1) != 0
-        mid = jnp.where(valid, mid, 0.0)
-        mp = jnp.pad(mid, ((0, 0), (1, 1), (0, 0)))
-        acc = jnp.zeros((n, Wo, M), jnp.float32)
-        for dy in range(3):
-            rsl = mp[dy:dy + s * (n - 1) + 1:s]
-            for dx in range(3):
-                acc += rsl[:, (s - 1) + dx:(s - 1) + dx + s * (Wo - 1) + 1:s,
-                           :] * dww[dy, dx][None, None, :]
-        acc += dwb[0][None, None, :]
-        dw = jax.nn.hard_swish(acc)
+        fill(scr, jnp.where(valid, mid, 0.0), col0=1)
+        acc = dw_taps(scr, _tap_weights(w_ref, o[2]), rows=n, cols=Wo,
+                      stride=s, col0=s - 1)
+        dw = jax.nn.hard_swish(acc + dwb[None])
         out = jnp.dot(dw.reshape(n * Wo, M), w2,
                       preferred_element_type=jnp.float32)
         out = (out + b2).reshape(n, Wo, F)
     else:                                        # dsconv (act always on)
         F = m.f_out
-        o = m.fp_offs
-        dww = _take(w_ref, o[0], (3, 3, C))
-        dwb = _take(w_ref, o[1], (1, C))
-        pww = _take(w_ref, o[2], (C, F))
-        pwb = _take(w_ref, o[3], (1, F))
-        xm = jnp.where(valid, cur, 0.0)
-        xp = jnp.pad(xm, ((0, 0), (1, 1), (0, 0)))
-        acc = jnp.zeros((n, Wo, C), jnp.float32)
-        for dy in range(3):
-            rsl = xp[dy:dy + s * (n - 1) + 1:s]
-            for dx in range(3):
-                acc += rsl[:, dx:dx + s * (Wo - 1) + 1:s, :] \
-                    * dww[dy, dx][None, None, :]
-        acc += dwb[0][None, None, :]
-        dw = jax.nn.hard_swish(acc)
+        dwb = _take(w_ref, o[1], 1, C)
+        pww = _take(w_ref, o[2], C, F)
+        pwb = _take(w_ref, o[3], 1, F)
+        fill(scr, jnp.where(valid, cur, 0.0), col0=1)
+        acc = dw_taps(scr, _tap_weights(w_ref, o[0]), rows=n, cols=Wo,
+                      stride=s)
+        dw = jax.nn.hard_swish(acc + dwb[None])
         out = jnp.dot(dw.reshape(n * Wo, C), pww,
                       preferred_element_type=jnp.float32)
         out = (out + pwb).reshape(n, Wo, F)
@@ -180,18 +180,18 @@ def _fp_member(cur, j, m: MemberGeom, w_ref):
     return out
 
 
-def _supersite_kernel(x_ref, w_ref, o_ref, *, geom: SupersiteGeom):
+def _supersite_kernel(x_ref, w_ref, o_ref, *scratch, geom: SupersiteGeom):
     j = pl.program_id(1)
     cur = x_ref[0, 0].astype(jnp.float32)        # (L0, W0, C0) slab
-    for m in geom.members:
-        cur = _fp_member(cur, j, m, w_ref)
+    for m, scr in zip(geom.members, scratch):
+        cur = _fp_member(cur, j, m, w_ref, scr)
     o_ref[0] = cur                               # (R, W_out, F_out)
 
 
 def supersite_fused(x, w_flat, *, geom: SupersiteGeom,
                     interpret: bool | None = None):
     """Run an fp super-site chain.  x: (B, H, W, C) member-0 input;
-    ``w_flat``: the (1, Nf) resident pack (``pack.pack_weights``);
+    ``w_flat``: the (rows, lanes) resident pack (``pack.pack_weights``);
     ``geom``: ``SupersiteGeom`` with band windows filled in
     (``ops.make_fp_geom``).  Returns (B, H_out, W_out, F_out) fp32.
 
@@ -212,19 +212,20 @@ def supersite_fused(x, w_flat, *, geom: SupersiteGeom,
     slabs = jnp.stack(
         [xpad[:, c0 + pad_top + c1 * j: c0 + pad_top + c1 * j + L]
          for j in range(nb)], axis=1)            # (B, nb, L, W, C)
-    nf = w_flat.shape[1]
 
     out = pl.pallas_call(
         functools.partial(_supersite_kernel, geom=geom),
         grid=(B, nb),
         in_specs=[
             pl.BlockSpec((1, 1, L, W, C), lambda b, j: (b, j, 0, 0, 0)),
-            pl.BlockSpec((1, nf), lambda b, j: (0, 0)),
+            pl.BlockSpec(w_flat.shape, lambda b, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, R, geom.w_out, geom.f_out),
                                lambda b, j: (b, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nb * R, geom.w_out, geom.f_out),
                                        jnp.float32),
+        scratch_shapes=[member_scratch(m, m.length, jnp.float32)
+                        for m in geom.members],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -236,8 +237,8 @@ def supersite_fused(x, w_flat, *, geom: SupersiteGeom,
 # FIX8: whole-map chain, per-batch-element grid
 # ---------------------------------------------------------------------------
 
-def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref):
-    """One FIX8 chain member: (int8 map, scale) -> fp32 output map.
+def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref, scr):
+    """One FIX8 chain member: (int8 map, (1, 1) scale) -> fp32 output map.
 
     Identical arithmetic to the per-site int8 emit kernels
     (``_mbconv_int8_emit_kernel`` / ``_dsconv_int8_emit_kernel``) up to
@@ -247,70 +248,61 @@ def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref):
     H, W, C = m.h_in, m.w_in, m.c_in
     s = m.stride
     Ho, Wo = H // s, W // s
+    qo, fo = m.q_offs, m.fp_offs
+    i32 = jnp.int32
     if m.kind == "mbconv":
         M, F = m.mid, m.f_out
-        qo, fo = m.q_offs, m.fp_offs
-        w1q = _take(wq_ref, qo[0], (C, M))
-        dwq = _take(wq_ref, qo[1], (3, 3, M))
-        w2q = _take(wq_ref, qo[2], (M, F))
-        s1 = _take(wf_ref, fo[0], (1, M))
-        b1 = _take(wf_ref, fo[1], (1, M))
-        dws = _take(wf_ref, fo[2], (1, M))
-        dwb = _take(wf_ref, fo[3], (1, M))
-        s2 = _take(wf_ref, fo[4], (1, F))
-        b2 = _take(wf_ref, fo[5], (1, F))
+        w1q = _take(wq_ref, qo[0], C, M)
+        w2q = _take(wq_ref, qo[2], M, F)
+        s1 = _take(wf_ref, fo[0], 1, M)
+        b1 = _take(wf_ref, fo[1], 1, M)
+        dws = _take(wf_ref, fo[2], 1, M)
+        dwb = _take(wf_ref, fo[3], 1, M)
+        s2 = _take(wf_ref, fo[4], 1, F)
+        b2 = _take(wf_ref, fo[5], 1, F)
         xq = cur_q.reshape(H * W, C)
-        acc = jax.lax.dot_general(xq, w1q, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
-        mid = acc.astype(jnp.float32) * (cur_s * s1[0])[None, :] + b1
+        acc = int8_dot(xq, w1q)
+        mid = acc.astype(jnp.float32) * (cur_s * s1) + b1
         mid = jax.nn.hard_swish(mid)
         mq, s_mid = requantize_i8(mid)
-        mp = jnp.pad(mq.reshape(H, W, M),
-                     ((1, 1), (1, 1), (0, 0))).astype(jnp.int32)
-        acc2 = jnp.zeros((H, W, M), jnp.int32)
-        for dy in range(3):
-            for dx in range(3):
-                acc2 += mp[dy:dy + H, dx:dx + W, :] \
-                    * dwq[dy, dx].astype(jnp.int32)[None, None, :]
-        dw = acc2.astype(jnp.float32) * (s_mid * dws[0])[None, None, :] \
-            + dwb[0][None, None, :]
-        if s > 1:
-            dw = dw[s - 1::s, s - 1::s, :]
+        fill(scr, mq.reshape(H, W, M), row0=1, col0=1)
+        acc2 = dw_taps(scr, _tap_weights(wq_ref, qo[1], i32), rows=Ho,
+                       cols=Wo, stride=s, row0=s - 1, col0=s - 1)
+        dw = acc2.astype(jnp.float32) * (s_mid * dws)[None] + dwb[None]
         dw = jax.nn.hard_swish(dw)
         dq, s_dw = requantize_i8(dw.reshape(Ho * Wo, M))
-        acc3 = jax.lax.dot_general(dq, w2q, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.int32)
-        out = acc3.astype(jnp.float32) * (s_dw * s2[0])[None, :] + b2
+        acc3 = int8_dot(dq, w2q)
+        out = acc3.astype(jnp.float32) * (s_dw * s2) + b2
     else:                                        # dsconv (act always on)
         F = m.f_out
-        qo, fo = m.q_offs, m.fp_offs
-        dwq = _take(wq_ref, qo[0], (3, 3, C))
-        pwq = _take(wq_ref, qo[1], (C, F))
-        dws = _take(wf_ref, fo[0], (1, C))
-        dwb = _take(wf_ref, fo[1], (1, C))
-        pws = _take(wf_ref, fo[2], (1, F))
-        pwb = _take(wf_ref, fo[3], (1, F))
-        xp = jnp.pad(cur_q, ((1, 1), (1, 1), (0, 0))).astype(jnp.int32)
-        acc = jnp.zeros((H, W, C), jnp.int32)
-        for dy in range(3):
-            for dx in range(3):
-                acc += xp[dy:dy + H, dx:dx + W, :] \
-                    * dwq[dy, dx].astype(jnp.int32)[None, None, :]
-        y = acc.astype(jnp.float32) * (cur_s * dws[0])[None, None, :] \
-            + dwb[0][None, None, :]
-        if s > 1:
-            y = y[s - 1::s, s - 1::s, :]
+        pwq = _take(wq_ref, qo[1], C, F)
+        dws = _take(wf_ref, fo[0], 1, C)
+        dwb = _take(wf_ref, fo[1], 1, C)
+        pws = _take(wf_ref, fo[2], 1, F)
+        pwb = _take(wf_ref, fo[3], 1, F)
+        fill(scr, cur_q, row0=1, col0=1)
+        acc = dw_taps(scr, _tap_weights(wq_ref, qo[0], i32), rows=Ho,
+                      cols=Wo, stride=s, row0=s - 1, col0=s - 1)
+        y = acc.astype(jnp.float32) * (cur_s * dws)[None] + dwb[None]
         y = jax.nn.hard_swish(y)
         dq, s_dw = requantize_i8(y.reshape(Ho * Wo, C))
-        acc2 = jax.lax.dot_general(dq, pwq, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.int32)
-        out = acc2.astype(jnp.float32) * (s_dw * pws[0])[None, :] + pwb
+        acc2 = int8_dot(dq, pwq)
+        out = acc2.astype(jnp.float32) * (s_dw * pws) + pwb
     return out.reshape(Ho, Wo, -1)
+
+
+def _requant_map(x):
+    """Per-batch-element requant of a (H, W, C) map -> (int8 map, (1, 1)
+    scale): the absmax runs over the whole map either way."""
+    q, s = requantize_i8(x.reshape(x.shape[0] * x.shape[1], -1))
+    return q.reshape(x.shape), s
 
 
 def _supersite_int8_kernel(x_ref, xs_ref, wq_ref, wf_ref, *refs,
                            geom: SupersiteGeom, has_xfp: bool,
                            exit_emit: bool, keep_fp: bool):
+    n_members = len(geom.members)
+    scratch, refs = refs[-n_members:], refs[:-n_members]
     if has_xfp:
         xfp_ref, refs = refs[0], refs[1:]
     if exit_emit:
@@ -320,30 +312,22 @@ def _supersite_int8_kernel(x_ref, xs_ref, wq_ref, wf_ref, *refs,
         ofp_ref = refs[0]
 
     cur_q = x_ref[0]                             # (H, W, C) int8
-    cur_s = xs_ref[0, 0]
+    cur_s = xs_ref[0]                            # (1, 1)
     cur_fp = xfp_ref[0] if has_xfp else None
-    n_members = len(geom.members)
-    for k, m in enumerate(geom.members):
-        out = _int8_member(cur_q, cur_s, m, wq_ref, wf_ref)
+    for k, (m, scr) in enumerate(zip(geom.members, scratch)):
+        out = _int8_member(cur_q, cur_s, m, wq_ref, wf_ref, scr)
         last = k == n_members - 1
         if m.residual:
             # execute()'s fp residual add + post-add quantize, per batch
-            # element (requantize_i8 over one element's map == the
-            # reference quantize_act)
-            sfp = cur_fp + out
-            if not last or exit_emit:
-                cur_q, cur_s = requantize_i8(sfp)
-            cur_fp = sfp
-        else:
-            if not last or exit_emit:
-                # the per-site emit kernel's act-quant epilogue
-                cur_q, cur_s = requantize_i8(
-                    out.reshape(out.shape[0] * out.shape[1], -1))
-                cur_q = cur_q.reshape(out.shape)
-            cur_fp = out
+            # element (one element's map == the reference quantize_act)
+            out = cur_fp + out
+        if not last or exit_emit:
+            # post-add quantize, or the per-site emit kernel's epilogue
+            cur_q, cur_s = _requant_map(out)
+        cur_fp = out
     if exit_emit:
         oq_ref[0] = cur_q
-        os_ref[0, 0] = cur_s
+        os_ref[0] = cur_s
         if keep_fp:
             ofp_ref[0] = cur_fp
     else:
@@ -356,7 +340,7 @@ def supersite_fused_int8(x_q, x_scale, wq_flat, wf_flat, *,
                          interpret: bool | None = None):
     """Run a FIX8 super-site chain.  x_q: (B, H, W, C) int8 with
     per-batch-element (or scalar) ``x_scale``; ``wq_flat``/``wf_flat``:
-    the (1, Nq) int8 + (1, Nf) fp32 resident pack halves; ``x_fp``: the
+    the int8 + fp32 resident pack halves (``pack.pack_weights``); ``x_fp``: the
     kept-fp entry activation (required iff member 0 is residual).
 
     Exit mirrors the site epilogue contract: ``exit_emit`` returns
@@ -372,14 +356,13 @@ def supersite_fused_int8(x_q, x_scale, wq_flat, wf_flat, *,
     assert x_q.dtype == jnp.int8
     Ho, Wo, F = geom.h_out, geom.w_out, geom.f_out
     xs = xs_per_batch(x_scale, B)
-    nq, nf = wq_flat.shape[1], wf_flat.shape[1]
     has_xfp = x_fp is not None
 
     in_specs = [
         pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
-        pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        pl.BlockSpec((1, nq), lambda b: (0, 0)),
-        pl.BlockSpec((1, nf), lambda b: (0, 0)),
+        pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
+        pl.BlockSpec(wq_flat.shape, lambda b: (0, 0)),
+        pl.BlockSpec(wf_flat.shape, lambda b: (0, 0)),
     ]
     args = [x_q, xs, wq_flat, wf_flat]
     if has_xfp:
@@ -387,9 +370,9 @@ def supersite_fused_int8(x_q, x_scale, wq_flat, wf_flat, *,
         args.append(x_fp.astype(jnp.float32))
     if exit_emit:
         out_shape = [jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.int8),
-                     jax.ShapeDtypeStruct((B, 1), jnp.float32)]
+                     jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)]
         out_specs = [pl.BlockSpec((1, Ho, Wo, F), lambda b: (b, 0, 0, 0)),
-                     pl.BlockSpec((1, 1), lambda b: (b, 0))]
+                     pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0))]
         if keep_fp:
             out_shape.append(
                 jax.ShapeDtypeStruct((B, Ho, Wo, F), jnp.float32))
@@ -407,6 +390,8 @@ def supersite_fused_int8(x_q, x_scale, wq_flat, wf_flat, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[member_scratch(m, m.h_in + 2, jnp.int32)
+                        for m in geom.members],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,

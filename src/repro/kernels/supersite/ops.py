@@ -2,11 +2,9 @@
 
 ``supersite_apply(params, x, supersite, ...)`` runs an fp chain banded
 over output rows; ``supersite_apply_int8`` runs the FIX8 chain whole-map
-per batch element.  Both draw their weights from the module-level
-residency cache (``pack.get_pack``) — packed once per (param tree,
-precision, chain), shared across every resolution bucket and executor
-rebuild — and hand the kernels a static ``SupersiteGeom`` so jit caches
-one program per chain shape.
+per batch element.  Both pack the members' weights into one resident
+block (``pack.pack_weights``) and hand the kernels a static
+``SupersiteGeom`` so jit caches one program per chain shape.
 
 The planner-facing half (``supersite_vmem_bytes`` /
 ``supersite_vmem_bytes_int8`` / ``choose_block_rows``) is pure host
@@ -25,13 +23,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantization import QTensor, act_fp, quantize_act
+from repro.kernels.compat import VMEM_BUDGET_BYTES
 from repro.kernels.registry import KernelBase, register
 from repro.kernels.supersite.kernel import (
     MemberGeom, SupersiteGeom, band_geometry, supersite_fused,
     supersite_fused_int8)
-from repro.kernels.supersite.pack import get_pack
-
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+from repro.kernels.supersite.pack import pack_weights
 
 # fp band heights, largest first — choose_block_rows picks the first
 # fit, and the offline search (repro.search) sweeps them per group
@@ -176,7 +173,7 @@ def supersite_apply(params, x, supersite, blocks=None, *,
     accepted for interface parity and ignored, mirroring the per-site
     fp impls (fp producers never emit int8 in-kernel)."""
     x = act_fp(x)
-    pack, _ = get_pack(params, supersite, "fp")
+    pack = pack_weights(params, supersite, "fp")
     rows = (blocks or {}).get("block_rows") or choose_block_rows(supersite)
     if rows is None:
         raise ValueError(f"super-site {supersite.name} fits no band "
@@ -193,7 +190,7 @@ def supersite_apply_int8(params, x, supersite, *, interpret=None,
     per-site consumers).  The exit follows the last member's epilogue:
     int8 emission returns a ``QTensor`` (fp alongside when the residual
     policy keeps it); otherwise the fp32 output."""
-    pack, _ = get_pack(params, supersite, "int8")
+    pack = pack_weights(params, supersite, "int8")
     geom = make_int8_geom(supersite, pack)
     first_residual = supersite.sites[0].residual
     if isinstance(x, QTensor):

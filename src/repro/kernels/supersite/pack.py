@@ -1,17 +1,19 @@
-"""Single-load weight residency: pack a super-site's weights ONCE.
+"""Single-load weight residency: pack a super-site's weights into ONE block.
 
 ME-ViT's (arXiv 2402.09709) single-load strategy, software-side: all
-member-site weights of a ``core.program.SuperSite`` are flattened into
-one resident block — a single fp32 vector for the fp chain, an int8
-vector + an fp32 scale/bias vector for the FIX8 chain — that the
+member-site weights of a ``core.program.SuperSite`` are stacked into
+one resident block — a single fp32 matrix for the fp chain, an int8
+matrix + an fp32 scale/bias matrix for the FIX8 chain — that the
 supersite kernel maps with a constant-index BlockSpec, so the grid
-re-reads nothing from HBM between spatial tiles.
+re-reads nothing from HBM between spatial tiles.  Each tensor occupies
+its own rows of the block, starting on a row aligned to the dtype's
+sublane tile and at lane 0, which is the form in which Mosaic slices a
+VMEM block without relayout.
 
-The pack is cached at module level keyed on the *param tree identity*
-(plus precision and member names) and the member geometry is
-resolution-independent, so every resolution bucket of one served model
-shares one pack: executor eviction and bucket switches never re-upload
-params (``pack_stats`` counts the hits the serving tests gate on).
+The pack is built from the params on every call of the forward: the
+served executors jit ``execute`` with the params as arguments, so the
+gather is part of each compiled forward (an XLA copy of the chain's
+weights per call), not a host-side cache.
 """
 from __future__ import annotations
 
@@ -22,17 +24,18 @@ import jax.numpy as jnp
 from repro.core.program import params_at
 from repro.core.quantization import fold_bn_into_conv
 
-__all__ = ["WeightPack", "pack_weights", "get_pack", "pack_stats",
-           "reset_pack_stats", "clear_pack_cache"]
+__all__ = ["WeightPack", "pack_weights"]
 
 
 class WeightPack(NamedTuple):
     """One super-site's resident weights.
 
-    ``fp``: (1, Nf) fp32 — weights+biases for an fp chain; scales+biases
-    for an int8 chain.  ``q``: (1, Nq) int8 weight values (int8 chains
-    only).  ``fp_offsets``/``q_offsets``: per-member tuples of static
-    flat offsets, in the fixed per-kind order the kernel unpacks
+    ``fp``: (rows, lanes) fp32 — weights+biases for an fp chain;
+    scales+biases for an int8 chain.  ``q``: (rows, lanes) int8 weight
+    values (int8 chains only).  Every tensor is stored as a matrix (a
+    vector as one row, a (3, 3, C) depthwise kernel as 9 rows).
+    ``fp_offsets``/``q_offsets``: per-member tuples of static starting
+    rows, in the fixed per-kind order the kernel unpacks
     (mbconv fp: w1,b1,dw,dwb,w2,b2; dsconv fp: dw,dwb,pw,pwb; int8 q:
     mbconv w1,dw,w2 / dsconv dw,pw; int8 fp: mbconv s1,b1,dws,dwb,s2,b2
     / dsconv dws,dwb,pws,pwb).  ``nbytes`` is the delivered-HBM cost of
@@ -71,16 +74,28 @@ def _member_int8_tensors(p, kind):
     return qs, fs
 
 
-def _flatten(tensors, dtype):
-    """Concatenate raveled tensors -> ((1, N) array, per-tensor offsets)."""
-    offs, flat, n = [], [], 0
-    for t in tensors:
+# row alignment of each tensor in a pack: the sublane tile of the dtype
+_ROW_ALIGN = {jnp.dtype(jnp.float32): 8, jnp.dtype(jnp.int8): 32}
+_LANES = 128
+
+
+def _stack_rows(tensors, dtype):
+    """Stack tensors as row blocks of one (rows, lanes) matrix ->
+    (matrix, per-tensor starting rows).  Rows start on the dtype's
+    sublane tile; lanes are padded to a multiple of 128."""
+    align = _ROW_ALIGN[jnp.dtype(dtype)]
+    mats = [jnp.asarray(t, dtype).reshape(-1, t.shape[-1]) for t in tensors]
+    if not mats:
+        return jnp.zeros((align, _LANES), dtype), ()
+    lanes = -(-max(m.shape[1] for m in mats) // _LANES) * _LANES
+    offs, blocks, n = [], [], 0
+    for m in mats:
+        rows = -(-m.shape[0] // align) * align
         offs.append(n)
-        flat.append(jnp.asarray(t, dtype).ravel())
-        n += int(t.size)
-    if not flat:
-        return jnp.zeros((1, 1), dtype), ()
-    return jnp.concatenate(flat).reshape(1, n), tuple(offs)
+        blocks.append(jnp.pad(m, ((0, rows - m.shape[0]),
+                                  (0, lanes - m.shape[1]))))
+        n += rows
+    return jnp.concatenate(blocks), tuple(offs)
 
 
 def pack_weights(params, supersite, precision: str) -> WeightPack:
@@ -97,8 +112,8 @@ def pack_weights(params, supersite, precision: str) -> WeightPack:
         q_all.extend(qs)
         fp_counts.append(len(fs))
         q_counts.append(len(qs))
-    fp_flat, fp_offs = _flatten(fp_all, jnp.float32)
-    q_flat, q_offs = (_flatten(q_all, jnp.int8) if q_all
+    fp_flat, fp_offs = _stack_rows(fp_all, jnp.float32)
+    q_flat, q_offs = (_stack_rows(q_all, jnp.int8) if q_all
                       else (None, ()))
 
     def _split(offs, counts):
@@ -113,45 +128,3 @@ def pack_weights(params, supersite, precision: str) -> WeightPack:
     return WeightPack(fp_flat, q_flat, _split(fp_offs, fp_counts),
                       _split(q_offs, q_counts), nbytes)
 
-
-# ---------------------------------------------------------------------------
-# the residency cache: one pack per (param tree, precision, member chain)
-# ---------------------------------------------------------------------------
-
-_PACKS: dict = {}
-_STATS = {"built": 0, "hits": 0}
-
-
-def get_pack(params, supersite, precision: str):
-    """Resident pack for this (param tree, precision, member chain) —
-    built once, then shared by every caller holding the same param tree:
-    all resolution buckets of one served model, every executor rebuild
-    after an eviction, every grid step of every launch.
-
-    Returns ``(pack, hit)``; ``hit`` tells telemetry whether the weights
-    were already resident (no re-upload).
-    """
-    key = (id(params), precision, supersite.members)
-    pack = _PACKS.get(key)
-    if pack is not None:
-        _STATS["hits"] += 1
-        return pack, True
-    pack = pack_weights(params, supersite, precision)
-    _PACKS[key] = pack
-    _STATS["built"] += 1
-    return pack, False
-
-
-def pack_stats() -> dict:
-    """Copy of the residency counters ({'built', 'hits'})."""
-    return dict(_STATS)
-
-
-def reset_pack_stats() -> None:
-    _STATS["built"] = 0
-    _STATS["hits"] = 0
-
-
-def clear_pack_cache() -> None:
-    """Drop every resident pack (tests / model swap)."""
-    _PACKS.clear()

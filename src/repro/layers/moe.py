@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
 from repro.distributed.ctx import current_ctx, shard
 from repro.layers.mlp import _act
 
@@ -323,7 +322,7 @@ def moe_shard_map(params, x, cfg: MoeConfig, ctx):
     if cfg.gated:
         args.append(params["w_gate"])
         in_specs.append(wspec(params["w_gate"], win_spec))
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(x_spec, P()), check_vma=False,
     )(*args)

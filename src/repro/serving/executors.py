@@ -407,34 +407,10 @@ class ExecutorCache:
             # forced precision must not leak into healthy buckets
             if donor is None and (state is None or not state.degraded):
                 self._donor_plans[key.resolution] = plan
-            self._warm_weight_packs(program, plan)
         fn = sharded_forward(program, self.params, plan=plan,
                              shard=shard) if shard is not None else None
         return Executor(key, program, plan, faults=self.faults,
                         degraded=state, fn=fn, shard=shard)
-
-    def _warm_weight_packs(self, program, plan) -> None:
-        """Build (or re-hit) the resident weight pack of every super-site
-        group in ``plan`` at executor-build time, so the first request
-        never pays the pack gather — and count what happened.
-
-        The pack cache (``kernels.supersite.pack``) keys on (param tree,
-        precision, member chain) — NOT on resolution or batch — so every
-        bucket of one served model after the first counts a
-        ``weight_pack_hit``: the weights were loaded into their resident
-        layout once and are shared across resolution buckets, LRU
-        evictions and executor rebuilds (single-load residency).
-        """
-        groups = getattr(plan, "groups", None) or {}
-        if not groups:
-            return
-        from repro.core.program import SuperSite
-        from repro.kernels.supersite.pack import get_pack
-        for g in groups.values():
-            sup = SuperSite.of(program, g.members, name=g.name)
-            _, hit = get_pack(self.params, sup, g.precision)
-            self.telemetry.count(
-                "weight_pack_hit" if hit else "weight_pack_built")
 
     # -- per-device fault domains ----------------------------------------
     @property

@@ -28,7 +28,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.common.compat import shard_map
 from repro.common.errors import MeshExhausted
 from repro.core.program import execute
 from repro.distributed.partition import data_parallel_specs
@@ -163,6 +162,6 @@ def sharded_forward(program, params, *, plan=None, shard: ShardSpec):
     def local(p, v):
         return execute(program, p, v, plan=plan)
 
-    f = shard_map(local, mesh=mesh, in_specs=(param_specs, act_spec),
-                  out_specs=act_spec, check_vma=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(param_specs, act_spec),
+                      out_specs=act_spec, check_vma=False)
     return jax.jit(f)

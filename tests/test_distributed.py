@@ -141,17 +141,18 @@ def test_elastic_reshard_and_ckpt_cross_mesh(tmp_path):
 def test_compressed_psum_matches_exact():
     r = run_sub("""
         from functools import partial
-        from repro.common.compat import shard_map
         from repro.optim.compression import compressed_psum
 
         mesh = jax.make_mesh((8,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-        @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                 out_specs=P("pod"))
         def compressed(x):
             return compressed_psum(x, "pod") * 8.0   # sum, not mean
 
-        @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                 out_specs=P("pod"))
         def exact(x):
             return jax.lax.psum(x, "pod")
 

@@ -8,10 +8,12 @@ reference path, and any plan-routed forward agrees with it within 1e-3.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from numpy.testing import assert_allclose
 
 from proptest import sweep
 
+from repro.common.errors import PlanError
 from repro.core.efficientvit import (
     B1_SMOKE, efficientvit, init_efficientvit, init_mbconv, mbconv)
 from repro.core.relu_attention import MSAConfig, init_msa, msa
@@ -202,8 +204,28 @@ def test_autotune_disqualifies_failing_candidates(tmp_autotune_cache):
             raise ValueError("tile too big for VMEM")
         return jnp.zeros(())
 
-    choice = autotune("unit2", (1,), [{"b": 8}, {"b": 16}], bench)
+    with pytest.warns(RuntimeWarning, match=r"\{'b': 8\} disqualified"):
+        choice = autotune("unit2", (1,), [{"b": 8}, {"b": 16}], bench)
     assert choice == {"b": 16}
+
+
+def test_autotune_all_candidates_failing(tmp_autotune_cache):
+    """Interpreter: the heuristic default, uncached.  Compiled backend:
+    a typed error naming every refusal — never a silent default."""
+    def bench(cand):
+        raise ValueError(f"tile {cand['b']} refused")
+
+    cands = [{"b": 8}, {"b": 16}]
+    with pytest.warns(RuntimeWarning, match="disqualified"):
+        assert autotune("unit3", (1,), cands, bench,
+                        interpret=True) == {"b": 8}
+    # an omitted flag resolves from the backend: the cpu interprets
+    with pytest.warns(RuntimeWarning, match="disqualified"):
+        assert autotune("unit3", (3,), cands, bench) == {"b": 8}
+    with pytest.warns(RuntimeWarning, match="disqualified"), \
+            pytest.raises(PlanError, match="tile 8 refused.*tile 16 refused"):
+        autotune("unit3", (2,), cands, bench, interpret=False)
+    assert autotune_mod.export_entries() == {}      # nothing was cached
 
 
 def test_pad_to_multiple():
@@ -213,3 +235,19 @@ def test_pad_to_multiple():
     assert float(padded[:, 5:].sum()) == 0.0
     same, n2 = pad_to_multiple(x, 1, 5)
     assert same is x and n2 == 5
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", RuntimeError)])
+def test_default_interpret_follows_backend(backend, expect, monkeypatch):
+    """Interpret only on the CPU backend, compile on TPU, and refuse any
+    other backend instead of quietly interpreting there."""
+    from repro.kernels import compat
+    monkeypatch.setattr(compat, "_backend", lambda: backend)
+    assert compat.default_interpret(True) is True
+    assert compat.default_interpret(False) is False
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="gpu"):
+            compat.default_interpret()
+    else:
+        assert compat.default_interpret() is expect

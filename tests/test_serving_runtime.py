@@ -92,21 +92,23 @@ def test_multi_resolution_fused_parity_fp(smoke_params, res, batch,
 
 @pytest.mark.parametrize("res,batch", [(32, 1), (64, 1), (32, 2)])
 def test_multi_resolution_fused_parity_int8(smoke_params, res, batch,
-                                            tmp_autotune_cache):
-    """Batch 1: int8-fused is bit-exact vs the int8 reference chain (the
-    in-kernel requant scales coincide); batch > 1 within quantization
-    noise with the top-1 label preserved."""
+                                            tmp_autotune_cache, int8_parity):
+    """Batch 1: int8-fused emits the int8 reference chain's codes bit
+    for bit at every boundary (the in-kernel requant decisions
+    coincide), its fp32 tail within the ulps ``int8_parity`` argues;
+    batch > 1 within quantization noise with the top-1 label
+    preserved."""
     qparams = quantize_efficientvit(smoke_params)
     x = _images(batch, res)
     program = lower(B1_SMOKE, batch=batch, image_size=res)
     plan = plan_program(program, qparams, autotune=False)
     assert all(d.precision == "int8"
                for d in plan.decisions.values() if d.fused)
-    ref = execute(program, qparams, x)
-    fus = execute(program, qparams, x, plan=plan)
     if batch == 1:
-        np.testing.assert_array_equal(np.asarray(fus), np.asarray(ref))
+        int8_parity(program, qparams, x, plan)
     else:
+        ref = execute(program, qparams, x)
+        fus = execute(program, qparams, x, plan=plan)
         assert bool((jnp.argmax(ref, -1) == jnp.argmax(fus, -1)).all())
         assert float(jnp.max(jnp.abs(ref - fus))) < 1e-2
 
@@ -383,7 +385,7 @@ def test_tuner_keys_distinct_across_buckets(kind, monkeypatch,
     may never share (or overwrite) a block choice."""
     captured = []
 
-    def fake_autotune(k, key, candidates, bench=None):
+    def fake_autotune(k, key, candidates, bench=None, *, interpret=True):
         captured.append((k, tuple(key)))
         return dict(candidates[0])
 
@@ -423,9 +425,9 @@ def test_dsconv_tune_reads_persistent_cache(tmp_autotune_cache):
     assert tune_block_f((2, 64, 64, 8), 8, allow_sweep=False,
                         interpret=True) == 256
     # a different batch bucket misses that entry -> heuristic first
-    # candidate (64), NOT the batch-2 choice: no cross-bucket collision
+    # candidate (128), NOT the batch-2 choice: no cross-bucket collision
     assert tune_block_f((4, 64, 64, 8), 8, allow_sweep=False,
-                        interpret=True) == 64
+                        interpret=True) == 128
     at.clear_memory_cache()
 
 

@@ -7,11 +7,11 @@ The contracts under test (ISSUE 10 / ROADMAP item 2):
   * the grouping pass in ``plan_program`` collapses consecutive fused
     conv sites of one stage into one launch, and the grouped forward
     matches the site-by-site interpreter — fp to <1e-5, int8 BIT-EXACT;
-  * weights are resident: one ``WeightPack`` per (param tree, precision,
-    member chain), built once and shared across resolution buckets and
-    executor rebuilds (``pack_stats`` / ``weight_pack_*`` telemetry),
-    and the plan report counts each member's weight bytes exactly once
-    with interior activation traffic at zero;
+  * weights are resident per launch: one ``WeightPack`` holds every
+    member tensor once, each on an aligned row at lane 0, the pack built
+    inside a jitted forward equals the host-built one, and the plan
+    report counts each member's weight bytes exactly once with interior
+    activation traffic at zero;
   * ``SiteOverride.group_break`` splits a chain exactly where pinned
     (the offline search's split/merge lever);
   * the fault ladder demotes a blamed member OUT of its group — the
@@ -30,8 +30,7 @@ from repro.core.fusion import (
     SiteOverride, launch_counts, plan_program, plan_report)
 from repro.core.program import SuperSite, execute, lower
 from repro.core.quantization import quantize_efficientvit
-from repro.kernels.supersite.pack import (
-    clear_pack_cache, get_pack, pack_stats, reset_pack_stats)
+from repro.kernels.supersite.pack import pack_weights
 from repro.serving.executors import ExecutorCache
 
 # Deep enough to form real chains (B1_SMOKE's depths of 1 group
@@ -46,15 +45,6 @@ N_GROUPS = 3
 @pytest.fixture
 def params():
     return init_efficientvit(jax.random.PRNGKey(0), CFG)
-
-
-@pytest.fixture(autouse=True)
-def fresh_pack_cache():
-    clear_pack_cache()
-    reset_pack_stats()
-    yield
-    clear_pack_cache()
-    reset_pack_stats()
 
 
 def _images(n, res=64, seed=1):
@@ -141,22 +131,66 @@ def test_supersite_chain_parity_int8_bit_exact(params, tmp_autotune_cache):
 # single-load weight residency
 # ---------------------------------------------------------------------------
 
-def test_weight_pack_built_once_counted_once(params, tmp_autotune_cache):
+@pytest.mark.parametrize("precision", ["fp", "int8"])
+def test_weight_pack_holds_each_member_tensor_once(params, precision,
+                                                   tmp_autotune_cache):
+    """Every member tensor sits in its own rows of the pack, starting on
+    the dtype's sublane tile at lane 0, and reads back unchanged."""
+    from repro.kernels.supersite.pack import (
+        _member_fp_tensors, _member_int8_tensors)
+    from repro.core.program import params_at
+    tree = params if precision == "fp" else quantize_efficientvit(params)
     program = lower(CFG, batch=1, image_size=64)
-    plan = plan_program(program, params, autotune=False)
+    plan = plan_program(program, tree, autotune=False)
     g = plan.groups["S2.ss0"]
     sup = SuperSite.of(program, g.members, name=g.name)
-    pack, hit = get_pack(params, sup, g.precision)
-    assert not hit and pack_stats() == {"built": 1, "hits": 0}
-    again, hit2 = get_pack(params, sup, g.precision)
-    assert hit2 and again is pack                 # resident, not rebuilt
-    assert pack_stats() == {"built": 1, "hits": 1}
-    # the pack IS its flat buffers: every member weight appears once
+    pack = pack_weights(tree, sup, g.precision)
+    for k, site in enumerate(sup.sites):
+        p = params_at(tree, site.param_path)
+        if precision == "int8":
+            qs, fs = _member_int8_tensors(p, site.kind)
+            halves = ((pack.q, pack.q_offsets[k], qs, 32),
+                      (pack.fp, pack.fp_offsets[k], fs, 8))
+        else:
+            halves = ((pack.fp, pack.fp_offsets[k],
+                       _member_fp_tensors(p, site.kind), 8),)
+        for mat, offs, tensors, align in halves:
+            assert len(offs) == len(tensors)
+            for off, t in zip(offs, tensors):
+                t = np.asarray(t).reshape(-1, np.shape(t)[-1])
+                assert off % align == 0
+                got = np.asarray(mat[off:off + t.shape[0], :t.shape[1]])
+                np.testing.assert_array_equal(got, t.astype(got.dtype))
+    assert pack.fp.shape[1] % 128 == 0
     q_bytes = int(pack.q.size) if pack.q is not None else 0
     assert pack.nbytes == int(pack.fp.size) * 4 + q_bytes
 
-    # report-level accounting: grouping never double-counts weight HBM,
-    # and interior members deliver ZERO activation bytes
+
+def test_weight_pack_built_in_jit_matches_host_pack(params,
+                                                    tmp_autotune_cache):
+    """The executors jit the forward with params as arguments, so the
+    pack is built inside the trace: it must equal the host-built pack,
+    and the jitted grouped forward the eager one."""
+    program = lower(CFG, batch=2, image_size=64)
+    plan = plan_program(program, params, autotune=False)
+    g = plan.groups["S1.ss0"]
+    sup = SuperSite.of(program, g.members, name=g.name)
+    host = pack_weights(params, sup, "fp")
+    traced = jax.jit(lambda p: pack_weights(p, sup, "fp").fp)(params)
+    np.testing.assert_array_equal(np.asarray(traced), np.asarray(host.fp))
+    x = _images(2)
+    eager = execute(program, params, x, plan=plan)
+    jitted = jax.jit(lambda p, v: execute(program, p, v, plan=plan))(
+        params, x)
+    assert_allclose(np.asarray(jitted), np.asarray(eager),
+                    rtol=1e-6, atol=1e-6)
+
+
+def test_plan_report_counts_group_weights_once(params, tmp_autotune_cache):
+    """Grouping never double-counts weight HBM, and interior members
+    deliver ZERO activation bytes."""
+    program = lower(CFG, batch=1, image_size=64)
+    plan = plan_program(program, params, autotune=False)
     flat_plan = plan_program(program, params, autotune=False,
                              supersites=False)
     rep, flat_rep = plan_report(plan), plan_report(flat_plan)
@@ -167,24 +201,6 @@ def test_weight_pack_built_once_counted_once(params, tmp_autotune_cache):
         for interior in grp.members[1:-1]:
             assert rows[interior]["hbm_delivered"] == 0, interior
         assert sum(rows[m]["launches_fused"] for m in grp.members) == 1
-
-
-def test_bucket_switch_never_reuploads_weights(params, tmp_autotune_cache):
-    """The pack cache keys on (param tree, precision, member chain) —
-    NOT resolution — so a resolution-bucket switch re-hits every
-    resident pack instead of re-uploading."""
-    cache = ExecutorCache(params, CFG, buckets=(1, 2), autotune=False)
-    cache.get(1, 64)
-    t = cache.telemetry.counters
-    assert t["weight_pack_built"] == N_GROUPS
-    assert t.get("weight_pack_hit", 0) == 0
-    cache.get(1, 32)                    # new resolution: fresh plan...
-    assert t["weight_pack_built"] == N_GROUPS     # ...same packs
-    assert t["weight_pack_hit"] == N_GROUPS
-    cache.get(2, 64)                    # new bucket, same resolution
-    assert t["weight_pack_built"] == N_GROUPS
-    assert t["weight_pack_hit"] == 2 * N_GROUPS
-    assert pack_stats()["built"] == N_GROUPS
 
 
 # ---------------------------------------------------------------------------
