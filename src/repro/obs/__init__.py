@@ -2,11 +2,15 @@
 
 Three pillars, deliberately decoupled from the serving hot path:
 
-  * ``obs.trace``   — ``Tracer``/``Span``: host-clock request spans with
-    Chrome/Perfetto JSON export.  No jax import, no device sync.
+  * ``obs.trace``   — ``Tracer``/``Span``: host-clock spans at the
+    scheduler's boundaries (admit, request, queue, form, dispatch with
+    its h2d copy and launch, device with its readback, finalize; listed
+    in ``obs.trace``) with Chrome/Perfetto JSON export.  No jax import,
+    no device sync.  Device time comes from the profiler trace that the
+    benchmark reduces (``bench/benchlib/xtrace.py``), not from here.
   * ``obs.profile`` — opt-in per-site profiled execution reconciling
-    measured wall clock against the analytic cycle model
-    (``DriftReport``).  Synchronizes per site; never on by default.
+    host wall clock against the FPGA cycle model (``DriftReport``).
+    Synchronizes per site; never on by default, and not a device time.
   * ``obs.metrics`` — ``MetricsRegistry``: Prometheus-text / JSON export
     facade over ``serving.telemetry`` plus standalone instruments.
 

@@ -21,15 +21,33 @@ Design constraints (they are the point):
     boundary MUST NOT synchronize with the device — recording a span on
     the dispatch path costs two host clock reads and a deque append.
     The device-side window of a batch is modeled as the span between
-    dispatch and materialization, both host-observed; per-kernel device
-    timing lives in ``repro.obs.profile`` (opt-in, explicitly not the
-    serving path).  ``tests/test_obs.py`` asserts the no-jax property.
+    dispatch and materialization, both host-observed; device time
+    itself comes from the profiler trace that the benchmark reduces
+    (``bench/benchlib/xtrace.py``), on the same clock as these spans.
+    ``tests/test_obs.py`` asserts the no-jax property.
   * **Injectable clock.**  The scheduler's ``ManualClock`` plugs in, so
     span timing in tests and trace replays is deterministic.
   * **Bounded memory.**  Finished spans live in a ring buffer
     (``capacity``, default 4096): a long-lived serving process keeps the
     most recent window, like the telemetry series.  ``dropped`` counts
     what the ring evicted.
+
+## The scheduler's spans
+
+``serving.scheduler.MicroBatchScheduler`` records, when a tracer is
+threaded (and does no tracing work at all without one):
+
+    admit      root, track "client": ``submit`` entry -> scheduler lock held
+    request    root: admission (lock held) -> terminal state
+    queue      child of request: one stay in an admission or retry queue
+    form       batch formation of one bucket
+    dispatch   root of one batch: executor lookup, stacking and padding,
+               the copy and launch below, telemetry
+    h2d        child of dispatch: the host->device copy of the input batch
+    launch     child of dispatch: the executor call (asynchronous enqueue)
+    device     root: a batch in flight, dispatched -> read back on the host
+    readback   child of device: the host blocked on the batch's output
+    finalize   scatter of one read-back batch onto its requests
 
 ## Trace JSON schema (``export`` / ``to_chrome``)
 

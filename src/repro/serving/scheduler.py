@@ -317,7 +317,14 @@ class MicroBatchScheduler:
         (bounded queue / overload fault), with ``req.error`` typed.
         A result-cache hit completes the request here — in front of
         admission, before the queue bound is even consulted."""
+        # "admit": how long this client waited for the scheduler lock
+        # (held by a finalize blocked on the device, or by step)
+        aspan = None
+        if self.tracer is not None:
+            aspan = self.tracer.begin("admit", track="client", rid=req.rid)
         with self._lock:
+            if aspan is not None:
+                self.tracer.end(aspan)
             req.arrival = self.clock()
             self.telemetry.count("submitted")
             if self.tracer is not None:
@@ -473,12 +480,23 @@ class MicroBatchScheduler:
             pad = np.zeros((bucket - len(reqs),) + imgs.shape[1:],
                            imgs.dtype)
             imgs = np.concatenate([imgs, pad])
+        lspan = None
+        if self.tracer is not None:
+            hspan = self.tracer.begin("h2d", parent=dspan, bucket=bucket,
+                                      bytes=imgs.nbytes)
+        x = jnp.asarray(imgs)                    # the host->device copy
+        if self.tracer is not None:
+            self.tracer.end(hspan)
+            lspan = self.tracer.begin("launch", parent=dspan)
         try:
-            out = ex(self.params, jnp.asarray(imgs))  # async, no host sync
+            out = ex(self.params, x)             # async, no host sync
         except ReproError as e:
+            self._t_end(lspan, error=type(e).__name__)
             self._t_end(dspan, error=type(e).__name__)
             self._on_failure(resolution, reqs, key, e, ex=ex)
             return
+        if self.tracer is not None:
+            self.tracer.end(lspan)
         self.telemetry.record_dispatch(
             key, len(reqs), bucket,
             queue_depth=len(self._queues.get(resolution, ())),
@@ -594,18 +612,27 @@ class MicroBatchScheduler:
             done = 0
             pending, self._pending = self._pending, []
             for out, reqs, key, ex, _t, devspan in pending:
+                # "readback": the host blocked on this batch's answer
+                rspan = None
+                if self.tracer is not None:
+                    rspan = self.tracer.begin("readback", parent=devspan,
+                                              bucket=key[0])
                 try:
                     arr = np.asarray(out)          # sync on this chunk
                 except ReproError as e:
+                    self._t_end(rspan, error=type(e).__name__)
                     self._t_end(devspan, error=type(e).__name__)
                     self._on_failure(key[1], reqs, key, e, ex=ex)
                     continue
                 except Exception as e:             # untyped XLA crash
+                    self._t_end(rspan, error=type(e).__name__)
                     self._t_end(devspan, error=type(e).__name__)
                     self._on_failure(key[1], reqs, key, ExecutorError(
                         f"materializing executor {key} output failed: "
                         f"{e}"), ex=ex)
                     continue
+                if self.tracer is not None:
+                    self.tracer.end(rspan)
                 self._t_end(devspan)
                 fspan = None
                 if self.tracer is not None:

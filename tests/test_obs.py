@@ -10,6 +10,7 @@ import json
 import math
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -21,7 +22,7 @@ from repro.obs import (
     escape_label, load_result, request_chains, validate_chrome_trace,
     validate_result, write_result)
 from repro.serving.scheduler import (
-    ManualClock, MicroBatchScheduler, Request)
+    BucketedPolicy, ManualClock, MicroBatchScheduler, Request)
 from repro.serving.telemetry import Telemetry
 
 
@@ -259,6 +260,155 @@ def test_async_host_loop_traces_without_span_leaks():
     for c in chains.values():
         assert {"queue"} <= c["children"]
         assert {"dispatch", "device", "finalize"} <= c["member_of"]
+
+
+def _within(child, parent):
+    return (child.parent_id == parent.span_id
+            and parent.start <= child.start
+            and child.end_ts <= parent.end_ts)
+
+
+def test_async_loop_batches_carry_one_copy_launch_and_readback():
+    """On the background loop, on the host's own clock: each dispatched
+    batch has exactly one ``h2d`` and one ``launch`` inside its
+    ``dispatch`` span, one ``readback`` inside its ``device`` span."""
+    clock = ManualClock()
+    tracer = Tracer()
+    sched = MicroBatchScheduler(FakeCache(), None, clock=clock,
+                                tracer=tracer)
+    sched.start(poll_s=0.001)
+    try:
+        reqs = _reqs(11, deadline_ms=5.0)
+        for r in reqs:
+            sched.submit(r)
+        clock.advance(0.05)            # flush the ragged tail
+        assert sched.wait(reqs, timeout_s=10.0)
+    finally:
+        sched.stop()
+    assert all(r.status == "completed" for r in reqs)
+    dispatches, devices = tracer.spans("dispatch"), tracer.spans("device")
+    assert len(dispatches) == len(devices) >= 3
+    for name, parents in (("h2d", dispatches), ("launch", dispatches),
+                          ("readback", devices)):
+        kids = tracer.spans(name)
+        assert len(kids) == len(parents), name
+        for p in parents:
+            mine = [k for k in kids if _within(k, p)]
+            assert len(mine) == 1, (name, p.attrs)
+    for h, d in zip(tracer.spans("h2d"), dispatches):
+        assert h.attrs["bucket"] == d.attrs["bucket"]
+        assert h.attrs["bytes"] == d.attrs["bucket"] * 32 * 32 * 3 * 4
+    assert not tracer.open_spans()
+
+
+def test_admit_measures_the_scheduler_lock_held_elsewhere():
+    """``admit`` opens before ``submit`` takes the lock and ends once it
+    holds it: a lock held by another thread for 40 ms reads 40 ms.  The
+    request's own span still opens after the lock, as before."""
+    clock = ManualClock()
+    tracer = Tracer(clock=clock)
+    sched = MicroBatchScheduler(FakeCache(), None, clock=clock,
+                                tracer=tracer)
+    req, = _reqs(1)
+    client = threading.Thread(target=sched.submit, args=(req,))
+    with sched._lock:
+        client.start()
+        deadline = time.monotonic() + 10.0
+        while not any(s.name == "admit" for s in tracer.open_spans()):
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        clock.advance(0.040)
+    client.join(timeout=10.0)
+    assert not client.is_alive()
+    admit, = tracer.spans("admit")
+    assert admit.duration == pytest.approx(0.040)
+    assert admit.parent_id is None and admit.track == "client"
+    assert admit.attrs == {"rid": req.rid}
+    request, = [s for s in tracer.open_spans() if s.name == "request"]
+    assert request.start == pytest.approx(0.040)
+    assert req.arrival == pytest.approx(0.040)
+
+
+def test_readback_ends_with_the_error_of_a_failed_materialization():
+    class Boom:
+        def __array__(self, *a, **k):
+            raise ExecutorError("materialization fault")
+
+    class BoomCache(FakeCache):
+        def get(self, batch, resolution):
+            return lambda params, x: Boom()
+
+    tracer = Tracer(clock=ManualClock())
+    sched = MicroBatchScheduler(BoomCache(), None, clock=ManualClock(),
+                                tracer=tracer, max_retries=0)
+    reqs = _reqs(4)
+    for r in reqs:
+        sched.submit(r)
+    sched.step(drain=True)
+    sched.finalize()
+    assert all(r.status == "failed" for r in reqs)
+    rb, = tracer.spans("readback")
+    dev, = tracer.spans("device")
+    assert rb.attrs["error"] == dev.attrs["error"] == "ExecutorError"
+    assert rb.parent_id == dev.span_id
+    assert not tracer.open_spans()
+
+
+def test_request_chains_ignore_the_new_child_and_client_spans():
+    """``admit`` (a root without ``rids``) and the batch spans' children
+    (``h2d``, ``launch``, ``readback``) leave the per-request chains as
+    they were: exactly the queue child and the four batch spans."""
+    clock = ManualClock()
+    tracer = Tracer(clock=clock)
+    sched = MicroBatchScheduler(FakeCache(), None, clock=clock,
+                                tracer=tracer)
+    reqs = _reqs(6)
+    for r in reqs:
+        sched.submit(r)
+    sched.step(drain=True)
+    sched.finalize()
+    doc = tracer.to_chrome()
+    names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
+    assert {"admit", "h2d", "launch", "readback"} <= names
+    chains = request_chains(doc)
+    assert set(chains) == {r.rid for r in reqs}
+    for c in chains.values():
+        assert c["children"] == {"queue"}
+        assert c["member_of"] == {"form", "dispatch", "device",
+                                  "finalize"}
+        assert c["events"] == ()
+
+
+class CountingClock(ManualClock):
+    def __init__(self):
+        super().__init__()
+        self.reads = 0
+
+    def __call__(self) -> float:
+        self.reads += 1
+        return super().__call__()
+
+
+@pytest.mark.parametrize("n", [4, 11])
+def test_clock_reads_without_a_tracer_are_pinned(n):
+    """Without a tracer the scheduler reads its clock once per request
+    (arrival), twice per step (expiry sweep, retry requeue) and twice
+    per batch (dispatch, finalize), and nothing more.  With a tracer on
+    the same clock, every extra read is a span boundary."""
+    reads = {}
+    for traced in (False, True):
+        clock = CountingClock()
+        tracer = Tracer(clock=clock) if traced else None
+        sched = MicroBatchScheduler(FakeCache(), None, clock=clock,
+                                    tracer=tracer)
+        for r in _reqs(n):
+            sched.submit(r)
+        sched.step(drain=True)
+        sched.finalize()
+        reads[traced] = clock.reads
+    batches = len(BucketedPolicy().form(n, (1, 2, 4), due=True))
+    assert reads[False] == n + 2 + 2 * batches
+    assert reads[True] == reads[False] + 2 * len(tracer.spans())
 
 
 # -- drift report math on a scripted timer ---------------------------------
