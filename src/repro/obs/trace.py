@@ -44,9 +44,13 @@ threaded (and does no tracing work at all without one):
     dispatch   root of one batch: executor lookup, stacking and padding,
                the copy and launch below, telemetry
     h2d        child of dispatch: the host->device copy of the input batch
-    launch     child of dispatch: the executor call (asynchronous enqueue)
+    launch     child of dispatch: the executor call (asynchronous enqueue);
+               attr ``inflight``, the batches already in flight (0: the
+               device had nothing queued)
     device     root: a batch in flight, dispatched -> read back on the host
-    readback   child of device: the host blocked on the batch's output
+    readback   child of device: the host waiting on the batch's output,
+               from when the host loop starts to wait on it (lock
+               released) or ``finalize`` reads it, until it is on the host
     finalize   scatter of one read-back batch onto its requests
 
 ## Trace JSON schema (``export`` / ``to_chrome``)

@@ -10,11 +10,12 @@ discipline of the LM engine (``serving.engine``) translated to vision:
 there slots free per token, here buckets form per dispatch.
 
 Dispatches are asynchronous: ``step()`` hands padded batches to the
-compiled executors and returns without any host/device sync; the device
-pipeline stays busy across chunks (the old ``VisionEngine.logits`` host
-loop implicitly serialized on each chunk's result).  ``finalize()``
-materializes outstanding outputs, scatters logits back onto their
-requests and stamps completion latency into telemetry.
+compiled executors and returns without any host/device sync.
+``finalize()`` materializes every outstanding output, in dispatch
+order, scatters logits back onto their requests and stamps completion
+latency into telemetry.  The async host loop (below) instead completes
+one batch at a time and dispatches again before it waits on the next,
+so the batches still queued on the device cover the host's hand-off.
 
 Wall-clock is injectable (``clock=``): the serving benchmark replays
 recorded traces on a manual clock, so queue-wait and deadline behavior
@@ -51,12 +52,22 @@ its retry budget against an empty mesh.
 
 ## The async host loop
 
-``start()`` moves ``step()``/``finalize()`` onto a background thread
-behind the (bounded) admission queue: ``submit()`` returns immediately,
-``wait()`` blocks until a request set is terminal, ``stop()`` drains
-and joins.  Every public entry point locks the same RLock, so the
-foreground/background interleaving cannot corrupt queue state.  A
-*watchdog* (``watchdog_ms``) sweeps dispatched-but-unmaterialized
+``start()`` runs the scheduler on a background thread behind the
+(bounded) admission queue: ``submit()`` returns immediately, ``wait()``
+blocks until a request set is terminal, ``stop()`` drains and joins.
+Every public entry point locks the same RLock, so the
+foreground/background interleaving cannot corrupt queue state.  Each
+turn of the loop ``step()``s, then waits for the oldest batch in flight
+with that lock released (``block_until_ready``), so ``submit()`` lands
+while the device computes.  It then retakes the lock, completes the
+batches at the front of the in-flight list whose outputs are ready (at
+least the oldest, in dispatch order), wakes ``wait()``ers and steps
+again: a refill is launched while the batches behind the one just read
+still run, so the device does not drain between turns.  The in-flight
+list is read again after the wait, since the watchdog or a foreground
+``finalize()`` may have taken the batch meanwhile.
+
+A *watchdog* (``watchdog_ms``) sweeps dispatched-but-unmaterialized
 batches: one that has been in flight longer than the bound is declared
 hung — a typed ``DeadlineExceeded`` routed through the same failure
 path, so the ladder moves and the requests retry on a rebuilt executor
@@ -220,6 +231,29 @@ class FixedMicrobatchPolicy:
         return sizes
 
 
+@dataclasses.dataclass(eq=False)
+class _InFlight:
+    """One dispatched batch not yet read back.  ``rspan`` is its open
+    ``readback`` span once the host loop waits on it with the lock
+    released, so whoever completes it ends that span."""
+    out: object
+    reqs: List[Request]
+    key: tuple
+    ex: object
+    t_disp: float
+    devspan: Optional[object] = None
+    rspan: Optional[object] = None
+
+
+def _ready(out) -> bool:
+    """Whether reading ``out`` would return without waiting on the
+    device: a device array that says so, or a host array."""
+    is_ready = getattr(out, "is_ready", None)
+    if is_ready is not None:
+        return bool(is_ready())
+    return getattr(out, "block_until_ready", None) is None
+
+
 class MicroBatchScheduler:
     """Admission queues + batch formation + async dispatch over an
     ``ExecutorCache``.
@@ -267,9 +301,7 @@ class MicroBatchScheduler:
         self.results = ResultCache(result_cache) \
             if result_cache is not None else None
         self._queues: dict[int, collections.deque] = {}
-        # in flight: (device_out, requests, bucket_key, executor, t_disp,
-        #             device_span) — the watchdog indexes t_disp at [4]
-        self._pending: list = []
+        self._pending: List[_InFlight] = []    # in dispatch order
         self._retry: list = []       # (not_before, resolution, requests)
         self._lock = threading.RLock()
         self._work = threading.Condition(self._lock)
@@ -318,7 +350,8 @@ class MicroBatchScheduler:
         A result-cache hit completes the request here — in front of
         admission, before the queue bound is even consulted."""
         # "admit": how long this client waited for the scheduler lock
-        # (held by a finalize blocked on the device, or by step)
+        # (held by step, by the loop completing a batch, or by a
+        # caller's finalize blocked on the device)
         aspan = None
         if self.tracer is not None:
             aspan = self.tracer.begin("admit", track="client", rid=req.rid)
@@ -373,7 +406,7 @@ class MicroBatchScheduler:
         with self._lock:
             return (self.queue_depth()
                     + sum(len(reqs) for _, _, reqs in self._retry)
-                    + sum(len(e[1]) for e in self._pending))
+                    + sum(len(b.reqs) for b in self._pending))
 
     # -- batch formation + dispatch -------------------------------------
     def _due(self, q) -> bool:
@@ -485,9 +518,13 @@ class MicroBatchScheduler:
             hspan = self.tracer.begin("h2d", parent=dspan, bucket=bucket,
                                       bytes=imgs.nbytes)
         x = jnp.asarray(imgs)                    # the host->device copy
+        # batches already in flight: 0 means the device has nothing
+        # queued behind which this launch could wait
+        inflight = len(self._pending)
         if self.tracer is not None:
             self.tracer.end(hspan)
-            lspan = self.tracer.begin("launch", parent=dspan)
+            lspan = self.tracer.begin("launch", parent=dspan,
+                                      inflight=inflight)
         try:
             out = ex(self.params, x)             # async, no host sync
         except ReproError as e:
@@ -497,6 +534,8 @@ class MicroBatchScheduler:
             return
         if self.tracer is not None:
             self.tracer.end(lspan)
+        if not inflight:
+            self.telemetry.count("launch_into_empty")
         self.telemetry.record_dispatch(
             key, len(reqs), bucket,
             queue_depth=len(self._queues.get(resolution, ())),
@@ -511,7 +550,7 @@ class MicroBatchScheduler:
             devspan = self.tracer.begin(
                 "device", rids=rids, bucket=bucket, resolution=resolution,
                 devices=list(getattr(ex, "device_ids", ()) or ()))
-        self._pending.append((out, reqs, key, ex, now, devspan))
+        self._pending.append(_InFlight(out, reqs, key, ex, now, devspan))
         self._t_end(dspan)
 
     # -- failure handling: retry/backoff + the degradation ladder --------
@@ -609,63 +648,69 @@ class MicroBatchScheduler:
         """
         with self._lock:
             self._check_watchdog()
-            done = 0
             pending, self._pending = self._pending, []
-            for out, reqs, key, ex, _t, devspan in pending:
-                # "readback": the host blocked on this batch's answer
-                rspan = None
-                if self.tracer is not None:
-                    rspan = self.tracer.begin("readback", parent=devspan,
-                                              bucket=key[0])
-                try:
-                    arr = np.asarray(out)          # sync on this chunk
-                except ReproError as e:
-                    self._t_end(rspan, error=type(e).__name__)
-                    self._t_end(devspan, error=type(e).__name__)
-                    self._on_failure(key[1], reqs, key, e, ex=ex)
-                    continue
-                except Exception as e:             # untyped XLA crash
-                    self._t_end(rspan, error=type(e).__name__)
-                    self._t_end(devspan, error=type(e).__name__)
-                    self._on_failure(key[1], reqs, key, ExecutorError(
-                        f"materializing executor {key} output failed: "
-                        f"{e}"), ex=ex)
-                    continue
-                if self.tracer is not None:
-                    self.tracer.end(rspan)
-                self._t_end(devspan)
-                fspan = None
-                if self.tracer is not None:
-                    fspan = self.tracer.begin(
-                        "finalize", rids=[r.rid for r in reqs],
-                        bucket=key[0], resolution=key[1])
-                if not np.all(np.isfinite(arr[:len(reqs)])):
-                    self._t_end(fspan, error="NumericsError")
-                    self._on_failure(key[1], reqs, key, NumericsError(
-                        f"non-finite logits delivered by executor {key} "
-                        f"(int8 epilogue blow-up signature)", key=key),
-                        ex=ex)
-                    continue
-                t = self.clock()
-                healthy = (getattr(ex, "degraded", None) is None
-                           or not ex.degraded.degraded)
-                for i, r in enumerate(reqs):
-                    assert r.status == "pending", (r.rid, r.status)
-                    r.logits = arr[i]
-                    r.status = "completed"
-                    # only undegraded, finite results may be replayed
-                    if self.results is not None and healthy \
-                            and self.results.put(r.image, arr[i]):
-                        self.telemetry.count("result_cache_store")
-                    self._t_close(r, "completed")
-                self.telemetry.record_latency(
-                    key, [(t - r.arrival) * 1e3 for r in reqs])
-                self._t_end(fspan)
-                done += len(reqs)
-            self.telemetry.count("completed", done)
-            if done:
-                self._work.notify_all()
-            return done
+            return self._completed(sum(self._complete(b) for b in pending))
+
+    def _completed(self, done: int) -> int:
+        self.telemetry.count("completed", done)
+        if done:
+            self._work.notify_all()
+        return done
+
+    def _complete(self, b: _InFlight) -> int:
+        """Read one batch back (blocking), check it and scatter its logits
+        onto its requests; returns the number completed, 0 when the batch
+        went to ``_on_failure`` instead.  The caller holds the lock and
+        has taken ``b`` off ``_pending``."""
+        reqs, key, ex = b.reqs, b.key, b.ex
+        # "readback": the host blocked on this batch's answer; the host
+        # loop opened it already if it waited on the device first
+        rspan = b.rspan
+        if self.tracer is not None and rspan is None:
+            rspan = self.tracer.begin("readback", parent=b.devspan,
+                                      bucket=key[0])
+        try:
+            arr = np.asarray(b.out)                # sync on this chunk
+        except ReproError as e:
+            self._t_end(rspan, error=type(e).__name__)
+            self._t_end(b.devspan, error=type(e).__name__)
+            self._on_failure(key[1], reqs, key, e, ex=ex)
+            return 0
+        except Exception as e:                     # untyped XLA crash
+            self._t_end(rspan, error=type(e).__name__)
+            self._t_end(b.devspan, error=type(e).__name__)
+            self._on_failure(key[1], reqs, key, ExecutorError(
+                f"materializing executor {key} output failed: {e}"), ex=ex)
+            return 0
+        self._t_end(rspan)
+        self._t_end(b.devspan)
+        fspan = None
+        if self.tracer is not None:
+            fspan = self.tracer.begin(
+                "finalize", rids=[r.rid for r in reqs],
+                bucket=key[0], resolution=key[1])
+        if not np.all(np.isfinite(arr[:len(reqs)])):
+            self._t_end(fspan, error="NumericsError")
+            self._on_failure(key[1], reqs, key, NumericsError(
+                f"non-finite logits delivered by executor {key} "
+                f"(int8 epilogue blow-up signature)", key=key), ex=ex)
+            return 0
+        t = self.clock()
+        healthy = (getattr(ex, "degraded", None) is None
+                   or not ex.degraded.degraded)
+        for i, r in enumerate(reqs):
+            assert r.status == "pending", (r.rid, r.status)
+            r.logits = arr[i]
+            r.status = "completed"
+            # only undegraded, finite results may be replayed
+            if self.results is not None and healthy \
+                    and self.results.put(r.image, arr[i]):
+                self.telemetry.count("result_cache_store")
+            self._t_close(r, "completed")
+        self.telemetry.record_latency(
+            key, [(t - r.arrival) * 1e3 for r in reqs])
+        self._t_end(fspan)
+        return len(reqs)
 
     # -- the watchdog ----------------------------------------------------
     def _check_watchdog(self) -> int:
@@ -683,30 +728,34 @@ class MicroBatchScheduler:
             return 0
         now = self.clock()
         keep, hung = [], []
-        for entry in self._pending:
-            (hung if now - entry[4] > self.watchdog_ms / 1e3
-             else keep).append(entry)
+        for b in self._pending:
+            (hung if now - b.t_disp > self.watchdog_ms / 1e3
+             else keep).append(b)
         self._pending = keep
-        for _out, reqs, key, ex, t, devspan in hung:
+        for b in hung:
+            key = b.key
             self.telemetry.count("watchdog_fired")
-            self._t_end(devspan, error="watchdog")
-            for r in reqs:
+            self._t_end(b.rspan, error="watchdog")
+            self._t_end(b.devspan, error="watchdog")
+            for r in b.reqs:
                 self._t_event(r, "watchdog_fired", bucket=key[0])
-            self._on_failure(key[1], reqs, key, DeadlineExceeded(
-                f"batch {key} in flight for {(now - t) * 1e3:.0f} ms "
-                f"(watchdog bound {self.watchdog_ms:g} ms) — declared "
-                f"hung", key=key), ex=ex)
+            self._on_failure(key[1], b.reqs, key, DeadlineExceeded(
+                f"batch {key} in flight for {(now - b.t_disp) * 1e3:.0f} "
+                f"ms (watchdog bound {self.watchdog_ms:g} ms) — declared "
+                f"hung", key=key), ex=b.ex)
         return len(hung)
 
     # -- the async host loop ---------------------------------------------
     def start(self, poll_s: float = 0.002) -> "MicroBatchScheduler":
-        """Run ``step()``/``finalize()`` on a background thread.
+        """Run the host loop on a background thread.
 
         ``submit()`` then behaves as the async front door: it enqueues
         (or sheds) and returns; the loop forms batches as they become
-        ready and materializes results.  ``poll_s`` bounds how long the
-        loop sleeps when idle — deadline flushes, backoff expiry and
-        the watchdog are all polled at least this often.
+        ready and completes them one at a time, oldest first, waiting on
+        the device with the lock released.  ``poll_s`` bounds how long
+        the loop sleeps when nothing is in flight — deadline flushes,
+        backoff expiry and the watchdog are all polled at least this
+        often.
         """
         with self._lock:
             if self._thread is not None:
@@ -728,9 +777,28 @@ class MicroBatchScheduler:
                 if self._stopping:
                     return
                 self.step()
-                if self._pending:
-                    self.finalize()
-                self._work.wait(timeout=poll_s)
+                if not self._pending:
+                    self._work.wait(timeout=poll_s)
+                    continue
+                oldest = self._pending[0]
+                if self.tracer is not None:
+                    oldest.rspan = self.tracer.begin(
+                        "readback", parent=oldest.devspan,
+                        bucket=oldest.key[0])
+            # wait on the device with the lock free, so submit() lands
+            wait = getattr(oldest.out, "block_until_ready", None)
+            if wait is not None:
+                try:
+                    wait()
+                except Exception:
+                    pass        # the read under the lock raises it again
+            with self._lock:
+                # the watchdog or a caller's finalize() may have taken it
+                done = 0
+                while self._pending and (self._pending[0] is oldest
+                                         or _ready(self._pending[0].out)):
+                    done += self._complete(self._pending.pop(0))
+                self._completed(done)
 
     def stop(self, *, drain: bool = True) -> None:
         """Join the host loop; ``drain=True`` first serves everything

@@ -8,6 +8,7 @@ idiom: host-only scripted executors, so hundreds of span assertions
 stay fast and deterministic."""
 import json
 import math
+import os
 import subprocess
 import sys
 import threading
@@ -409,6 +410,252 @@ def test_clock_reads_without_a_tracer_are_pinned(n):
     batches = len(BucketedPolicy().form(n, (1, 2, 4), due=True))
     assert reads[False] == n + 2 + 2 * batches
     assert reads[True] == reads[False] + 2 * len(tracer.spans())
+
+
+# -- the async loop waits on the device with the lock released -------------
+
+class HeldOutput:
+    """A device output that is not ready until ``gate`` is set; records
+    whether the thread that waits on it holds the scheduler's lock."""
+
+    def __init__(self, value, gate, sched, waits):
+        self.value, self.gate, self.sched, self.waits = \
+            value, gate, sched, waits
+
+    def is_ready(self):
+        return self.gate.is_set()
+
+    def block_until_ready(self):
+        self.waits.append(self.sched._lock._is_owned())
+        assert self.gate.wait(timeout=30.0)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        assert self.gate.wait(timeout=30.0)
+        return self.value
+
+
+class HeldCache(FakeCache):
+    """Bucket 4 only; batch k (from 0) answers k on every logit, held
+    back by one gate."""
+
+    def __init__(self, gate):
+        super().__init__(buckets=(4,))
+        self.gate, self.sched, self.waits, self.calls = gate, None, [], 0
+
+    def get(self, batch, resolution):
+        def run(params, x):
+            out = np.full((int(x.shape[0]), 4), float(self.calls),
+                          np.float32)
+            self.calls += 1
+            return HeldOutput(out, self.gate, self.sched, self.waits)
+        return run
+
+
+def _until(cond, timeout_s=10.0):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+
+
+def test_async_loop_waits_on_the_device_with_the_lock_released():
+    """While batch 1 is held on the device, a submit from another thread
+    lands at once, and a second full bucket launches behind it
+    (``inflight`` 1) before batch 1's readback ends.  Once released,
+    every request completes exactly once, in dispatch order."""
+    gate = threading.Event()
+    cache = HeldCache(gate)
+    tracer = Tracer()
+    sched = MicroBatchScheduler(cache, None, clock=ManualClock(),
+                                tracer=tracer)
+    cache.sched = sched
+    first, second = _reqs(8)[:4], _reqs(8)[4:]
+    sched.start(poll_s=0.001)
+    try:
+        for r in first:
+            sched.submit(r)
+        _until(lambda: cache.waits)      # the loop waits on batch 1
+        client = threading.Thread(
+            target=lambda: [sched.submit(r) for r in second])
+        client.start()
+        client.join(timeout=1.0)
+        assert not client.is_alive(), "submit blocked behind the device"
+        assert sched.queue_depth() == 4
+        assert sched.step() == 4         # the lock is free to step too
+        assert [r.status for r in first + second] == ["pending"] * 8
+        launches = tracer.spans("launch")
+        assert [s.attrs["inflight"] for s in launches] == [0, 1]
+        open_rb = [s for s in tracer.open_spans() if s.name == "readback"]
+        assert len(open_rb) == 1
+        gate.set()
+        assert sched.wait(first + second, timeout_s=10.0)
+    finally:
+        gate.set()
+        sched.stop()
+    assert cache.waits and not any(cache.waits)
+    rb1 = open_rb[0]
+    dev1 = next(s for s in tracer.spans("device")
+                if s.span_id == rb1.parent_id)
+    assert dev1.attrs["rids"] == [0, 1, 2, 3]
+    assert launches[1].end_ts < rb1.end_ts
+    for r in first + second:
+        assert r.status == "completed"
+        np.testing.assert_array_equal(r.logits, [r.rid // 4] * 4)
+    assert cache.telemetry.counters["completed"] == 8
+    assert cache.telemetry.counters["launch_into_empty"] == 1
+    assert [s.attrs["rids"] for s in tracer.spans("finalize")] == \
+        [[0, 1, 2, 3], [4, 5, 6, 7]]
+    assert [s.attrs["status"] for s in tracer.spans("request")] == \
+        ["completed"] * 8
+    assert len(tracer.spans("readback")) == 2
+    assert not tracer.open_spans(), [s.name for s in tracer.open_spans()]
+
+
+class FaultyOutput:
+    """A device output whose computation failed: waiting and reading
+    both raise."""
+
+    def __init__(self, sched, waits):
+        self.sched, self.waits = sched, waits
+
+    def block_until_ready(self):
+        self.waits.append(self.sched._lock._is_owned())
+        raise ExecutorError("materialization fault")
+
+    def __array__(self, dtype=None, copy=None):
+        raise ExecutorError("materialization fault")
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_async_loop_routes_a_materialization_error_through_retry(traced):
+    """The first batch fails when the loop waits on it (lock released)
+    and again when it is read (lock held): the failure path retries it
+    and it completes.  ``launch_into_empty`` counts the first launch and
+    the retry's, not the launch made behind the first batch."""
+    cache = FakeCache(buckets=(4,))
+    waits = []
+    sched = MicroBatchScheduler(cache, None, clock=ManualClock(),
+                                backoff_ms=0.0,
+                                tracer=Tracer() if traced else None)
+    launches = []
+
+    def get(batch, resolution):
+        def run(params, x):
+            launches.append(len(launches))
+            if len(launches) == 1:
+                return FaultyOutput(sched, waits)
+            return np.full((int(x.shape[0]), 4), 1.0, np.float32)
+        return run
+
+    cache.get = get
+    reqs = _reqs(8)
+    for r in reqs:
+        sched.submit(r)                  # both buckets launch in one step
+    sched.start(poll_s=0.001)
+    try:
+        assert sched.wait(reqs, timeout_s=10.0)
+    finally:
+        sched.stop()
+    assert waits == [False]
+    assert [r.status for r in reqs] == ["completed"] * 8
+    assert [r.retries for r in reqs] == [1] * 4 + [0] * 4
+    tel = cache.telemetry.counters
+    assert tel["dispatch_failures"] == 1 and tel["retries"] == 4
+    assert len(launches) == 3
+    assert tel["launch_into_empty"] == 2
+    if traced:
+        assert [s.attrs["inflight"] for s in
+                sched.tracer.spans("launch")] == [0, 1, 0]
+        rb = sched.tracer.spans("readback")
+        assert rb[0].attrs["error"] == "ExecutorError"
+        assert not sched.tracer.open_spans()
+
+
+class TimedOutput:
+    """A device output that becomes ready ``delay_s`` after its launch."""
+
+    def __init__(self, value, delay_s):
+        self.value, self.ready_at = value, time.monotonic() + delay_s
+
+    def is_ready(self):
+        return time.monotonic() >= self.ready_at
+
+    def block_until_ready(self):
+        time.sleep(max(0.0, self.ready_at - time.monotonic()))
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.block_until_ready()
+        return self.value
+
+
+def test_async_loop_stress_with_concurrent_submits_and_finalize():
+    """More submitter threads than cores, a foreground ``finalize()``
+    racing the loop for the batch it waits on, and a short switch
+    interval: every request completes exactly once with its own answer,
+    and every batch has exactly one closed readback."""
+    n_threads = (os.cpu_count() or 1) + 2
+    per_thread = 12
+    cache = FakeCache(buckets=(1, 2, 4))
+
+    def get(batch, resolution):
+        def run(params, x):
+            x = np.asarray(x)
+            return TimedOutput(x[:, 0, 0, :1].copy(),
+                               0.0003 * (len(tracer.spans("launch")) % 5))
+        return run
+
+    cache.get = get
+    tracer = Tracer()
+    sched = MicroBatchScheduler(cache, None, clock=ManualClock(),
+                                tracer=tracer)
+    groups = [[Request(rid=g * per_thread + i, image=np.full(
+        (8, 8, 3), g * per_thread + i, np.float32))
+        for i in range(per_thread)] for g in range(n_threads)]
+    flat = [r for g in groups for r in g]
+    switch, hook = sys.getswitchinterval(), threading.excepthook
+    died = []
+    threading.excepthook = lambda args: died.append(args.exc_value)
+    sys.setswitchinterval(1e-5)
+    stop = threading.Event()
+
+    def finalizer():
+        while not stop.is_set():
+            sched.finalize()
+            time.sleep(0.002)
+
+    try:
+        sched.start(poll_s=0.001)
+        threads = [threading.Thread(
+            target=lambda g=g: [sched.submit(r) for r in g])
+            for g in groups] + [threading.Thread(target=finalizer)]
+        for t in threads:
+            t.start()
+        for t in threads[:-1]:
+            t.join(timeout=60.0)
+            assert not t.is_alive()
+        # the ragged tail never comes due on a manual clock: stop drains it
+        stop.set()
+        threads[-1].join(timeout=60.0)
+        assert not threads[-1].is_alive()
+        sched.stop(drain=True)
+    finally:
+        stop.set()
+        sys.setswitchinterval(switch)
+        threading.excepthook = hook
+        sched.stop()
+    assert not died, died               # the loop thread never raised
+    for r in flat:
+        assert r.status == "completed", (r.rid, r.status)
+        np.testing.assert_array_equal(r.logits, [r.rid])
+    assert cache.telemetry.counters["completed"] == len(flat)
+    devices = tracer.spans("device")
+    readbacks = tracer.spans("readback")
+    assert sum(len(d.attrs["rids"]) for d in devices) == len(flat)
+    assert sorted(rb.parent_id for rb in readbacks) == \
+        sorted(d.span_id for d in devices)
+    assert not tracer.open_spans(), [s.name for s in tracer.open_spans()]
 
 
 # -- drift report math on a scripted timer ---------------------------------
