@@ -2,7 +2,30 @@
 configuration and traffic mix; their files sit under ``bench/configs``
 and ``bench/traffic``, a configuration's plain reference beside it
 (``bench/configs/<reference>.py``), each per-layer metric's reader at
-``bench/metrics/<name>.py``."""
+``bench/metrics/<name>.py``.
+
+A configuration's file keeps the harness's keys at its top level
+(``name``, ``source``, ``reference``, ``image_size``, ``precision``,
+``matmul_precision``, ``peak``, ``control``, ``correct``, ``assumed``)
+and the program's architecture in one ``model`` object: every field of
+the program's ``EfficientViTConfig`` but ``name`` and ``image_size``
+that the configuration sets (``system.model_config``; a key the program
+has no field for refuses the cell before any weights are made).
+
+A reference module (``bench/configs/<reference>.py``) is plain JAX that
+imports nothing of the program, and gives
+
+* ``init_params(key, cfg)``: the fp32 weights, in the tree the program
+  serves, drawn from ``key`` inside one jitted call;
+* ``images(key, n, size)``: ``n`` input images of ``size`` pixels;
+* ``forward(params, x, cfg, *, quant_bits, products)``: the logits, at
+  the configuration's arithmetic or at its control's;
+* ``macs_per_image(cfg)``: the work of one image, counted from sizes.
+
+A new configuration is added as new files only: its JSON, its reference
+where no existing one computes it, and any per-layer metric readers;
+then appends to ``BENCHMARK.json``'s ``configs``, ``workloads`` and the
+metrics' ``workloads`` lists."""
 from __future__ import annotations
 
 import dataclasses

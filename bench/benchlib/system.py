@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from benchlib.spec import SpecError
 from benchlib.traffic import Mix, Schedule
 
 clock = time.perf_counter
@@ -59,15 +60,35 @@ def make_inputs(ref, cfg: dict, pool: int, seed: int):
     return jax.block_until_ready(params), jax.device_get(images)
 
 
+def frozen(value):
+    """Lists as tuples, at every depth: the program caches its lowering
+    on the hashed config."""
+    if isinstance(value, (list, tuple)):
+        return tuple(frozen(v) for v in value)
+    return value
+
+
 def model_config(cfg: dict):
+    """The program's ``EfficientViTConfig`` of a configuration: its
+    ``name`` and ``image_size`` and every key of its ``model``.  A key
+    the program has no field for refuses the configuration."""
     from repro.core.efficientvit import EfficientViTConfig
-    return EfficientViTConfig(
-        name=cfg["name"], widths=tuple(cfg["widths"]),
-        depths=tuple(cfg["depths"]), head_dim=cfg["head_dim"],
-        msa_scales=tuple(cfg["msa_scales"]),
-        expand_ratio=cfg["expand_ratio"],
-        head_widths=tuple(cfg["head_widths"]),
-        num_classes=cfg["num_classes"], image_size=cfg["image_size"])
+    model = cfg.get("model")
+    if not isinstance(model, dict):
+        raise SpecError(f"configuration {cfg['name']}: no \"model\" object "
+                        f"with the program's architecture")
+    fields = {f.name for f in dataclasses.fields(EfficientViTConfig)}
+    for key in model:
+        if key in ("name", "image_size"):
+            raise SpecError(f"configuration {cfg['name']}: \"model\" "
+                            f"repeats {key!r}, a top-level key")
+        if key not in fields:
+            raise SpecError(f"configuration {cfg['name']}: model key "
+                            f"{key!r} is not a field of this checkout's "
+                            f"EfficientViTConfig, so its program cannot "
+                            f"serve this configuration")
+    return EfficientViTConfig(name=cfg["name"], image_size=cfg["image_size"],
+                              **{k: frozen(v) for k, v in model.items()})
 
 
 def served_tree(params, cfg: dict):

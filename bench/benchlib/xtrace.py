@@ -49,14 +49,22 @@ def _stats(ev) -> dict:
         return {}
 
 
-_HLO = re.compile(r"%?([\w.\-]+) = (\w+\[[^\]]*\])\S* ([\w\-]+)\(")
+_SHAPE = r"\w+\[[^\]]*\]"
+_HLO = re.compile(rf"%?([\w.\-]+) = ({_SHAPE}|\(.*?\))\S* ([\w\-]+)\(")
 
 
 def short_name(name: str) -> str:
     """``%fusion.3 = f32[8,56,56,32]{...} fusion(...), ...`` (how the TPU
-    names an op: its HLO text) -> ``fusion.3 fusion f32[8,56,56,32]``."""
+    names an op: its HLO text) -> ``fusion.3 fusion f32[8,56,56,32]``;
+    a tuple result ``(s8[8,56,56,32]{...}, f32[8]{...})`` keeps its
+    shapes, ``(s8[8,56,56,32], f32[8])``."""
     m = _HLO.match(name)
-    return f"{m[1]} {m[3]} {m[2]}" if m else name
+    if not m:
+        return name
+    shape = m[2]
+    if shape.startswith("("):
+        shape = f"({', '.join(re.findall(_SHAPE, shape))})"
+    return f"{m[1]} {m[3]} {shape}"
 
 
 def is_custom_call(name: str, stats: dict) -> bool:

@@ -2,7 +2,8 @@
 for the benchmark's configurations, written against the paper and
 independent of the program under test: it imports nothing from ``src/``.
 
-Three things live here, all driven by a configuration file's sizes:
+Three things live here, all driven by the architecture in a
+configuration file's ``model`` object and its ``image_size``:
 
 * ``init_params``: random weights from a key, in the layout the served
   engine takes (conv + BatchNorm pairs, bare MSA convs, two fc layers).
@@ -87,7 +88,8 @@ def init_params(key, cfg: dict):
     """Random weights for ``cfg`` (fan-in scaled normals, BatchNorm
     statistics drawn near identity)."""
     keys = _Keys(key)
-    w, d, e = cfg["widths"], cfg["depths"], cfg["expand_ratio"]
+    m = cfg["model"]
+    w, d, e = m["widths"], m["depths"], m["expand_ratio"]
     p = {"stem_conv": _conv_bn(keys, 3, 3, w[0]),
          "stem_ds": [{"dw": _conv_bn(keys, 3, w[0], w[0], groups=w[0]),
                       "pw": _conv_bn(keys, 1, w[0], w[0])}
@@ -98,16 +100,16 @@ def init_params(key, cfg: dict):
     for si in (3, 4):
         p[f"stage{si}"] = {
             "down": _mbconv(keys, w[si - 1], w[si], e),
-            "blocks": [{"msa": _msa(keys, w[si], cfg["head_dim"],
-                                    cfg["msa_scales"]),
+            "blocks": [{"msa": _msa(keys, w[si], m["head_dim"],
+                                    m["msa_scales"]),
                         "mbconv": _mbconv(keys, w[si], w[si], e)}
                        for _ in range(d[si])]}
-    hw1, hw2 = cfg["head_widths"]
+    hw1, hw2 = m["head_widths"]
     p["head"] = {"conv": _conv_bn(keys, 1, w[4], hw1),
                  "fc1": {"w": jax.random.normal(keys(), (hw1, hw2))
                          * hw1 ** -0.5},
                  "fc2": {"w": jax.random.normal(keys(), (hw2,
-                                                        cfg["num_classes"]))
+                                                        m["num_classes"]))
                          * hw2 ** -0.5}}
     return p
 
@@ -259,7 +261,7 @@ def forward(params, x, cfg: dict, *, quant_bits=None, products="fp32"):
         st = params[f"stage{si}"]
         y = _mbconv_fwd(a, st["down"], y, stride=2)
         for blk in st["blocks"]:
-            y = y + _msa_fwd(a, blk["msa"], y, cfg["head_dim"])
+            y = y + _msa_fwd(a, blk["msa"], y, cfg["model"]["head_dim"])
             y = y + _mbconv_fwd(a, blk["mbconv"], y)
     head = params["head"]
     y = jnp.mean(_conv_bn_act(a, head["conv"], y), axis=(1, 2))
@@ -276,8 +278,9 @@ def forward(params, x, cfg: dict, *, quant_bits=None, products="fp32"):
 def macs_per_image(cfg: dict) -> int:
     """Multiply-accumulates of one image: convolutions, attention
     products and fc layers (elementwise work is not counted)."""
-    w, d, e = cfg["widths"], cfg["depths"], cfg["expand_ratio"]
-    hd, scales = cfg["head_dim"], cfg["msa_scales"]
+    m = cfg["model"]
+    w, d, e = m["widths"], m["depths"], m["expand_ratio"]
+    hd, scales = m["head_dim"], m["msa_scales"]
     r = cfg["image_size"] // 2
     macs = r * r * w[0] * 3 * 9                                  # stem conv
     macs += d[0] * (r * r * w[0] * 9 + r * r * w[0] * w[0])       # DSConvs
@@ -308,6 +311,6 @@ def macs_per_image(cfg: dict) -> int:
             macs += n_br * heads * tok * hd * (hd + 1)           # Q [KV|ksum]
             macs += tok * n_br * total * c                       # proj
             macs += mbconv(r, r, c, c)
-    hw1, hw2 = cfg["head_widths"]
-    macs += r * r * w[4] * hw1 + hw1 * hw2 + hw2 * cfg["num_classes"]
+    hw1, hw2 = m["head_widths"]
+    macs += r * r * w[4] * hw1 + hw1 * hw2 + hw2 * m["num_classes"]
     return macs

@@ -179,6 +179,9 @@ def run_cell(args, require_tpu: bool = True) -> dict:
     sys.path.insert(0, str(ROOT / "src"))
     jax = configure_jax(cfg)
     peaks = check_device(jax, cell.chips) if require_tpu else {}
+    # a configuration this checkout's program cannot represent is
+    # refused now, before minutes of weights and compiles
+    system.model_config(cfg)
     compiles = CompileLog()
     ref = cell.reference
     import repro.serving.vision  # noqa: F401  (import time is set-up)

@@ -10,8 +10,9 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-TINY = {"widths": [8, 16, 24, 32, 48], "depths": [1, 1, 1, 1, 1],
-        "head_widths": [64, 64], "num_classes": 10, "image_size": 32}
+TINY = {"model": {"widths": [8, 16, 24, 32, 48], "depths": [1, 1, 1, 1, 1],
+                  "head_widths": [64, 64], "num_classes": 10},
+        "image_size": 32}
 
 
 def make(tmp: Path, with_src: bool = True) -> Path:
@@ -25,7 +26,8 @@ def make(tmp: Path, with_src: bool = True) -> Path:
 
 def tiny_config(base: str, name: str) -> dict:
     cfg = json.loads((BENCH / "configs" / f"{base}.json").read_text())
-    cfg.update(TINY, name=name)
+    cfg["model"].update(TINY["model"])
+    cfg.update(name=name, image_size=TINY["image_size"])
     return cfg
 
 

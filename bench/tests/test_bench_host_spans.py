@@ -103,7 +103,7 @@ def test_readers_return_nothing_without_the_new_spans(name):
     assert read(name, _run(spans)) is None
 
 
-def test_int8_offline_cell_is_found_with_all_nine_metrics():
+def test_int8_offline_cell_is_found_with_all_ten_metrics():
     cell = spec.load_cell("b1-r224-int8.offline")
     assert cell.chips == 1
     assert cell.config["name"] == "b1-r224-int8"
@@ -113,6 +113,7 @@ def test_int8_offline_cell_is_found_with_all_nine_metrics():
     assert [m["name"] for m in cell.end_to_end] == ["images_per_s",
                                                     "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert len(names) == 9 and set(NEW) <= set(names)
+    assert len(names) == 10 and set(NEW) <= set(names)
+    assert "launch_empty_share" in names
     for m in cell.per_layer:
         assert callable(spec.metric_reader(m["name"]))

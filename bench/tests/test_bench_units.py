@@ -105,4 +105,11 @@ def test_tpu_op_names_are_shortened():
     assert xtrace.short_name(name) == \
         "supersite_op.2 custom-call f32[8,64,56,32]"
     assert xtrace.is_custom_call(name, {})
+    tupled = ('%_supersite_op_int8.2 = (s8[8,56,56,32]{3,2,1,0:T(8,128)'
+              '(4,1)S(1)}, f32[8]{0:T(256)}) custom-call(s8[8,2,69,112,16]'
+              '{4,3,2,1,0:T(8,128)(4,1)} %pad, f32[8]{0} %scale), '
+              'custom_call_target="tpu_custom_call"')
+    assert xtrace.short_name(tupled) == \
+        "_supersite_op_int8.2 custom-call (s8[8,56,56,32], f32[8])"
+    assert xtrace.is_custom_call(tupled, {})
     assert xtrace.short_name("while.86") == "while.86"
