@@ -36,8 +36,10 @@ import jax.numpy as jnp
 
 from repro.common.errors import LoweringError
 from repro.core.efficientvit import (
-    B1, EfficientViTConfig, OpRecord, _act, conv_bn_act, dsconv, mbconv)
+    B1, EfficientViTConfig, OpRecord, activation, conv_bn_act, dsconv,
+    fmbconv, mbconv, resblock, stage_layout)
 from repro.core.relu_attention import MSAConfig, msa
+from repro.layers.norms import layernorm
 
 __all__ = ["Epilogue", "EPILOGUE_FP", "Site", "SuperSite", "Program",
            "lower", "execute", "manifest", "site_records", "FUSIBLE_KINDS",
@@ -87,12 +89,13 @@ class Epilogue:
 
 EPILOGUE_FP = Epilogue()
 
-# Structural kinds ``execute`` interprets inline; every OTHER kind is
-# fusible — it plans through the kernel registry, so a newly registered
-# kind (see kernels/registry.py's worked example) is schedulable the
-# moment ``lower`` emits its Site.  FUSIBLE_KINDS lists the built-ins.
-STRUCTURAL_KINDS = ("conv_bn", "gap", "fc")
-FUSIBLE_KINDS = ("dsconv", "mbconv", "msa")
+# Structural kinds ``execute`` interprets inline (a ResBlock runs as
+# two XLA convs); every OTHER kind is fusible — it plans through the
+# kernel registry, so a newly registered kind (see kernels/registry.py's
+# worked example) is schedulable the moment ``lower`` emits its Site.
+# FUSIBLE_KINDS lists the built-ins.
+STRUCTURAL_KINDS = ("conv_bn", "resblock", "gap", "fc")
+FUSIBLE_KINDS = ("dsconv", "mbconv", "fmbconv", "msa")
 # Conv-chain kinds the inter-layer super-site pass may group into one
 # launch (core.fusion.plan_program's grouping pass + kernels/supersite).
 SUPERSITE_KINDS = ("dsconv", "mbconv")
@@ -105,18 +108,23 @@ class Site:
     ``name`` is the dotted site id shared with ``FusionPlan`` decisions
     (e.g. ``"S3.evit0.msa"``); ``param_path`` indexes the param tree
     (str = dict key, int = list index); ``attrs`` carries kind-specific
-    geometry (mbconv: ``mid``; msa: ``heads``/``head_dim``/``scales``/
-    ``n_branches``; conv_bn: ``k``).
+    geometry (mbconv / fmbconv / resblock: ``mid``; msa: ``heads``/
+    ``head_dim``/``scales``/``n_branches``; conv_bn: ``k``; fc:
+    ``norm`` when a LayerNorm precedes the activation).
     """
     name: str
-    kind: str                  # conv_bn | dsconv | mbconv | msa | gap | fc
+    kind: str                  # conv_bn | dsconv | mbconv | fmbconv |
+    #                            resblock | msa | gap | fc
     stage: str                 # stem | S1..S4 | head
     param_path: Tuple[Any, ...]
     in_shape: Tuple[int, ...]  # (B, H, W, C) — (B, C) for fc
     out_shape: Tuple[int, ...]
     stride: int = 1
     residual: bool = False     # out = x + op(x)
-    act: bool = False          # trailing Hardswish (conv_bn / fc sites)
+    act: str = ""              # the activation the site applies:
+    #                            conv_bn / fc its trailing one, conv
+    #                            blocks the one inside ("" = none;
+    #                            core.efficientvit.ACTIVATIONS)
     attrs: Mapping[str, Any] = dataclasses.field(default_factory=dict)
     epilogue: Epilogue = EPILOGUE_FP   # producer-side output descriptor
     #                          (assigned by core.fusion.plan_program's
@@ -266,7 +274,8 @@ def params_at(params, path: Tuple[Any, ...]):
 # lower: cfg -> Program (the single architecture walk)
 # ---------------------------------------------------------------------------
 
-_SEQ_FIELDS = ("widths", "depths", "msa_scales", "head_widths")
+_SEQ_FIELDS = ("widths", "depths", "msa_scales", "head_widths",
+               "stage_blocks", "expand_ratios")
 
 
 def lower(cfg: EfficientViTConfig = B1, *, batch: int = 1,
@@ -279,7 +288,7 @@ def lower(cfg: EfficientViTConfig = B1, *, batch: int = 1,
     stay usable (the cache hashes the config).
     """
     repl = {f: tuple(v) for f in _SEQ_FIELDS
-            if not isinstance(v := getattr(cfg, f), tuple)}
+            if not isinstance(v := getattr(cfg, f), (tuple, type(None)))}
     if repl:
         cfg = dataclasses.replace(cfg, **repl)
     return _lower(cfg, batch, image_size)
@@ -315,10 +324,15 @@ def _validate_geometry(sites: Tuple[Site, ...], size: int) -> None:
         prev = s
 
 
+# stage_layout's block kinds -> Site kinds
+_SITE_KIND = {"ds": "dsconv", "res": "resblock", "mb": "mbconv",
+              "fmb": "fmbconv"}
+
+
 @functools.lru_cache(maxsize=64)
 def _lower(cfg: EfficientViTConfig, batch: int,
            image_size: int | None) -> Program:
-    w, d = cfg.widths, cfg.depths
+    w = cfg.widths
     size = image_size or cfg.image_size
     B = batch
     if B < 1:
@@ -328,54 +342,53 @@ def _lower(cfg: EfficientViTConfig, batch: int,
             f"image_size={size}: EfficientViT downsamples by 2 five "
             f"times (stem, S1, S2, S3.down, S4.down), so serving "
             f"resolutions must be multiples of 32 (192/224/256/...)")
+    try:
+        blocks = stage_layout(cfg)
+        activation(cfg.act)
+    except ValueError as e:
+        raise LoweringError(f"{cfg.name}: {e}") from None
+    if cfg.head_norm not in ("none", "ln"):
+        raise LoweringError(f"{cfg.name}: head_norm={cfg.head_norm!r}, "
+                            f"not 'none' or 'ln'")
+    act = cfg.act
     sites: list[Site] = []
     r = size // 2
 
     sites.append(Site("stem.conv1", "conv_bn", "stem", ("stem_conv",),
                       (B, size, size, 3), (B, r, r, w[0]), stride=2,
-                      act=True, attrs={"k": 3}))
-    for i in range(d[0]):
-        sites.append(Site(f"stem.ds{i}", "dsconv", "stem", ("stem_ds", i),
-                          (B, r, r, w[0]), (B, r, r, w[0]), residual=True))
-    for si in (1, 2):
-        c_in = w[si - 1]
-        for bi in range(d[si]):
-            stride = 2 if bi == 0 else 1
-            ro = r // stride
+                      act=act, attrs={"k": 3}))
+    for b in blocks:
+        ro = r // b.stride
+        shapes = ((B, r, r, b.c_in), (B, ro, ro, b.c_out))
+        name = f"{b.stage}.{b.name}"
+        if b.kind == "att":
             sites.append(Site(
-                f"S{si}.mb{bi}", "mbconv", f"S{si}", (f"stage{si}", bi),
-                (B, r, r, c_in), (B, ro, ro, w[si]), stride=stride,
-                residual=bi > 0, attrs={"mid": c_in * cfg.expand_ratio}))
-            r, c_in = ro, w[si]
-    for si in (3, 4):
-        c = w[si]
-        sites.append(Site(
-            f"S{si}.down", "mbconv", f"S{si}", (f"stage{si}", "down"),
-            (B, r, r, w[si - 1]), (B, r // 2, r // 2, c), stride=2,
-            attrs={"mid": w[si - 1] * cfg.expand_ratio}))
-        r //= 2
-        heads = c // cfg.head_dim
-        for bi in range(d[si]):
-            sites.append(Site(
-                f"S{si}.evit{bi}.msa", "msa", f"S{si}",
-                (f"stage{si}", "blocks", bi, "msa"),
-                (B, r, r, c), (B, r, r, c), residual=True,
-                attrs={"heads": heads, "head_dim": cfg.head_dim,
+                f"{name}.msa", "msa", b.stage, b.path + ("msa",), *shapes,
+                residual=True,
+                attrs={"heads": b.c_in // cfg.head_dim,
+                       "head_dim": cfg.head_dim,
                        "scales": tuple(cfg.msa_scales),
                        "n_branches": 1 + len(cfg.msa_scales)}))
             sites.append(Site(
-                f"S{si}.evit{bi}.mb", "mbconv", f"S{si}",
-                (f"stage{si}", "blocks", bi, "mbconv"),
-                (B, r, r, c), (B, r, r, c), residual=True,
-                attrs={"mid": c * cfg.expand_ratio}))
+                f"{name}.mb", "mbconv", b.stage, b.path + ("mbconv",),
+                *shapes, residual=True, act=act,
+                attrs={"mid": b.c_in * b.expand}))
+        else:
+            sites.append(Site(
+                name, _SITE_KIND[b.kind], b.stage, b.path, *shapes,
+                stride=b.stride, residual=b.residual, act=act,
+                attrs={} if b.kind == "ds" else {"mid": b.c_in * b.expand}))
+        r = ro
     hw1, hw2 = cfg.head_widths
     sites.append(Site("head.conv", "conv_bn", "head", ("head", "conv"),
-                      (B, r, r, w[4]), (B, r, r, hw1), act=True,
+                      (B, r, r, w[4]), (B, r, r, hw1), act=act,
                       attrs={"k": 1}))
     sites.append(Site("head.gap", "gap", "head", (),
                       (B, r, r, hw1), (B, hw1)))
     sites.append(Site("head.fc1", "fc", "head", ("head", "fc1"),
-                      (B, hw1), (B, hw2), act=True))
+                      (B, hw1), (B, hw2), act=act,
+                      attrs={"norm": "ln"} if cfg.head_norm == "ln"
+                      else {}))
     sites.append(Site("head.fc2", "fc", "head", ("head", "fc2"),
                       (B, hw2), (B, cfg.num_classes)))
     _validate_geometry(tuple(sites), size)
@@ -390,7 +403,8 @@ def _fc(p, h):
     if "qw" in p:
         from repro.core.quantization import matmul_int8
         return matmul_int8(h, p["qw"], p["scale"])
-    return jnp.einsum("bc,cf->bf", h, p["w"].astype(h.dtype))
+    y = jnp.einsum("bc,cf->bf", h, p["w"].astype(h.dtype))
+    return y + p["b"].astype(h.dtype) if "b" in p else y
 
 
 def _dispatch(site: Site, p, y, plan, cfg, attention_fn, kernel_ep):
@@ -430,11 +444,24 @@ def _dispatch(site: Site, p, y, plan, cfg, attention_fn, kernel_ep):
         return impl.apply(p, y, site, d, interpret=plan.interpret, **ep_kw)
     y = act_fp(y)
     if site.kind == "dsconv":
-        return dsconv(p, y, stride=site.stride)
+        return dsconv(p, y, stride=site.stride, act=site.act)
     if site.kind == "mbconv":
-        return mbconv(p, y, stride=site.stride)
+        return mbconv(p, y, stride=site.stride, act=site.act)
+    if site.kind == "fmbconv":
+        return fmbconv(p, y, stride=site.stride, act=site.act)
     from repro.kernels.registry import get_probe
     return get_probe(site.kind).ref(p, y, site)
+
+
+def _adds_residual(site: Site, plan) -> bool:
+    """The site's fused kernel adds the residual in-kernel
+    (``KernelImpl.adds_residual``), so ``execute`` must not add it again."""
+    d = plan.get(site.name) if plan is not None else None
+    if d is None or not d.fused:
+        return False
+    from repro.kernels.registry import get_kernel
+    return getattr(get_kernel(site.kind, d.precision), "adds_residual",
+                   False)
 
 
 def execute(program: Program, params, x, *, plan=None, attention_fn=None,
@@ -498,19 +525,24 @@ def execute(program: Program, params, x, *, plan=None, attention_fn=None,
                 # structural producer: XLA fuses the act-quant into the
                 # conv/BN epilogue — the boundary tensor is int8
                 y = quantize_act(y, keep_fp=ep.residual != "none")
+        elif site.kind == "resblock":
+            y = act_fp(y)
+            y = y + resblock(p, y, act=site.act)
         elif site.kind == "gap":
             y = jnp.mean(act_fp(y), axis=(1, 2))
         elif site.kind == "fc":
             y = _fc(p, act_fp(y))
+            if site.attrs.get("norm") == "ln":
+                y = layernorm(p["ln"], y)
             if site.act:
-                y = _act(y)
+                y = activation(site.act)(y)
         else:
             # the kernel only runs the epilogue itself for non-residual
             # sites; a residual producer's quantize applies post-add
             kernel_ep = ep if (ep is not None and ep.emits_q
                                and not site.residual) else None
             out = _dispatch(site, p, y, plan, cfg, attention_fn, kernel_ep)
-            if site.residual:
+            if site.residual and not _adds_residual(site, plan):
                 s = act_fp(y) + act_fp(out)
                 if ep is not None and ep.emits_q:   # "post-add" policy
                     y = quantize_act(s, keep_fp=True)
@@ -538,6 +570,21 @@ def _mbconv_records(site: Site) -> list[OpRecord]:
                  fused_with_prev=False),
         OpRecord(site.stage, f"{n}.pw2", "pw", Ho, Ho, mid, F,
                  fused_with_prev=True),
+    ]
+
+
+def _dense_pair_records(site: Site) -> list[OpRecord]:
+    """FusedMBConv (3x3 conv C -> mid at the stride, 1x1 mid -> F) and
+    ResBlock (3x3 C -> mid, 3x3 mid -> F): two dense convs each."""
+    _, _, _, C = site.in_shape
+    _, Ho, _, F = site.out_shape
+    mid = site.attrs["mid"]
+    n = site.local_name
+    k2 = 3 if site.kind == "resblock" else 1
+    return [
+        OpRecord(site.stage, f"{n}.conv1", "conv", Ho, Ho, C, mid, 3),
+        OpRecord(site.stage, f"{n}.conv2", "conv" if k2 > 1 else "pw",
+                 Ho, Ho, mid, F, k2, fused_with_prev=k2 == 1),
     ]
 
 
@@ -600,6 +647,8 @@ def site_records(program: Program) -> list[Tuple[Site, list[OpRecord]]]:
                                 fused_with_prev=True))
         elif site.kind == "mbconv":
             ops.extend(_mbconv_records(site))
+        elif site.kind in ("fmbconv", "resblock"):
+            ops.extend(_dense_pair_records(site))
         elif site.kind == "msa":
             ops.extend(_msa_records(site))
         elif site.kind == "fc":

@@ -213,9 +213,39 @@ def _is_conv_bn(node) -> bool:
     return isinstance(node, dict) and set(node) == {"conv", "bn"}
 
 
-def quantize_efficientvit(params):
+# param-tree blocks FIX8 has no int8 path for: their keys -> block kind
+_NO_INT8 = {frozenset({"conv1", "conv2"}): "resblock",
+            frozenset({"spatial", "point"}): "fmbconv"}
+
+
+def quantize_efficientvit(params, cfg=None):
     """Recursively fold+quantize every conv+BN block of an EfficientViT
-    param tree; bare convs (MSA qkv/aggreg/proj) get weight+act int8 too."""
+    param tree; bare convs (MSA qkv/aggreg/proj) get weight+act int8 too.
+
+    FIX8 covers the B series only.  A tree holding an L-series block
+    (ResBlock, FusedMBConv, a LayerNorm head) — or a ``cfg`` whose
+    activation is not Hardswish — raises ``ValueError`` naming it rather
+    than quantizing part of the tree."""
+    if cfg is not None and (cfg.act != "hswish"
+                            or cfg.head_norm != "none"):
+        raise ValueError(
+            f"quantize_efficientvit: FIX8 runs Hardswish networks with a "
+            f"plain head; {cfg.name} has act={cfg.act!r}, "
+            f"head_norm={cfg.head_norm!r}")
+
+    found: dict[str, str] = {}          # block kind -> first path
+
+    def check(node, path="params"):
+        if isinstance(node, dict):
+            kind = _NO_INT8.get(frozenset(node)) or (
+                "layernorm head" if "ln" in node else None)
+            if kind is not None:
+                found.setdefault(kind, path)
+            for k, v in node.items():
+                check(v, f"{path}.{k}")
+        elif isinstance(node, list):
+            for i, v in enumerate(node):
+                check(v, f"{path}[{i}]")
 
     def walk(node):
         if _is_conv_bn(node):
@@ -238,6 +268,11 @@ def quantize_efficientvit(params):
             return [walk(v) for v in node]
         return node
 
+    check(params)
+    if found:
+        raise ValueError("quantize_efficientvit: FIX8 has no int8 path for "
+                         + ", ".join(f"the {k!r} block at {p}"
+                                     for k, p in sorted(found.items())))
     return walk(params)
 
 

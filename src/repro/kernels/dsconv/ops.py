@@ -211,6 +211,9 @@ class DsconvKernel(KernelBase):
 
     def apply(self, params, x, site, decision=None, *, interpret=None,
               epilogue=None):
+        if site.act not in ("", "hswish"):
+            raise ValueError(f"{site.name}: the DSConv kernels run "
+                             f"Hardswish only, not {site.act!r}")
         blocks = decision.blocks if decision is not None else {}
         return dsconv_apply(params, x, stride=site.stride,
                             block_f=blocks.get("block_f", 128),
@@ -218,7 +221,7 @@ class DsconvKernel(KernelBase):
 
     def ref(self, params, x, site, *, epilogue=None, **kw):
         from repro.core.efficientvit import dsconv
-        out = dsconv(params, x, stride=site.stride)
+        out = dsconv(params, x, stride=site.stride, act=site.act or "hswish")
         if epilogue is not None and epilogue.emits_q:
             return quantize_act(out, keep_fp=epilogue.residual == "keep-fp")
         return out

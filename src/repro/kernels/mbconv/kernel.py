@@ -7,9 +7,13 @@ activation traffic); on the FPGA it streams RPE -> aux buffer -> MAT
 engine and never reaches DRAM.  Here both intermediates (the PW1
 expansion and the DW output) live only in VMEM scratch:
 
-  MXU stage 1: mid = Hardswish(x @ w1 + b1)          (1x1 expansion)
-  VPU stage  : dw  = Hardswish(DW3x3(mid) + b_dw)    (9 shifted MACs)
-  MXU stage 2: out = dw @ w2 + b2                    (1x1 projection)
+  MXU stage 1: mid = act(x @ w1 + b1)          (1x1 expansion)
+  VPU stage  : dw  = act(DW3x3(mid) + b_dw)    (9 shifted MACs)
+  MXU stage 2: out = dw @ w2 + b2              (1x1 projection)
+
+``act`` is static: Hardswish for the B series, tanh-form GELU for the
+L series (``core.efficientvit.ACTIVATIONS``); the FIX8 variants run
+Hardswish only.
 
 Grid: (batch, c_out tiles).  Stages 1-2 run once per batch element
 (c_out tile 0) into scratch; the remaining c_out tiles reuse the scratch
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.efficientvit import activation
 from repro.kernels.autotune import pad_to_multiple
 from repro.kernels.compat import default_interpret, tpu_compiler_params
 from repro.kernels.quant import int8_dot, requantize_i8, xs_per_batch
@@ -33,8 +38,10 @@ from repro.kernels.taps import dw_taps, fill, tap_scratch
 
 
 def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
-                   o_ref, mid_scratch, dw_scratch, *, stride: int):
+                   o_ref, mid_scratch, dw_scratch, *, stride: int,
+                   act: str):
     j = pl.program_id(1)
+    f = activation(act)
     H, W, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
     M = w1_ref.shape[1]
     Ho, Wo = H // stride, W // stride
@@ -45,7 +52,7 @@ def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
         x = x_ref[0].astype(jnp.float32).reshape(H * W, C)
         mid = jnp.dot(x, w1_ref[...].astype(jnp.float32),
                       preferred_element_type=jnp.float32)
-        mid = jax.nn.hard_swish(mid + b1_ref[...])
+        mid = f(mid + b1_ref[...])
         fill(mid_scratch, mid.reshape(H, W, M), row0=1, col0=1)
 
         # VPU stage: depthwise 3x3 (SAME, anchored at stride-1) over the
@@ -55,7 +62,7 @@ def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
                       rows=Ho, cols=Wo, stride=stride,
                       row0=stride - 1, col0=stride - 1)
         acc += dwb_ref[...][None]
-        dw_scratch[...] = jax.nn.hard_swish(acc).reshape(Ho * Wo, M)
+        dw_scratch[...] = f(acc).reshape(Ho * Wo, M)
 
     # MXU stage 2: 1x1 projection of the VMEM-resident DW output
     out = jnp.dot(dw_scratch[...], w2_ref[...].astype(jnp.float32),
@@ -65,7 +72,8 @@ def _mbconv_kernel(x_ref, w1_ref, b1_ref, dww_ref, dwb_ref, w2_ref, b2_ref,
 
 
 def mbconv_fused(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
-                 block_f: int = 128, interpret: bool | None = None):
+                 block_f: int = 128, act: str = "hswish",
+                 interpret: bool | None = None):
     """x: (B, H, W, C); w1: (C, M); dw_w: (3, 3, M); w2: (M, F).
 
     Returns (B, Ho, Wo, F) fp32, Ho = H // stride.  The c_out axis is
@@ -85,7 +93,7 @@ def mbconv_fused(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
     nf = Fp // bf
 
     out = pl.pallas_call(
-        functools.partial(_mbconv_kernel, stride=stride),
+        functools.partial(_mbconv_kernel, stride=stride, act=act),
         grid=(B, nf),
         in_specs=[
             pl.BlockSpec((1, H, W, C), lambda b, j: (b, 0, 0, 0)),

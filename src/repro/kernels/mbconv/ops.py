@@ -86,23 +86,25 @@ def tune_block_f(x_shape, mid: int, f: int, *, stride: int = 1,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("stride", "block_f", "interpret"))
+                   static_argnames=("stride", "block_f", "act", "interpret"))
 def mbconv_op(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
-              block_f: int = 128, interpret: bool | None = None):
+              block_f: int = 128, act: str = "hswish",
+              interpret: bool | None = None):
     B, H, W, C = x.shape
     M = w1.shape[1]
     if mbconv_vmem_bytes(H, W, C, M, stride) > VMEM_BUDGET_BYTES:
-        return mbconv_ref(x, w1, b1, dw_w, dw_b, w2, b2, stride=stride)
+        return mbconv_ref(x, w1, b1, dw_w, dw_b, w2, b2, stride=stride,
+                          act=act)
     return mbconv_fused(x, w1, b1, dw_w, dw_b, w2, b2, stride=stride,
-                        block_f=block_f, interpret=interpret)
+                        block_f=block_f, act=act, interpret=interpret)
 
 
 def mbconv_apply(params, x, *, stride: int = 1, block_f: int | None = None,
-                 interpret: bool | None = None):
+                 act: str = "hswish", interpret: bool | None = None):
     """EfficientViT {'pw1','dw','pw2'} conv+BN block -> fused megakernel.
 
     Matches core.efficientvit.mbconv: BN folded into all three convs,
-    Hardswish after pw1 and dw, bare projection after pw2.
+    the activation ``act`` after pw1 and dw, bare projection after pw2.
     """
     w1_4, b1 = fold_bn_into_conv(params["pw1"]["conv"], params["pw1"]["bn"])
     dw_4, dw_b = fold_bn_into_conv(params["dw"]["conv"], params["dw"]["bn"])
@@ -115,7 +117,8 @@ def mbconv_apply(params, x, *, stride: int = 1, block_f: int | None = None,
                                stride=stride, allow_sweep=False,
                                interpret=interpret)
     out = mbconv_op(x, w1, b1, dw_w, dw_b, w2, b2, stride=stride,
-                    block_f=block_f, interpret=interpret)
+                    block_f=block_f, act=act,
+                    interpret=interpret)
     return out.astype(x.dtype)
 
 
@@ -244,11 +247,12 @@ class MbconvKernel(KernelBase):
         blocks = decision.blocks if decision is not None else {}
         return mbconv_apply(params, x, stride=site.stride,
                             block_f=blocks.get("block_f"),
-                            interpret=interpret)
+                            act=site.act or "hswish", interpret=interpret)
 
     def ref(self, params, x, site, *, epilogue=None, **kw):
         from repro.core.efficientvit import mbconv
-        out = mbconv(params, x, stride=site.stride)
+        out = mbconv(params, x, stride=site.stride,
+                     act=site.act or "hswish")
         if epilogue is not None and epilogue.emits_q:
             return quantize_act(out, keep_fp=epilogue.residual == "keep-fp")
         return out

@@ -16,15 +16,19 @@ import jax
 import jax.numpy as jnp
 
 
-def mbconv_ref(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1):
+def mbconv_ref(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1,
+               act: str = "hswish"):
     """x: (B, H, W, C); w1: (C, M); dw_w: (3, 3, M); w2: (M, F).
 
-    Returns (B, Ho, Wo, F) fp32 with Ho = H // stride.
+    Returns (B, Ho, Wo, F) fp32 with Ho = H // stride; ``act`` names the
+    activation after both expansion stages (Hardswish in the B series).
     """
+    from repro.core.efficientvit import activation
+    f = activation(act)
     B, H, W, C = x.shape
     xf = x.astype(jnp.float32)
     mid = jnp.einsum("bhwc,cm->bhwm", xf, w1.astype(jnp.float32))
-    mid = jax.nn.hard_swish(mid + b1[None, None, None, :])
+    mid = f(mid + b1[None, None, None, :])
     mp = jnp.pad(mid, ((0, 0), (1, 1), (1, 1), (0, 0)))
     acc = jnp.zeros_like(mid)
     for dy in range(3):
@@ -34,7 +38,7 @@ def mbconv_ref(x, w1, b1, dw_w, dw_b, w2, b2, *, stride: int = 1):
     acc = acc + dw_b[None, None, None, :]
     if stride > 1:
         acc = acc[:, stride - 1::stride, stride - 1::stride, :]
-    acc = jax.nn.hard_swish(acc)
+    acc = f(acc)
     out = jnp.einsum("bhwm,mf->bhwf", acc, w2.astype(jnp.float32))
     return out + b2[None, None, None, :]
 

@@ -15,6 +15,7 @@ Built-in registrations (loaded lazily from the kernel packages):
     ("dsconv", "int8")     kernels/dsconv/ops.py     FIX8, in-kernel requant
     ("mbconv", "fp")       kernels/mbconv/ops.py     PW+DW+PW megakernel
     ("mbconv", "int8")     kernels/mbconv/ops.py     FIX8, in-kernel requant
+    ("fmbconv", "fp")      kernels/fmbconv/ops.py    dense 3x3 + PW (L series)
     ("msa",    "fp")       kernels/relu_attn/ops.py  single-launch MSA module
     ("msa",    "int8")     kernels/int8_matmul/ops.py  + W8A8 projections
     ("group_agg", "int8")  kernels/group_conv/ops.py  MSA multi-scale
@@ -33,6 +34,10 @@ what each family supports:
     takes_q   ``apply`` accepts a ``QTensor`` input (skips the
               consumer-side activation quantize entirely)
     emits_q   ``apply`` implements the int8 act-quant epilogue
+
+``adds_residual`` declares that ``apply`` adds a residual site's input
+to its output in-kernel, so ``core.program.execute`` does not add it
+again (the impl's ``ref`` still returns the bare block).
 
 ``batch_dependent_tiles`` declares that ``tune`` keys its block choices
 on the batch axis; ``plan_program(..., reuse=)`` then only accepts
@@ -103,6 +108,7 @@ class KernelImpl(Protocol):
     vmem_budget: float
     takes_q: bool
     emits_q: bool
+    adds_residual: bool
     batch_dependent_tiles: bool
 
     def site_precision(self, params) -> str:
@@ -194,6 +200,7 @@ class KernelBase:
     vmem_budget = VMEM_UNLIMITED
     takes_q = False               # apply accepts QTensor inputs
     emits_q = False               # apply implements the int8 epilogue
+    adds_residual = False         # apply adds a residual site's input
     batch_dependent_tiles = False  # tune keys blocks on the batch axis
 
     def site_precision(self, params) -> str:
@@ -230,6 +237,7 @@ _REGISTRY: Dict[Tuple[str, str], Any] = {}
 _BUILTIN_MODULES = (
     "repro.kernels.dsconv.ops",
     "repro.kernels.mbconv.ops",
+    "repro.kernels.fmbconv.ops",
     "repro.kernels.relu_attn.ops",
     "repro.kernels.int8_matmul.ops",
     "repro.kernels.group_conv.ops",

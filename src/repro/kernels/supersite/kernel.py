@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.efficientvit import activation
 from repro.kernels.compat import default_interpret, tpu_compiler_params
 from repro.kernels.quant import int8_dot, requantize_i8
 from repro.kernels.taps import dw_taps, fill, tap_scratch
@@ -66,6 +67,7 @@ class MemberGeom(NamedTuple):
     n_out: int = 0             # output rows produced per band
     fp_offs: Tuple[int, ...] = ()
     q_offs: Tuple[int, ...] = ()
+    act: str = "hswish"        # the member's activation (static)
 
 
 class SupersiteGeom(NamedTuple):
@@ -131,9 +133,10 @@ def _fp_member(cur, j, m: MemberGeom, w_ref, scr):
 
     Arithmetic is element-for-element the per-site megakernel's
     (kernels/mbconv, kernels/dsconv): same tap order, same bias /
-    subsample / Hardswish ordering, so the fused chain tracks the
+    subsample / activation ordering, so the fused chain tracks the
     site-by-site path to accumulation roundoff only.
     """
+    f = activation(m.act)
     L, W, C = m.length, m.w_in, m.c_in
     s, n = m.stride, m.n_out
     Wo = W // s
@@ -152,13 +155,13 @@ def _fp_member(cur, j, m: MemberGeom, w_ref, scr):
         b2 = _take(w_ref, o[5], 1, F)
         mid = jnp.dot(cur.reshape(L * W, C), w1,
                       preferred_element_type=jnp.float32)
-        mid = jax.nn.hard_swish(mid + b1).reshape(L, W, M)
+        mid = f(mid + b1).reshape(L, W, M)
         # the reference zero-pads MID: rows outside the feature map must
-        # contribute zero to the DW taps, and hardswish(b1) != 0
+        # contribute zero to the DW taps, and act(b1) != 0
         fill(scr, jnp.where(valid, mid, 0.0), col0=1)
         acc = dw_taps(scr, _tap_weights(w_ref, o[2]), rows=n, cols=Wo,
                       stride=s, col0=s - 1)
-        dw = jax.nn.hard_swish(acc + dwb[None])
+        dw = f(acc + dwb[None])
         out = jnp.dot(dw.reshape(n * Wo, M), w2,
                       preferred_element_type=jnp.float32)
         out = (out + b2).reshape(n, Wo, F)
@@ -170,7 +173,7 @@ def _fp_member(cur, j, m: MemberGeom, w_ref, scr):
         fill(scr, jnp.where(valid, cur, 0.0), col0=1)
         acc = dw_taps(scr, _tap_weights(w_ref, o[0]), rows=n, cols=Wo,
                       stride=s)
-        dw = jax.nn.hard_swish(acc + dwb[None])
+        dw = f(acc + dwb[None])
         out = jnp.dot(dw.reshape(n * Wo, C), pww,
                       preferred_element_type=jnp.float32)
         out = (out + pwb).reshape(n, Wo, F)
@@ -249,6 +252,7 @@ def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref, scr):
     s = m.stride
     Ho, Wo = H // s, W // s
     qo, fo = m.q_offs, m.fp_offs
+    f = activation(m.act)
     i32 = jnp.int32
     if m.kind == "mbconv":
         M, F = m.mid, m.f_out
@@ -263,13 +267,13 @@ def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref, scr):
         xq = cur_q.reshape(H * W, C)
         acc = int8_dot(xq, w1q)
         mid = acc.astype(jnp.float32) * (cur_s * s1) + b1
-        mid = jax.nn.hard_swish(mid)
+        mid = f(mid)
         mq, s_mid = requantize_i8(mid)
         fill(scr, mq.reshape(H, W, M), row0=1, col0=1)
         acc2 = dw_taps(scr, _tap_weights(wq_ref, qo[1], i32), rows=Ho,
                        cols=Wo, stride=s, row0=s - 1, col0=s - 1)
         dw = acc2.astype(jnp.float32) * (s_mid * dws)[None] + dwb[None]
-        dw = jax.nn.hard_swish(dw)
+        dw = f(dw)
         dq, s_dw = requantize_i8(dw.reshape(Ho * Wo, M))
         acc3 = int8_dot(dq, w2q)
         out = acc3.astype(jnp.float32) * (s_dw * s2) + b2
@@ -284,7 +288,7 @@ def _int8_member(cur_q, cur_s, m: MemberGeom, wq_ref, wf_ref, scr):
         acc = dw_taps(scr, _tap_weights(wq_ref, qo[0], i32), rows=Ho,
                       cols=Wo, stride=s, row0=s - 1, col0=s - 1)
         y = acc.astype(jnp.float32) * (cur_s * dws)[None] + dwb[None]
-        y = jax.nn.hard_swish(y)
+        y = f(y)
         dq, s_dw = requantize_i8(y.reshape(Ho * Wo, C))
         acc2 = int8_dot(dq, pwq)
         out = acc2.astype(jnp.float32) * (s_dw * pws) + pwb

@@ -47,7 +47,8 @@ def _member_specs(supersite, fp_offsets=None, q_offsets=None):
         _, h, w, c = site.in_shape
         out.append(MemberGeom(site.kind, site.stride, site.residual,
                               h, w, c, site.attrs.get("mid", 0),
-                              site.out_shape[-1], fp_offs=fo, q_offs=qo))
+                              site.out_shape[-1], fp_offs=fo, q_offs=qo,
+                              act=site.act or "hswish"))
     return tuple(out)
 
 
@@ -254,8 +255,8 @@ class SupersiteKernel(KernelBase):
         y = act_fp(x)
         for s in site.sites:
             p = params_at(params, s.param_path)
-            out = dsconv(p, y, stride=s.stride) if s.kind == "dsconv" \
-                else mbconv(p, y, stride=s.stride)
+            fn = dsconv if s.kind == "dsconv" else mbconv
+            out = fn(p, y, stride=s.stride, act=s.act or "hswish")
             y = y + out if s.residual else out
         if epilogue is not None and epilogue.emits_q:
             return quantize_act(y, keep_fp=epilogue.residual != "none")

@@ -64,6 +64,31 @@ from repro.serving.telemetry import Telemetry
 __all__ = ["ExecutorKey", "Executor", "ExecutorCache", "DegradeState"]
 
 
+def _plan_routes(plan) -> dict:
+    """Which path each planned site takes, by kind.
+
+    ``attrs`` (the ``plan`` span's closing attributes): ``fused_<kind>``
+    and ``ref_<kind>`` site counts, plus ``demoted``, the reference-path
+    sites as ``site:reason`` in program order ("" when none).
+    ``counters`` (``Telemetry``): ``plan_sites_fused.<kind>`` and
+    ``plan_sites_ref.<kind>.<reason>``.  Super-site members count under
+    their own kind.
+    """
+    attrs: dict = {}
+    counters: dict = {}
+    demoted = []
+    for d in plan.decisions.values():
+        route = "fused" if d.fused else "ref"
+        attrs[f"{route}_{d.kind}"] = attrs.get(f"{route}_{d.kind}", 0) + 1
+        name = (f"plan_sites_fused.{d.kind}" if d.fused
+                else f"plan_sites_ref.{d.kind}.{d.reason}")
+        counters[name] = counters.get(name, 0) + 1
+        if not d.fused:
+            demoted.append(f"{d.name}:{d.reason}")
+    attrs["demoted"] = ",".join(demoted)
+    return {"attrs": attrs, "counters": counters}
+
+
 @dataclasses.dataclass(frozen=True)
 class ExecutorKey:
     batch: int        # bucket size (the compiled batch dimension)
@@ -398,7 +423,10 @@ class ExecutorCache:
                                 demote=(state.demoted if state is not None
                                         else ()),
                                 overrides=overrides)
-            self._t_end(pspan)
+            routes = _plan_routes(plan)
+            for name, n in routes["counters"].items():
+                self.telemetry.count(name, n)
+            self._t_end(pspan, **routes["attrs"])
             self.telemetry.count("plans_built")
             reused = sum(d.reused for d in plan.decisions.values())
             if reused:

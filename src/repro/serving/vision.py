@@ -137,7 +137,7 @@ class VisionEngine:
         """FIX8 serving mode: quantize an fp32 param tree post-training
         and serve it through the int8 fused path."""
         from repro.core.quantization import quantize_efficientvit
-        return cls(quantize_efficientvit(params), cfg,
+        return cls(quantize_efficientvit(params, cfg), cfg,
                    dataclasses.replace(serve_cfg, precision="int8"))
 
     # -- batch API (back-compat) ----------------------------------------

@@ -147,6 +147,33 @@ def test_dsconv_compiles(site, variant, batch, one_chip, no_compile_cache):
     _compile(fn, args, one_chip)
 
 
+# EfficientViT-L2@224 at bucket 8: the FusedMBConv kernel at its widest
+# stride-2 site and a residual stride-1 site (the plan's mid tiles), and
+# the GELU MBConv kernel at S4.down, whose 6144-wide mid is the largest
+# block of either model
+FMBCONV_SITES = {"S1.down": ((112, 112, 32), 512, 64, 2, False, 128),
+                 "S2.fmb0": ((28, 28, 128), 512, 128, 1, True, 256)}
+
+
+@pytest.mark.parametrize("site", sorted(FMBCONV_SITES))
+def test_fmbconv_compiles(site, one_chip, no_compile_cache):
+    from repro.kernels.fmbconv.kernel import fmbconv_fused
+    (H, W, C), mid, f, s, residual, bm = FMBCONV_SITES[site]
+    args = (_z((8, H, W, C)), _z((3, 3, C, mid)), _z((mid,)),
+            _z((mid, f)), _z((f,)))
+    text = _compile(lambda *a: fmbconv_fused(
+        *a, stride=s, block_m=bm, act="gelu_tanh", residual=residual,
+        interpret=False), args, one_chip)
+    assert "fmbconv_op" in text       # the name the device trace reads
+
+
+def test_mbconv_gelu_compiles_at_l2_s4_down(one_chip, no_compile_cache):
+    from repro.kernels.mbconv import kernel as k
+    fn, args = _mbconv_case("fp", 8, (14, 14, 256), 6144, 512, 2)
+    _compile(lambda *a: k.mbconv_fused(*a, stride=2, act="gelu_tanh",
+                                       interpret=False), args, one_chip)
+
+
 # ---------------------------------------------------------------------------
 # super-sites: B1@224's S1 / S2 chains (both start with a stride-2 member)
 # ---------------------------------------------------------------------------

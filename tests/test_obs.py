@@ -798,3 +798,35 @@ def test_ledger_fixture_is_valid():
     assert doc["gates"] and all(doc["gates"].values()), doc["gates"]
     assert doc["metrics"]["trace"]["fp"]["chains"] \
         == doc["config"]["n_requests"]
+
+
+def test_plan_span_and_counters_say_which_sites_ran_which_path():
+    """A tiny L-series executor's ``plan`` span counts fused and
+    reference-path sites by kind and names each demotion with its
+    reason; ``Telemetry`` counts the same, by kind and reason.  A
+    ``demote=`` ladder step (``degrade(site=...)``) shows in both."""
+    import jax
+
+    from repro.core.efficientvit import L_SMOKE, init_efficientvit
+    from repro.serving.executors import ExecutorCache
+
+    params = init_efficientvit(jax.random.PRNGKey(0), L_SMOKE)
+    tracer = Tracer()
+    cache = ExecutorCache(params, L_SMOKE, buckets=(2,), autotune=False,
+                          tracer=tracer)
+    cache.get(2, 64)
+    cache.degrade(2, 64, site="S1.fmb0")
+    cache.get(2, 64)
+    healthy, demoted = tracer.spans("plan")
+    assert healthy.attrs == {"reused_donor": False, "fused_fmbconv": 4,
+                             "fused_mbconv": 4, "fused_msa": 1,
+                             "demoted": ""}
+    assert demoted.attrs["fused_fmbconv"] == 3
+    assert demoted.attrs["ref_fmbconv"] == 1
+    assert demoted.attrs["demoted"] == "S1.fmb0:fault"
+    c = cache.telemetry.counters
+    assert c["plan_sites_fused.fmbconv"] == 4 + 3
+    assert c["plan_sites_fused.mbconv"] == 4 + 4
+    assert c["plan_sites_fused.msa"] == 2
+    assert c["plan_sites_ref.fmbconv.fault"] == 1
+    assert not any(k.startswith("plan_sites_ref.mbconv") for k in c)
