@@ -25,10 +25,10 @@ Fusible sites (= ``Program.fusible()``, the IR is the source of truth):
   * ``S{3,4}.evit{i}.mb``     MBConv        -> kernels/mbconv
   * ``S{3,4}.evit{i}.msa``    MSA module    -> kernels/relu_attn (+
                               kernels/int8_matmul projections for FIX8)
-  * L series: ``S{1,2}.down`` / ``S{1,2}.fmb{i}`` FusedMBConv ->
+  * L series: ``stem.res0`` ResBlock -> kernels/resblock (3x3 + 3x3 +
+    residual); ``S{1,2}.down`` / ``S{1,2}.fmb{i}`` FusedMBConv ->
     kernels/fmbconv (dense 3x3 + PW); ``S3.down`` / ``S3.mb{i}`` /
-    ``S4.down`` MBConv; the ResBlock stem runs as XLA convs (a
-    structural site, never planned)
+    ``S4.down`` MBConv
 
 Anything that fails a check runs the reference path — ``plan=None``
 leaves the reference forward byte-identical.  ``build_plan`` remains as

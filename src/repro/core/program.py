@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from repro.common.errors import LoweringError
 from repro.core.efficientvit import (
     B1, EfficientViTConfig, OpRecord, activation, conv_bn_act, dsconv,
-    fmbconv, mbconv, resblock, stage_layout)
+    fmbconv, mbconv, stage_layout)
 from repro.core.relu_attention import MSAConfig, msa
 from repro.layers.norms import layernorm
 
@@ -89,13 +89,13 @@ class Epilogue:
 
 EPILOGUE_FP = Epilogue()
 
-# Structural kinds ``execute`` interprets inline (a ResBlock runs as
-# two XLA convs); every OTHER kind is fusible — it plans through the
-# kernel registry, so a newly registered kind (see kernels/registry.py's
-# worked example) is schedulable the moment ``lower`` emits its Site.
-# FUSIBLE_KINDS lists the built-ins.
-STRUCTURAL_KINDS = ("conv_bn", "resblock", "gap", "fc")
-FUSIBLE_KINDS = ("dsconv", "mbconv", "fmbconv", "msa")
+# Structural kinds ``execute`` interprets inline (single XLA convs, the
+# pooling and the head); every OTHER kind is fusible — it plans through
+# the kernel registry, so a newly registered kind (see kernels/
+# registry.py's worked example) is schedulable the moment ``lower``
+# emits its Site.  FUSIBLE_KINDS lists the built-ins.
+STRUCTURAL_KINDS = ("conv_bn", "gap", "fc")
+FUSIBLE_KINDS = ("dsconv", "mbconv", "fmbconv", "resblock", "msa")
 # Conv-chain kinds the inter-layer super-site pass may group into one
 # launch (core.fusion.plan_program's grouping pass + kernels/supersite).
 SUPERSITE_KINDS = ("dsconv", "mbconv")
@@ -415,9 +415,9 @@ def _dispatch(site: Site, p, y, plan, cfg, attention_fn, kernel_ep):
     ``msa`` shim so ``plan.default_fuse`` applies to unknown names, an
     explicitly overridden ``attention_fn`` wins over the plan, and an
     int8-fused decision keeps its W8A8 projections even under an
-    overridden attention core.  Kinds beyond the built-ins resolve
-    through the registry: ``apply`` when fused, the impl's ``ref``
-    otherwise.  ``y`` may be a ``QTensor`` from the producer's epilogue
+    overridden attention core.  Other kinds (``resblock``, and any
+    registered beyond the built-ins) resolve through the registry:
+    ``apply`` when fused, the impl's ``ref`` otherwise.  ``y`` may be a ``QTensor`` from the producer's epilogue
     (only ever assigned to fused int8 consumers); ``kernel_ep`` is the
     in-kernel part of this site's own epilogue (``None`` for fp output
     or a post-add policy, which ``execute`` applies after the residual).
@@ -525,9 +525,6 @@ def execute(program: Program, params, x, *, plan=None, attention_fn=None,
                 # structural producer: XLA fuses the act-quant into the
                 # conv/BN epilogue — the boundary tensor is int8
                 y = quantize_act(y, keep_fp=ep.residual != "none")
-        elif site.kind == "resblock":
-            y = act_fp(y)
-            y = y + resblock(p, y, act=site.act)
         elif site.kind == "gap":
             y = jnp.mean(act_fp(y), axis=(1, 2))
         elif site.kind == "fc":

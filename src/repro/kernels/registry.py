@@ -16,6 +16,8 @@ Built-in registrations (loaded lazily from the kernel packages):
     ("mbconv", "fp")       kernels/mbconv/ops.py     PW+DW+PW megakernel
     ("mbconv", "int8")     kernels/mbconv/ops.py     FIX8, in-kernel requant
     ("fmbconv", "fp")      kernels/fmbconv/ops.py    dense 3x3 + PW (L series)
+    ("resblock", "fp")     kernels/resblock/ops.py   3x3 + 3x3 + residual
+                           (the L series' stem)
     ("msa",    "fp")       kernels/relu_attn/ops.py  single-launch MSA module
     ("msa",    "int8")     kernels/int8_matmul/ops.py  + W8A8 projections
     ("group_agg", "int8")  kernels/group_conv/ops.py  MSA multi-scale
@@ -238,6 +240,7 @@ _BUILTIN_MODULES = (
     "repro.kernels.dsconv.ops",
     "repro.kernels.mbconv.ops",
     "repro.kernels.fmbconv.ops",
+    "repro.kernels.resblock.ops",
     "repro.kernels.relu_attn.ops",
     "repro.kernels.int8_matmul.ops",
     "repro.kernels.group_conv.ops",

@@ -167,6 +167,17 @@ def test_fmbconv_compiles(site, one_chip, no_compile_cache):
     assert "fmbconv_op" in text       # the name the device trace reads
 
 
+def test_resblock_compiles(one_chip, no_compile_cache):
+    """L2@224's ResBlock stem at bucket 8: both 3x3 convs of 32 channels
+    at 112x112, GELU, the residual, in one launch."""
+    from repro.kernels.resblock.kernel import resblock_fused
+    args = (_z((8, 112, 112, 32)), _z((3, 3, 32, 32)), _z((32,)),
+            _z((3, 3, 32, 32)), _z((32,)))
+    text = _compile(lambda *a: resblock_fused(
+        *a, act="gelu_tanh", interpret=False), args, one_chip)
+    assert "resblock_op" in text      # the name the device trace reads
+
+
 def test_mbconv_gelu_compiles_at_l2_s4_down(one_chip, no_compile_cache):
     from repro.kernels.mbconv import kernel as k
     fn, args = _mbconv_case("fp", 8, (14, 14, 256), 6144, 512, 2)

@@ -84,11 +84,19 @@ def test_l2_plan_fuses_every_fmbconv_site_at_bucket_8():
                         interpret=False)
     fmb = [d for d in plan.decisions.values() if d.kind == "fmbconv"]
     assert len(fmb) == 6 and all(d.fused for d in fmb)
+    res = plan.get("stem.res0")
+    assert (res.kind, res.fused, res.reason) == ("resblock", True, "ok")
     demoted = {d.name: d.reason for d in plan.decisions.values()
                if not d.fused}
     # the planner's analytic VMEM budget keeps S3.down's 2048-wide
     # expansion at 28x28 on the reference path
     assert demoted == {"S3.down": "vmem"}
+    # B1 has no ResBlock: its plan is the 22 fused DSConv/MBConv/MSA sites
+    b1 = plan_program(lower(B1, batch=8), jax.eval_shape(
+        lambda k: init_efficientvit(k, B1), jax.random.PRNGKey(0)),
+        autotune=False, interpret=False)
+    assert sorted((d.kind, d.fused) for d in b1.decisions.values()) == \
+        [("dsconv", True)] + [("mbconv", True)] * 14 + [("msa", True)] * 7
 
 
 def test_unplanned_program_matches_the_plain_reference(l_smoke):
@@ -107,10 +115,28 @@ def test_fused_plan_matches_the_plain_reference(l_smoke):
     with jax.default_matmul_precision("highest"):
         plan = plan_program(program, params, autotune=False)
         assert all(d.fused for d in plan.decisions.values())
+        assert plan.get("stem.res0").kind == "resblock"
         got = execute(program, params, x, plan=plan)
+        unplanned = execute(program, params, x)
     # the interpreted kernels sum taps and mid tiles in their own order:
     # fp32 roundoff only, as for the unplanned program
     assert _rel_err(got, want) < 1e-5
+    assert _rel_err(got, unplanned) < 1e-5
+
+
+def test_demoted_resblock_matches_the_plain_reference(l_smoke):
+    """A plan that sends the stem's ResBlock to the reference path (the
+    ladder's ``demote``) runs it as XLA convs plus ``execute``'s own
+    residual add, every other site fused."""
+    params, x, want = l_smoke
+    program = lower(L_SMOKE, batch=2)
+    with jax.default_matmul_precision("highest"):
+        plan = plan_program(program, params, autotune=False,
+                            demote=("stem.res0",))
+        assert {d.name for d in plan.decisions.values() if not d.fused} \
+            == {"stem.res0"}
+        got = execute(program, params, x, plan=plan)
+    assert _rel_err(got, want) < 1e-5     # fp32 roundoff, as above
 
 
 @pytest.mark.parametrize("stride,residual", [(1, False), (1, True),

@@ -818,13 +818,14 @@ def test_plan_span_and_counters_say_which_sites_ran_which_path():
     cache.degrade(2, 64, site="S1.fmb0")
     cache.get(2, 64)
     healthy, demoted = tracer.spans("plan")
-    assert healthy.attrs == {"reused_donor": False, "fused_fmbconv": 4,
-                             "fused_mbconv": 4, "fused_msa": 1,
-                             "demoted": ""}
+    assert healthy.attrs == {"reused_donor": False, "fused_resblock": 1,
+                             "fused_fmbconv": 4, "fused_mbconv": 4,
+                             "fused_msa": 1, "demoted": ""}
     assert demoted.attrs["fused_fmbconv"] == 3
     assert demoted.attrs["ref_fmbconv"] == 1
     assert demoted.attrs["demoted"] == "S1.fmb0:fault"
     c = cache.telemetry.counters
+    assert c["plan_sites_fused.resblock"] == 1 + 1
     assert c["plan_sites_fused.fmbconv"] == 4 + 3
     assert c["plan_sites_fused.mbconv"] == 4 + 4
     assert c["plan_sites_fused.msa"] == 2
