@@ -62,8 +62,8 @@ def cold_start_replay(tree, spec, trace, images, *, precision,
         t0 = time.perf_counter()
         try:
             _tel, logits, _wall, cache = replay(
-                tree, spec, trace, images, policy_name="bucketed",
-                precision=precision, autotune=True, artifact=artifact)
+                tree, spec, trace, images, precision=precision,
+                autotune=True, artifact=artifact)
         finally:
             if old is None:
                 os.environ.pop("REPRO_AUTOTUNE_CACHE", None)

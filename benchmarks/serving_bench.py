@@ -1,16 +1,12 @@
-"""Mixed-trace serving benchmark: fixed-microbatch padding vs
-shape-bucketed continuous micro-batching, fp32 and FIX8 int8.
+"""Mixed-trace serving benchmark: shape-bucketed continuous
+micro-batching, fp32 and FIX8 int8.
 
 A synthetic request trace (Poisson-ish arrivals, mixed resolutions) is
-replayed twice per precision through the serving runtime
-(``serving.executors`` + ``serving.scheduler``):
-
-  * ``fixed``    — the legacy ``VisionEngine`` behavior: every dispatch
-    is the full microbatch, ragged groups padded up to it;
-  * ``bucketed`` — batch formation groups same-resolution requests into
-    the largest ready bucket and flushes due tails to the smallest
-    bucket that fits, so pad waste only ever appears inside the
-    smallest covering bucket.
+replayed once per precision through the serving runtime
+(``serving.executors`` + ``serving.scheduler``): batch formation groups
+same-resolution requests into the largest ready bucket and flushes due
+tails to the smallest bucket that fits, so pad waste only ever appears
+inside the smallest covering bucket.
 
 Replay runs on a manual clock (deterministic queue/deadline behavior);
 wall clock is measured around the dispatch+finalize work for a
@@ -18,21 +14,19 @@ throughput figure (CPU interpret mode: a consistency check, not a TPU
 number — occupancy and pad waste are the backend-independent story).
 
 Asserts (CI smoke gate, ``--smoke``):
-  * bucketed pads strictly fewer samples and reaches strictly higher
-    batch occupancy than fixed, at BOTH precisions;
-  * fp logits agree between the two policies (1e-3) and with the
-    unbatched reference forward;
-  * executor-cache key-set drift gate: the bucketed smoke replay
-    compiles exactly ``EXPECTED_SMOKE_KEYS`` — a scheduler or bucket-
-    policy change that alters the compiled working set must update the
-    expectation here explicitly.
+  * fp logits agree with the unbatched reference forward (1e-3);
+  * executor-cache key-set drift gate: the smoke replay compiles
+    exactly ``EXPECTED_SMOKE_KEYS`` — a scheduler or bucket-policy
+    change that alters the compiled working set must update the
+    expectation here explicitly;
+  * every completed request left a complete span chain.
 
     PYTHONPATH=src python -m benchmarks.serving_bench [--smoke]
         [--record-trace PATH]   export the replayed trace as JSON (the
                                 offline schedule search's input)
         [--trace PATH]          replay a recorded trace instead of
                                 synthesizing one
-        [--trace-json PATH]     export the fp bucketed replay's request
+        [--trace-json PATH]     export the fp replay's request
                                 timeline as Chrome trace JSON (Perfetto)
         [--json OUT]            machine-readable result ledger
                                 (repro.obs.ledger, BENCH_SCHEMA)
@@ -53,23 +47,21 @@ from repro.obs import (
     validate_chrome_trace, write_result)
 from repro.serving.executors import ExecutorCache
 from repro.serving.scheduler import (
-    BucketedPolicy, FixedMicrobatchPolicy, ManualClock, MicroBatchScheduler,
-    Request)
+    ManualClock, MicroBatchScheduler, Request)
 from repro.serving.telemetry import Telemetry
 
-# Drift gate: the (batch bucket, resolution) executors the bucketed
-# smoke replay actually dispatches to.  12 requests over {32, 64}px with
+# Drift gate: the (batch bucket, resolution) executors the smoke replay
+# actually dispatches to.  12 requests over {32, 64}px with
 # buckets (1, 2, 4): full 4-buckets for the steady groups, a 1-bucket
 # only for the drained tail.  If batch formation changes, this set
 # moves — update it HERE, deliberately, alongside the scheduler change.
 EXPECTED_SMOKE_KEYS = {(4, 32), (4, 64), (1, 64)}
 
 SMOKE = dict(n_requests=12, resolutions=(32, 64), res_weights=(0.5, 0.5),
-             buckets=(1, 2, 4), microbatch=4, mean_gap_ms=2.0,
-             deadline_ms=40.0)
+             buckets=(1, 2, 4), mean_gap_ms=2.0, deadline_ms=40.0)
 FULL = dict(n_requests=32, resolutions=(32, 64, 96),
             res_weights=(0.3, 0.5, 0.2), buckets=(1, 2, 4, 8),
-            microbatch=8, mean_gap_ms=2.0, deadline_ms=40.0)
+            mean_gap_ms=2.0, deadline_ms=40.0)
 
 
 def make_trace(spec: dict, seed: int = 0):
@@ -90,11 +82,10 @@ def make_images(trace, seed: int = 1):
             for _, res in trace]
 
 
-def replay(params, spec, trace, images, *, policy_name: str,
-           precision: str = "auto", devices=None, cfg=B1_SMOKE,
-           autotune: bool = False, artifact=None,
-           with_tracer: bool = False):
-    """One policy x precision replay; returns (telemetry, logits, wall_s,
+def replay(params, spec, trace, images, *, precision: str = "auto",
+           devices=None, cfg=B1_SMOKE, autotune: bool = False,
+           artifact=None, with_tracer: bool = False):
+    """One replay at one precision; returns (telemetry, logits, wall_s,
     cache).  ``devices`` shards every dispatch's batch axis across that
     mesh (``serving.sharding``); ``artifact`` adopts an offline-searched
     ``repro.search.ScheduleArtifact`` (buckets + pinned plans, zero
@@ -108,20 +99,14 @@ def replay(params, spec, trace, images, *, policy_name: str,
                           precision=precision, autotune=autotune,
                           telemetry=tel, devices=devices,
                           artifact=artifact, tracer=tracer)
-    policy = (FixedMicrobatchPolicy(spec["microbatch"])
-              if policy_name == "fixed" else BucketedPolicy())
-    sched = MicroBatchScheduler(cache, params, policy=policy,
-                                telemetry=tel, clock=clock, tracer=tracer)
+    sched = MicroBatchScheduler(cache, params, telemetry=tel, clock=clock,
+                                tracer=tracer)
     reqs = [Request(rid=i, image=img, deadline_ms=spec["deadline_ms"])
             for i, img in enumerate(images)]
     # warm the compiled working set outside the timed window, like a
     # serving engine warming up before traffic — CPU-interpret compile
     # stalls would otherwise dominate the replay wall clock
-    if policy_name == "fixed":
-        for res in spec["resolutions"]:
-            cache.get(spec["microbatch"], res).warm(params)
-    else:
-        cache.warmup(spec["resolutions"])
+    cache.warmup(spec["resolutions"])
     t0 = time.perf_counter()
     for (at, _), req in zip(trace, reqs):
         clock.advance_to(at)
@@ -146,7 +131,7 @@ def reference_logits(params, images):
     return np.stack(outs)
 
 
-def _policy_line(name, tel, wall, n):
+def _replay_line(name, tel, wall, n):
     return (f"  {name:<9} occupancy {tel.occupancy:>5.1%}  "
             f"padded {tel.total('padded'):>3}  "
             f"dispatches {tel.total('dispatches'):>3}  "
@@ -161,8 +146,8 @@ def sharded_section(params, qparams, spec, trace, images, results):
     trace replayed with every dispatch batch-axis-sharded across the
     mesh, plus a 4x-compressed high-QPS replay for per-device occupancy.
 
-    Parity gates: sharded fp logits match the single-device bucketed
-    replay to 1e-5, and sharded int8 logits are BIT-EXACT — per-batch-
+    Parity gates: sharded fp logits match the single-device replay to
+    1e-5, and sharded int8 logits are BIT-EXACT — per-batch-
     element activation scales make the batch split invisible to each
     request's numerics.
     """
@@ -176,21 +161,20 @@ def sharded_section(params, qparams, spec, trace, images, results):
     for prec_name, tree, precision, gate in (
             ("fp", params, "auto", 1e-5), ("int8", qparams, "int8", 0.0)):
         tel, logits, wall, cache = replay(
-            tree, spec, trace, images, policy_name="bucketed",
-            precision=precision, devices=devices)
-        single = results[prec_name]["bucketed"]["logits"]
+            tree, spec, trace, images, precision=precision,
+            devices=devices)
+        single = results[prec_name]["logits"]
         err = float(np.max(np.abs(logits - single)))
         assert err <= gate, \
             (prec_name, "sharded vs single-device drift", err, gate)
-        print(_policy_line(f"{prec_name}", tel, wall, n)
+        print(_replay_line(f"{prec_name}", tel, wall, n)
               + f"  vs single-device max|Δ| {err:.1e}"
               + (" (bit-exact)" if err == 0.0 else ""))
     # high-QPS replay: arrivals compressed 4x, so batch formation leans
     # on the big buckets and every mesh device sees traffic
     fast = [(at / 4.0, res) for at, res in trace]
     tel, _logits, wall, _cache = replay(
-        params, spec, fast, images, policy_name="bucketed",
-        devices=devices)
+        params, spec, fast, images, devices=devices)
     assert tel.devices, "sharded replay recorded no per-device telemetry"
     used = sorted(tel.devices)
     print(f"  high-QPS (4x arrival rate): {len(used)} devices active")
@@ -203,7 +187,7 @@ def sharded_section(params, qparams, spec, trace, images, results):
 
 
 def check_trace(tracer, reqs_done: int, trace_json: str | None = None):
-    """Observability gate: the bucketed replay's trace must be schema-
+    """Observability gate: the replay's trace must be schema-
     valid and contain a COMPLETE admit -> queue -> dispatch -> device ->
     finalize chain for every completed request.  Optionally exports the
     Chrome trace JSON to ``trace_json``."""
@@ -246,50 +230,33 @@ def run(smoke: bool = False, trace_path: str | None = None,
 
     print(f"# serving bench — {B1_SMOKE.name}, {n} requests over "
           f"{spec['resolutions']}px, buckets {spec['buckets']}, "
-          f"fixed microbatch {spec['microbatch']}, "
           f"deadline {spec['deadline_ms']:.0f} ms (virtual clock)")
 
     results = {}
     for prec_name, tree, precision in (("fp", params, "auto"),
                                        ("int8", qparams, "int8")):
+        # the replays run WITH tracing enabled, so every drift gate
+        # below (parity, EXPECTED_SMOKE_KEYS) holds on the traced
+        # runtime, not a tracing-off twin
+        tel, logits, wall, cache = replay(
+            tree, spec, trace, images, precision=precision,
+            with_tracer=True)
+        results[prec_name] = dict(tel=tel, logits=logits, wall=wall,
+                                  cache=cache)
         print(f"\n## {prec_name}")
-        per = {}
-        for policy in ("fixed", "bucketed"):
-            # the bucketed replays run WITH tracing enabled, so every
-            # drift gate below (occupancy, parity, EXPECTED_SMOKE_KEYS)
-            # holds on the traced runtime, not a tracing-off twin
-            tel, logits, wall, cache = replay(
-                tree, spec, trace, images, policy_name=policy,
-                precision=precision, with_tracer=(policy == "bucketed"))
-            per[policy] = dict(tel=tel, logits=logits, wall=wall,
-                               cache=cache)
-            print(_policy_line(policy, tel, wall, n))
-        results[prec_name] = per
-
-        fx, bk = per["fixed"]["tel"], per["bucketed"]["tel"]
-        assert bk.total("padded") < fx.total("padded"), \
-            (prec_name, bk.total("padded"), fx.total("padded"))
-        assert bk.occupancy > fx.occupancy, \
-            (prec_name, bk.occupancy, fx.occupancy)
-        print(f"  -> bucketed pads {fx.total('padded') - bk.total('padded')}"
-              f" fewer samples; occupancy {fx.occupancy:.1%} -> "
-              f"{bk.occupancy:.1%}")
-        print("\n  per-bucket telemetry (bucketed):")
-        for line in bk.table().splitlines():
+        print(_replay_line(prec_name, tel, wall, n))
+        print("\n  per-bucket telemetry:")
+        for line in tel.table().splitlines():
             print("  " + line)
 
-    # fp numerics: both policies match each other and the unbatched
-    # reference (int8 batch formation differs between the policies, and
-    # although per-batch-element activation scales make each request's
-    # int8 numerics batch-invariant, dequant reassociation still leaves
-    # float-ulp noise — per-bucket parity lives in
-    # tests/test_serving_runtime.py).
+    # fp numerics: the replay matches the unbatched reference (int8
+    # dequant reassociation leaves float-ulp noise across batch shapes
+    # — per-bucket parity lives in tests/test_serving_runtime.py).
     fp = results["fp"]
     ref = reference_logits(params, images)
-    for policy in ("fixed", "bucketed"):
-        err = float(np.max(np.abs(fp[policy]["logits"] - ref)))
-        assert err < 1e-3, (policy, err)
-    print(f"\nfp parity: fixed/bucketed vs unbatched reference "
+    err = float(np.max(np.abs(fp["logits"] - ref)))
+    assert err < 1e-3, err
+    print(f"\nfp parity: replay vs unbatched reference "
           f"max|Δ| < 1e-3 on all {n} requests")
 
     # executor-cache key-set drift gate (smoke trace only: the full
@@ -297,7 +264,7 @@ def run(smoke: bool = False, trace_path: str | None = None,
     # Gated on the keys batch formation actually dispatched to — the
     # warmed cache holds the full bucket x resolution product.
     if smoke:
-        got = {(b, res) for b, res, _ in fp["bucketed"]["tel"].buckets}
+        got = {(b, res) for b, res, _ in fp["tel"].buckets}
         assert got == EXPECTED_SMOKE_KEYS, \
             f"executor key-set drift: {sorted(got)} != " \
             f"{sorted(EXPECTED_SMOKE_KEYS)} — update EXPECTED_SMOKE_KEYS " \
@@ -305,11 +272,11 @@ def run(smoke: bool = False, trace_path: str | None = None,
         print(f"executor key-set gate: dispatched {sorted(got)} == expected")
 
     # trace completeness gate: every completed request in both traced
-    # (bucketed) replays left a full admit -> queue -> dispatch ->
-    # device -> finalize chain; the fp trace optionally exports
+    # replays left a full admit -> queue -> dispatch -> device ->
+    # finalize chain; the fp trace optionally exports
     trace_stats = {}
     for prec_name in ("fp", "int8"):
-        tracer = results[prec_name]["bucketed"]["cache"].tracer
+        tracer = results[prec_name]["cache"].tracer
         doc, n_complete, chains = check_trace(
             tracer, n, trace_json if prec_name == "fp" else None)
         trace_stats[prec_name] = dict(spans=n_complete, chains=len(chains))
@@ -321,33 +288,22 @@ def run(smoke: bool = False, trace_path: str | None = None,
              else ""))
 
     metrics = {
-        prec: {pol: {"occupancy": d["tel"].occupancy,
-                     "padded": d["tel"].total("padded"),
-                     "dispatches": d["tel"].total("dispatches"),
-                     "wall_s": d["wall"]}
-               for pol, d in per.items()}
-        for prec, per in results.items()}
+        prec: {"occupancy": d["tel"].occupancy,
+               "padded": d["tel"].total("padded"),
+               "dispatches": d["tel"].total("dispatches"),
+               "wall_s": d["wall"]}
+        for prec, d in results.items()}
     if json_out is not None:
-        fp_m, i8_m = metrics["fp"], metrics["int8"]
         doc = bench_result(
             "serving_bench",
             config=dict(smoke=smoke, n_requests=n,
                         resolutions=list(spec["resolutions"]),
                         buckets=list(spec["buckets"]),
-                        microbatch=spec["microbatch"],
                         deadline_ms=spec["deadline_ms"],
                         n_devices=len(jax.devices())),
             metrics=dict(metrics,
                          trace=dict(trace_stats)),
             gates=dict(
-                fewer_padded_fp=(fp_m["bucketed"]["padded"]
-                                 < fp_m["fixed"]["padded"]),
-                fewer_padded_int8=(i8_m["bucketed"]["padded"]
-                                   < i8_m["fixed"]["padded"]),
-                higher_occupancy_fp=(fp_m["bucketed"]["occupancy"]
-                                     > fp_m["fixed"]["occupancy"]),
-                higher_occupancy_int8=(i8_m["bucketed"]["occupancy"]
-                                       > i8_m["fixed"]["occupancy"]),
                 fp_parity=True,           # asserted above
                 smoke_key_set=smoke,      # asserted above when smoke
                 trace_chains_complete=True))

@@ -10,12 +10,13 @@ here in milliseconds.
 """
 import dataclasses
 
-from repro.core.accelerator_model import HwConfig, analyze
+from repro.core.accelerator_model import HwConfig, analyze_program
 from repro.core.efficientvit import B1
+from repro.core.program import lower
 
 
 def row(tag, hw, fuse=True):
-    rep, _, _ = analyze(B1, hw, fuse=fuse)
+    rep, _, _ = analyze_program(lower(B1), hw, fuse=fuse)
     print(f"{tag:34s} {rep.gops:8.1f} {rep.utilization:7.1%} "
           f"{rep.latency_ms:9.3f} {rep.dram_bytes / 1e6:9.1f}")
     return rep
@@ -40,7 +41,7 @@ def main():
     for bw in (4.8, 9.6, 19.2, 38.4):
         hw = dataclasses.replace(base, dram_gbps=bw)
         f = row(f"  DDR {bw:4.1f} GB/s + TMP", hw)
-        nf = analyze(B1, hw, fuse=False)[0]
+        nf = analyze_program(lower(B1), hw, fuse=False)[0]
         print(f"{'':34s} fusion saves {nf.total_cycles / f.total_cycles - 1:6.1%} cycles")
 
     # frequency scaling

@@ -13,9 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, smoke_variant
-from repro.core.accelerator_model import analyze
+from repro.core.accelerator_model import analyze_program
 from repro.core.efficientvit import B1, B1_SMOKE, efficientvit, init_efficientvit
-from repro.core.fusion import build_plan, launch_counts
+from repro.core.fusion import launch_counts, plan_program
+from repro.core.program import execute, lower
 from repro.core.quantization import quantization_error, quantize_efficientvit
 from repro.models.registry import build_model
 
@@ -29,9 +30,10 @@ print(f"[1] EfficientViT-B1(smoke) logits: {logits.shape}, "
       f"top-1 class {int(jnp.argmax(logits))}")
 
 # -- 2. fused inference path (TMP dataflow on TPU) ---------------------------
-plan = build_plan(params, B1_SMOKE, batch=1, autotune=False)
+program = lower(B1_SMOKE, batch=1)
+plan = plan_program(program, params, autotune=False)
 logits_kernel = jax.jit(
-    lambda p, x: efficientvit(p, x, B1_SMOKE, plan=plan))(params, img)
+    lambda p, x: execute(program, p, x, plan=plan))(params, img)
 err = float(jnp.max(jnp.abs(logits - logits_kernel)))
 lc = launch_counts(plan)
 print(f"[2] fused plan: {plan.n_fused()}/{len(plan.decisions)} sites fused, "
@@ -44,7 +46,7 @@ qlogits = jax.jit(lambda p, x: efficientvit(p, x, B1_SMOKE))(qparams, img)
 print(f"[3] FIX8 relative L2 error: {float(quantization_error(logits, qlogits)):.4f}")
 
 # -- 4. the accelerator the paper built --------------------------------------
-rep, stages, _ = analyze(B1)
+rep, stages, _ = analyze_program(lower(B1))
 print(f"[4] cycle model @B1/224px: {rep.gops:.1f} GOPS "
       f"(paper 780.2), util {rep.utilization:.1%} (paper >95%), "
       f"{rep.gops_per_w:.1f} GOPS/W (paper 105.1)")

@@ -32,8 +32,8 @@ import dataclasses
 import math
 from typing import Optional, Sequence
 
-from repro.core.efficientvit import B1, EfficientViTConfig, OpRecord
-from repro.core.program import Program, lower, manifest
+from repro.core.efficientvit import OpRecord
+from repro.core.program import Program, manifest
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,13 +388,6 @@ def site_breakdown(program: Program, hw: HwConfig = HwConfig(), *,
             "cycles": float(cycles),
         })
     return rows
-
-
-def analyze(cfg: EfficientViTConfig = B1, hw: HwConfig = HwConfig(), *,
-            fuse: bool = True, include_head: bool = False):
-    """Back-compat shim: lower the config and analyze the program."""
-    return analyze_program(lower(cfg), hw, fuse=fuse,
-                           include_head=include_head)
 
 
 # Paper Table II reference rows, for the comparison benchmark.

@@ -14,9 +14,9 @@ defaults give B1.  ``stage_layout`` lists the blocks of either pattern.
 
 This module owns the *building blocks* (param init + reference block
 forwards).  The network-level walk lives in ONE place —
-``core.program.lower`` — and ``efficientvit()`` / ``layer_manifest()``
-below are thin shims over that IR (``execute``/``manifest``), so the
-forward, the fusion plan, the accelerator cycle model and the
+``core.program.lower`` — and ``efficientvit()`` below is the reference
+forward over that IR (``lower`` then ``execute`` without a plan), so
+the forward, the fusion plan, the accelerator cycle model and the
 fig6/table2 benchmarks all trace to the same lowering.
 """
 from __future__ import annotations
@@ -27,7 +27,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.relu_attention import MSAConfig, init_msa, msa
+from repro.core.relu_attention import MSAConfig, init_msa
 from repro.layers.conv import conv2d, dwconv2d, init_conv2d, init_dwconv2d, init_pwconv, pwconv
 from repro.layers.norms import batchnorm, init_batchnorm, init_layernorm
 
@@ -254,14 +254,6 @@ def init_evit_module(key, c, head_dim, scales, expand, dtype):
     }
 
 
-def evit_module(p, x, cfg: EfficientViTConfig, c, *, attention_fn=None):
-    mcfg = MSAConfig(c, cfg.head_dim, tuple(cfg.msa_scales), cfg.dtype)
-    kw = {} if attention_fn is None else {"attention_fn": attention_fn}
-    x = x + msa(p["msa"], x, mcfg, **kw)
-    x = x + mbconv(p["mbconv"], x, act=cfg.act)
-    return x
-
-
 # ---------------------------------------------------------------------------
 # full model
 # ---------------------------------------------------------------------------
@@ -312,23 +304,16 @@ def init_efficientvit(key, cfg: EfficientViTConfig = B1):
     return params
 
 
-def efficientvit(params, x, cfg: EfficientViTConfig = B1, *,
-                 attention_fn=None, plan=None):
-    """x: (B, H, W, 3) image -> (B, num_classes) logits.
-
-    Back-compat shim over the program IR: lowers ``cfg`` (cached) and
-    interprets it with ``core.program.execute``.  ``plan`` is an
-    optional ``core.fusion.FusionPlan`` routing fusible sites through
-    the registry's Pallas megakernels — at the precision each site's
-    params carry, so a ``quantize_efficientvit`` tree runs the FIX8
-    int8 megakernels.  With ``plan=None`` the reference path runs
-    unchanged.
+def efficientvit(params, x, cfg: EfficientViTConfig = B1):
+    """x: (B, H, W, 3) image -> (B, num_classes) logits: the reference
+    forward (``core.program.lower`` for the input's shape, then
+    ``execute`` without a plan).  A plan-routed forward is
+    ``execute(program, params, x, plan=plan_program(program, params))``.
     """
     from repro.core.program import execute, lower
 
     program = lower(cfg, batch=x.shape[0], image_size=x.shape[1])
-    return execute(program, params, x, plan=plan,
-                   attention_fn=attention_fn)
+    return execute(program, params, x)
 
 
 # ---------------------------------------------------------------------------
@@ -364,16 +349,8 @@ class OpRecord:
         return self.c_in
 
 
-def layer_manifest(cfg: EfficientViTConfig = B1) -> list[OpRecord]:
-    """Enumerate hardware ops for one inference at cfg.image_size.
-
-    Back-compat shim: the records are expanded from the same program IR
-    the forward executes (``core.program.lower`` + ``manifest``), so the
-    cycle model and benchmarks cannot drift from what actually runs.
-    """
-    from repro.core.program import lower, manifest
-    return manifest(lower(cfg))
-
-
 def total_macs(cfg: EfficientViTConfig = B1) -> int:
-    return sum(op.macs for op in layer_manifest(cfg))
+    """MACs of one inference at ``cfg.image_size``, from the same
+    program IR the forward executes."""
+    from repro.core.program import lower, manifest
+    return sum(op.macs for op in manifest(lower(cfg)))

@@ -30,9 +30,8 @@ Fusible sites (= ``Program.fusible()``, the IR is the source of truth):
     kernels/fmbconv (dense 3x3 + PW); ``S3.down`` / ``S3.mb{i}`` /
     ``S4.down`` MBConv
 
-Anything that fails a check runs the reference path — ``plan=None``
-leaves the reference forward byte-identical.  ``build_plan`` remains as
-the stable back-compat entry point (lower + plan in one call).
+Anything that fails a check runs the reference path; ``execute`` with
+``plan=None`` is the reference forward.
 """
 from __future__ import annotations
 
@@ -40,30 +39,22 @@ import dataclasses
 from typing import Mapping
 
 __all__ = ["SiteDecision", "SiteOverride", "GroupDecision", "FusionPlan",
-           "build_plan", "plan_program", "plan_report", "report_dict",
-           "launch_counts", "site_traffic", "EXPECTED_B1_FUSED_LAUNCHES",
-           "EXPECTED_B1_FUSED_LAUNCHES_INT8",
-           "EXPECTED_B1_SUPERSITE_LAUNCHES",
+           "plan_program", "plan_report", "report_dict", "launch_counts",
+           "site_traffic", "EXPECTED_B1_SUPERSITE_LAUNCHES",
            "EXPECTED_B1_SUPERSITE_LAUNCHES_INT8"]
 
-# Drift gate: one fused launch per fusible site of EfficientViT-B1
-# (1 stem DSConv + 2+3 MBConv + 2 downsamples + (3+4) x (MSA + MBConv)).
-# benchmarks/e2e_latency.py and tests/test_program.py fail if a change
-# moves this number without an explicit expectation update here.
-EXPECTED_B1_FUSED_LAUNCHES = 22
-# FIX8 twin: the quantized MSA multi-scale aggregation convs run the
-# grouped int8 Pallas kernel (kernels/group_conv) instead of reference
-# XLA convs, so each fused int8 MSA site counts ``n_branches`` launches
-# (1 attention core + 1 per aggregation scale): 22 + 7 msa x 1 scale.
-EXPECTED_B1_FUSED_LAUNCHES_INT8 = 29
-# With the inter-layer super-site pass (``kernels/supersite``) the
-# planner collapses each stage's consecutive conv chain into ONE launch:
-# B1 groups S1 [mb0, mb1] (-1 launch) and S2 [mb0, mb1, mb2] (-2).
-# stem.ds0 is a run of one and the S3/S4 conv sites interleave with MSA
-# sites, so no other run qualifies.  The per-site numbers above remain
-# the ``supersites=False`` expectation.
+# Drift gate: fused launches of EfficientViT-B1 @224.  B1 has 22
+# fusible sites (1 stem DSConv + 2+3 MBConv + 2 downsamples + (3+4) x
+# (MSA + MBConv)); the super-site pass (``kernels/supersite``) collapses
+# S1 [mb0, mb1] (-1 launch) and S2 [mb0, mb1, mb2] (-2) into one launch
+# each — stem.ds0 is a run of one and the S3/S4 conv sites interleave
+# with MSA sites, so no other run qualifies.  At int8 each fused MSA
+# site adds one grouped aggregation launch per scale (kernels/
+# group_conv): 7 msa x 1 scale.  benchmarks/e2e_latency.py and
+# tests/test_program.py fail if a change moves either number without an
+# explicit expectation update here.
 EXPECTED_B1_SUPERSITE_LAUNCHES = 19           # 22 - 3
-EXPECTED_B1_SUPERSITE_LAUNCHES_INT8 = 26      # 29 - 3
+EXPECTED_B1_SUPERSITE_LAUNCHES_INT8 = 26      # 19 + 7
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +63,7 @@ class SiteDecision:
     kind: str              # dsconv | mbconv | msa
     fused: bool
     reason: str            # "ok" | "vmem" | "quantized" | "not-quantized"
-    #                        | "mixed" | "disabled"
+    #                        | "mixed"
     #                        | "fault" (demoted by the degradation ladder)
     #                        | "search" (demoted by an offline-searched
     #                          schedule override, repro.search)
@@ -174,7 +165,6 @@ class GroupDecision:
 class FusionPlan:
     decisions: Mapping[str, SiteDecision]
     interpret: bool | None = None   # None -> backend auto-detect
-    default_fuse: bool = True   # sites not in the table (standalone msa())
     # producer-side output epilogues by site name — includes STRUCTURAL
     # producers (e.g. a quantized stem conv feeding a fused int8 DSConv),
     # which have no SiteDecision of their own
@@ -186,16 +176,6 @@ class FusionPlan:
 
     def get(self, name):
         return self.decisions.get(name)
-
-    def is_fused(self, name) -> bool:
-        d = self.decisions.get(name)
-        if d is None:
-            return self.default_fuse
-        return d.fused
-
-    def blocks(self, name) -> dict:
-        d = self.decisions.get(name)
-        return dict(d.blocks) if d is not None else {}
 
     def n_fused(self) -> int:
         return sum(d.fused for d in self.decisions.values())
@@ -263,7 +243,7 @@ def _reusable_blocks(reuse, site, prec, impl):
     return dict(d.blocks)
 
 
-def _decide(site, params, *, enabled, autotune, interpret, precision,
+def _decide(site, params, *, autotune, interpret, precision,
             reuse=None, override=None):
     from repro.kernels.registry import get_kernel, get_probe
 
@@ -272,9 +252,6 @@ def _decide(site, params, *, enabled, autotune, interpret, precision,
         return SiteDecision(site.name, site.kind, False, override.reason,
                             shape=shape,
                             precision=override.precision or "fp")
-    if not enabled:
-        return SiteDecision(site.name, site.kind, False, "disabled",
-                            shape=shape)
     if override is not None and override.precision is not None:
         precision = override.precision
     probe = get_probe(site.kind)          # precision policy is per-kind
@@ -374,8 +351,8 @@ def _group_supersites(program, decisions, overrides):
     for VMEM (reason ``"vmem"``) is *rescued* into a group when the
     banded chain fits — spatial tiling is exactly what the lone whole-map
     kernel lacked — and becomes ``fused=True, reason="ok"``.  Any other
-    demotion reason (``"fault"``, ``"search"``, ``"disabled"``, precision
-    mismatches) excludes the site and splits the run around it, which is
+    demotion reason (``"fault"``, ``"search"``, precision mismatches)
+    excludes the site and splits the run around it, which is
     how the serving degradation ladder's per-site demotions break groups
     back into member launches instead of falling to reference wholesale.
     """
@@ -454,17 +431,26 @@ def _group_supersites(program, decisions, overrides):
     return groups
 
 
-def plan_program(program, params, *, fuse_dsconv: bool = True,
-                 fuse_mbconv: bool = True, fuse_msa: bool = True,
-                 autotune: bool = True, interpret: bool | None = None,
-                 precision: str = "auto",
-                 reuse: FusionPlan | None = None,
-                 epilogues: bool = True,
-                 demote=(),
-                 overrides: Mapping[str, SiteOverride] | None = None,
-                 supersites: bool = True
+def plan_program(program, params, *, autotune: bool = True,
+                 interpret: bool | None = None, precision: str = "auto",
+                 reuse: FusionPlan | None = None, demote=(),
+                 overrides: Mapping[str, SiteOverride] | None = None
                  ) -> FusionPlan:
     """Freeze per-site routing for a lowered ``core.program.Program``.
+
+    Three passes, always in this order:
+
+    1. per-site decisions (``_decide``): each fusible site runs its
+       registered kernel family at the resolved precision, or the
+       reference path with a reason;
+    2. the producer->consumer epilogue pass (``assign_epilogues``):
+       producers of fused int8 consumers get an int8 ``Epilogue``, so
+       the executed program delivers 1 byte/element activation
+       boundaries (residual adds stay fp); fp plans assign none;
+    3. the inter-layer grouping pass (``_group_supersites``): maximal
+       runs of >=2 consecutive fused conv sites of one stage collapse
+       into single-launch ``FusionPlan.groups`` with the chain's weights
+       packed VMEM-resident once per launch.
 
     ``precision``: "auto" (default) matches each site's params — fp32
     trees run the fp megakernels, ``quantize_efficientvit`` trees run
@@ -484,8 +470,8 @@ def plan_program(program, params, *, fuse_dsconv: bool = True,
     ``demote``: site names forced to the reference path with reason
     ``"fault"`` — the serving degradation ladder's lever: after a fused
     launch or plan failure blamed on one site, the executor rebuilds
-    its plan with exactly that site demoted (``"vmem"``-style) while
-    every other site stays fused.
+    its plan with exactly that site demoted while every other site
+    stays fused.  A demoted member splits its super-site group.
 
     A failure inside one site's decision (an autotune sweep crash, a
     registry probe raising) is re-raised as a typed
@@ -495,24 +481,11 @@ def plan_program(program, params, *, fuse_dsconv: bool = True,
     ``overrides``: an optional ``{site name: SiteOverride}`` schedule —
     the offline schedule search's injection point (``repro.search``).
     An override wins over the tuner/heuristics for its site: it can pin
-    the site to the reference path, force a precision, and freeze block
-    sizes verbatim (no tuner consultation).  ``demote`` still wins over
-    an override — a fault-ladder demotion must not be resurrected by a
-    stale artifact.  Sites without an override plan exactly as before.
-
-    ``epilogues`` (default on) runs the producer->consumer pass
-    (``assign_epilogues``) after the per-site decisions: producers of
-    fused int8 consumers get an int8 ``Epilogue`` so the executed
-    program delivers 1 byte/element activation boundaries (residual
-    adds stay fp).  ``False`` keeps the legacy consumer-side-quantize
-    dataflow — an A/B lever the serving executor cache keys on.
-
-    ``supersites`` (default on) runs the inter-layer grouping pass
-    (``_group_supersites``) last: maximal runs of >=2 consecutive fused
-    conv sites collapse into single-launch ``FusionPlan.groups`` with
-    the chain's weights packed VMEM-resident once per launch.  An
-    override's ``group_break`` splits a run at that site (the offline
-    search's boundary lever); ``False`` keeps per-site launches.
+    the site to the reference path, force a precision, freeze block
+    sizes verbatim (no tuner consultation) and, with ``group_break``,
+    split a super-site run at that site.  ``demote`` still wins over an
+    override — a fault-ladder demotion must not be resurrected by a
+    stale artifact.
 
     Runs outside jit: autotune sweeps (when ``autotune=True`` and the
     cache is cold) time the real kernels on synthetic inputs here, never
@@ -524,8 +497,6 @@ def plan_program(program, params, *, fuse_dsconv: bool = True,
 
     assert precision in ("auto", "fp", "int8"), precision
     interpret = default_interpret(interpret)
-    enabled = {"dsconv": fuse_dsconv, "mbconv": fuse_mbconv,
-               "msa": fuse_msa}
     demote = frozenset(demote)
     decisions: dict[str, SiteDecision] = {}
     for site in program.fusible():
@@ -537,7 +508,6 @@ def plan_program(program, params, *, fuse_dsconv: bool = True,
         try:
             decisions[site.name] = _decide(
                 site, params_at(params, site.param_path),
-                enabled=enabled.get(site.kind, True),  # new kinds default
                 autotune=autotune, interpret=interpret,
                 precision=precision, reuse=reuse,
                 override=(overrides or {}).get(site.name))
@@ -546,40 +516,16 @@ def plan_program(program, params, *, fuse_dsconv: bool = True,
                 e, ReproError) else None
             raise PlanError(f"planning {site.name} failed: {e}",
                             site=site_name or site.name) from e
-    ep_map: dict[str, object] = {}
-    if epilogues:
-        ep_map, q_in = assign_epilogues(program, params, decisions)
-        for name, d in decisions.items():
-            ep = ep_map.get(name)
-            arrives_q = name in q_in
-            if ep is not None or arrives_q:
-                decisions[name] = dataclasses.replace(
-                    d, epilogue=ep, q_in=arrives_q)
-    groups: dict[str, GroupDecision] = {}
-    if supersites:
-        groups = _group_supersites(program, decisions, overrides)
+    ep_map, q_in = assign_epilogues(program, params, decisions)
+    for name, d in decisions.items():
+        ep = ep_map.get(name)
+        arrives_q = name in q_in
+        if ep is not None or arrives_q:
+            decisions[name] = dataclasses.replace(
+                d, epilogue=ep, q_in=arrives_q)
+    groups = _group_supersites(program, decisions, overrides)
     return FusionPlan(decisions=decisions, interpret=interpret,
                       epilogues=ep_map, groups=groups)
-
-
-def build_plan(params, cfg, *, batch: int = 1, image_size: int | None = None,
-               fuse_dsconv: bool = True, fuse_mbconv: bool = True,
-               fuse_msa: bool = True, autotune: bool = True,
-               interpret: bool | None = None,
-               precision: str = "auto",
-               epilogues: bool = True) -> FusionPlan:
-    """Back-compat entry point: lower the config, then plan it.
-
-    Equivalent to ``plan_program(lower(cfg, batch=..., image_size=...),
-    params, ...)``; kept so existing callers and tests keep working.
-    """
-    from repro.core.program import lower
-
-    program = lower(cfg, batch=batch, image_size=image_size)
-    return plan_program(program, params, fuse_dsconv=fuse_dsconv,
-                        fuse_mbconv=fuse_mbconv, fuse_msa=fuse_msa,
-                        autotune=autotune, interpret=interpret,
-                        precision=precision, epilogues=epilogues)
 
 
 # ---------------------------------------------------------------------------
@@ -594,10 +540,9 @@ def _mbconv_bytes(B, H, W, C, mid, F, stride, precision="fp"):
 
     The 1-byte int8 input is the steady-state FIX8 pipeline number: it
     assumes the producer emits (or its epilogue fuses) the int8
-    activation, as on the paper's accelerator.  Today's implementation
-    quantizes x in XLA just before the kernel, so measured traffic
-    carries an extra fp32 read until producer-side int8 emission lands
-    (ROADMAP open item)."""
+    activation, as on the paper's accelerator (``plan_program``'s
+    epilogue pass; ``hbm_delivered`` in ``plan_report`` counts what the
+    executed boundaries actually move)."""
     Ho, Wo = H // stride, W // stride
     xn = B * H * W * C
     midn = B * H * W * mid

@@ -12,17 +12,13 @@ that representation for the repo:
     ``manifest(program)``         hardware op records (MACs/shapes) for
                                   the cycle model + fig6/table2
 
-Before this module the network existed three times — the
-``efficientvit()`` forward, ``build_plan``'s site walk, and
-``layer_manifest`` — each hand-maintained and free to drift.  Now all
-three derive from the same frozen ``Site`` sequence, so the fusion
-plan's site set, the analytic HBM accounting, and the benchmark numbers
-cannot disagree with what actually runs.
+The forward, the fusion plan's site set, the analytic HBM accounting
+and the benchmark numbers all derive from the same frozen ``Site``
+sequence, so they cannot disagree with what actually runs.
 
-Execution routes fusible sites (``dsconv | mbconv | msa``) through the
-pluggable kernel registry (``repro.kernels.registry``) when a
-``FusionPlan`` decision says so; with ``plan=None`` the reference path
-below is byte-identical to the pre-IR forward.  Registering a new
+Execution routes fusible sites through the pluggable kernel registry
+(``repro.kernels.registry``) when a ``FusionPlan`` decision says so;
+with ``plan=None`` every site runs its reference op.  Registering a new
 kernel (see the registry docstring for the worked grouped-int8 example)
 makes it schedulable here with no changes to this file.
 """
@@ -407,17 +403,14 @@ def _fc(p, h):
     return y + p["b"].astype(h.dtype) if "b" in p else y
 
 
-def _dispatch(site: Site, p, y, plan, cfg, attention_fn, kernel_ep):
-    """Fusible site: registry kernel when the plan says so, else reference.
+def _dispatch(site: Site, p, y, plan, cfg, kernel_ep):
+    """Fusible site: registry kernel when the plan fuses it, else the
+    reference block.
 
-    Mirrors the legacy dispatch contract: conv sites fall back when their
-    decision is absent or unfused; unplanned MSA sites route through the
-    ``msa`` shim so ``plan.default_fuse`` applies to unknown names, an
-    explicitly overridden ``attention_fn`` wins over the plan, and an
-    int8-fused decision keeps its W8A8 projections even under an
-    overridden attention core.  Other kinds (``resblock``, and any
-    registered beyond the built-ins) resolve through the registry:
-    ``apply`` when fused, the impl's ``ref`` otherwise.  ``y`` may be a ``QTensor`` from the producer's epilogue
+    A fused decision of any kind runs the registered impl's ``apply`` at
+    the decision's precision.  Otherwise the built-in kinds run their
+    reference block forward and any other registered kind its impl's
+    ``ref``.  ``y`` may be a ``QTensor`` from the producer's epilogue
     (only ever assigned to fused int8 consumers); ``kernel_ep`` is the
     in-kernel part of this site's own epilogue (``None`` for fp output
     or a post-add policy, which ``execute`` applies after the residual).
@@ -425,24 +418,18 @@ def _dispatch(site: Site, p, y, plan, cfg, attention_fn, kernel_ep):
     from repro.core.quantization import act_fp
 
     d = plan.get(site.name) if plan is not None else None
-    # the kwarg is only passed when an epilogue is actually assigned, so
-    # registered impls predating the epilogue contract stay compatible
-    ep_kw = {} if kernel_ep is None else {"epilogue": kernel_ep}
-    if site.kind == "msa":
-        if attention_fn is None and d is not None and d.fused:
-            from repro.kernels.registry import get_kernel
-            impl = get_kernel(site.kind, d.precision)
-            return impl.apply(p, y, site, d, interpret=plan.interpret,
-                              **ep_kw)
-        mcfg = MSAConfig(site.in_shape[-1], site.attrs["head_dim"],
-                         site.attrs["scales"], cfg.dtype)
-        kw = {} if attention_fn is None else {"attention_fn": attention_fn}
-        return msa(p, act_fp(y), mcfg, plan=plan, site=site.name, **kw)
     if d is not None and d.fused:
         from repro.kernels.registry import get_kernel
+        # the kwarg is only passed when an epilogue is actually assigned,
+        # so registered impls predating the epilogue contract stay
+        # compatible
+        ep_kw = {} if kernel_ep is None else {"epilogue": kernel_ep}
         impl = get_kernel(site.kind, d.precision)
         return impl.apply(p, y, site, d, interpret=plan.interpret, **ep_kw)
     y = act_fp(y)
+    if site.kind == "msa":
+        return msa(p, y, MSAConfig(site.in_shape[-1], site.attrs["head_dim"],
+                                   site.attrs["scales"], cfg.dtype))
     if site.kind == "dsconv":
         return dsconv(p, y, stride=site.stride, act=site.act)
     if site.kind == "mbconv":
@@ -464,8 +451,7 @@ def _adds_residual(site: Site, plan) -> bool:
                    False)
 
 
-def execute(program: Program, params, x, *, plan=None, attention_fn=None,
-            profile=None):
+def execute(program: Program, params, x, *, plan=None, profile=None):
     """Run the lowered program.  x: (B, H, W, 3) -> (B, num_classes).
 
     ``plan`` is an optional ``core.fusion.FusionPlan`` (built by
@@ -475,9 +461,7 @@ def execute(program: Program, params, x, *, plan=None, attention_fn=None,
     ``Epilogue`` assignments that make producers emit int8 activations
     for fused int8 consumers (``QTensor`` boundaries; residual adds stay
     fp per each epilogue's residual policy).  ``plan=None`` runs the
-    reference ops — byte-identical to the pre-IR ``efficientvit()``
-    forward.  An explicit ``attention_fn`` override disables epilogue
-    emission (the int8 dataflow only runs on the default fused path).
+    reference ops.
 
     ``profile`` is an optional ``repro.obs.profile.SiteProfiler``: each
     site's output is blocked on (``block_until_ready``) at the site
@@ -486,17 +470,16 @@ def execute(program: Program, params, x, *, plan=None, attention_fn=None,
     offline model-drift audits only — never the serving path, and never
     under jit (the barrier is meaningless on tracers).
     """
-    from repro.core.quantization import QTensor, act_fp, quantize_act
+    from repro.core.quantization import act_fp, quantize_act
 
     cfg = program.cfg
-    epilogues = (getattr(plan, "epilogues", None) or {}) \
-        if attention_fn is None else {}
+    epilogues = getattr(plan, "epilogues", None) or {}
     # super-site groups (core.fusion's grouping pass): the whole member
     # chain runs as one launch, entered at the first member.  Disabled
-    # under an attention_fn override (legacy dataflow) and under
-    # profiling (the drift report needs one wall-clock window PER site).
+    # under profiling (the drift report needs one wall-clock window PER
+    # site).
     groups = (getattr(plan, "groups", None) or {}) \
-        if (attention_fn is None and profile is None) else {}
+        if profile is None else {}
     group_entry: dict[str, Any] = {}
     group_skip: set[str] = set()
     for g in groups.values():
@@ -538,7 +521,7 @@ def execute(program: Program, params, x, *, plan=None, attention_fn=None,
             # sites; a residual producer's quantize applies post-add
             kernel_ep = ep if (ep is not None and ep.emits_q
                                and not site.residual) else None
-            out = _dispatch(site, p, y, plan, cfg, attention_fn, kernel_ep)
+            out = _dispatch(site, p, y, plan, cfg, kernel_ep)
             if site.residual and not _adds_residual(site, plan):
                 s = act_fp(y) + act_fp(out)
                 if ep is not None and ep.emits_q:   # "post-add" policy
@@ -658,5 +641,5 @@ def site_records(program: Program) -> list[Tuple[Site, list[OpRecord]]]:
 
 def manifest(program: Program) -> list[OpRecord]:
     """Expand the IR into per-hardware-op records (one inference; the
-    batch dim is excluded, matching the legacy ``layer_manifest``)."""
+    batch dim is excluded)."""
     return [op for _, ops in site_records(program) for op in ops]

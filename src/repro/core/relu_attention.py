@@ -12,9 +12,8 @@ Faithful to Cai et al. (ICCV'23) + the accelerator paper's Fig. 2(b):
      divisor path is the K-adder-tree + divider pipeline of §III-D.
   4. Concat scales, 1x1 projection (+BN).
 
-The attention core delegates to ``layers.attention.relu_linear_attention_
-noncausal`` so LM and ViT share one implementation; the fused Pallas
-kernel (kernels/relu_attn) is an opt-in drop-in replacement.
+``msa`` is the plain jnp reference; the fused Pallas module
+(kernels/relu_attn) is what a plan routes a fused site to.
 """
 from __future__ import annotations
 
@@ -92,52 +91,18 @@ def _conv_any(p, x, *, groups=1):
     return conv2d(p, x, groups=groups)
 
 
-def msa(params, x, cfg: MSAConfig, *, attention_fn=relu_global_attention,
-        plan=None, site=None):
+def msa(params, x, cfg: MSAConfig, *, attention_fn=relu_global_attention):
     """x: (B, H, W, C) -> (B, H, W, C).
 
-    ``plan=None`` (default) is the reference path: a Python loop over the
-    ``1 + len(scales)`` branches, each through ``attention_fn``.
-
-    ``plan``/``site`` are back-compat shim kwargs: they delegate to the
-    kernel registry's fused MSA module (``kernels.relu_attn.ops.
-    msa_fused_apply`` — all branches and heads folded into ONE attention
-    launch, §III-D intra-layer fusion; the int8 registration additionally
-    routes the QKV/output projections through the Pallas W8A8 GEMM).
-    ``site`` names this module's plan entry, e.g. "S3.evit0.msa"; omit it
-    for a standalone module (``plan.default_fuse`` applies).  An
-    explicitly overridden ``attention_fn`` always wins over the plan:
-    the fused route only replaces the default reference core.  Program
-    execution (``core.program.execute``) dispatches through the registry
-    directly and never passes these kwargs.
+    The reference MSA module: a Python loop over the ``1 + len(scales)``
+    branches, each through ``attention_fn``.  A FIX8 tree
+    (``qconv`` leaves) runs its convs through ``conv2d_int8``.  The
+    fused module (all branches and heads in ONE attention launch) is the
+    kernel registry's ``msa`` kind, which ``core.program.execute``
+    dispatches to when the plan fuses the site.
     """
-    d = plan.get(site) if (plan is not None and site is not None) else None
-    if plan is not None and attention_fn is relu_global_attention:
-        if d.fused if d is not None else plan.default_fuse:
-            from repro.core.program import Site
-            from repro.kernels.registry import get_kernel
-            prec = d.precision if d is not None else "fp"
-            impl = get_kernel("msa", prec)
-            shim_site = Site(
-                name=site or "msa", kind="msa", stage="", param_path=(),
-                in_shape=x.shape, out_shape=x.shape,
-                attrs={"heads": cfg.n_heads, "head_dim": cfg.head_dim,
-                       "scales": tuple(cfg.scales),
-                       "n_branches": 1 + len(cfg.scales)})
-            return impl.apply(params, x, shim_site, d,
-                              interpret=plan.interpret)
-
-    # reference attention core — but an int8-fused decision keeps its
-    # W8A8 projections even when attention_fn overrides the fused core
-    int8_proj = (d is not None and d.fused and d.precision == "int8"
-                 and "qconv" in params["qkv"] and "qconv" in params["proj"])
     B, H, W, C = x.shape
-    if int8_proj:
-        from repro.kernels.int8_matmul.ops import conv1x1_w8a8
-        qkv = conv1x1_w8a8(params["qkv"]["qconv"], x,
-                           interpret=plan.interpret)  # (B,H,W,3*total)
-    else:
-        qkv = _conv_any(params["qkv"], x)             # (B,H,W,3*total)
+    qkv = _conv_any(params["qkv"], x)                 # (B,H,W,3*total)
     multi = [qkv]
     for i, s in enumerate(cfg.scales):
         agg = _conv_any(params["aggreg"][i]["dw"], qkv, groups=qkv.shape[-1])
@@ -150,9 +115,6 @@ def msa(params, x, cfg: MSAConfig, *, attention_fn=relu_global_attention,
         o = attention_fn(q, k, v)
         outs.append(o.reshape(B, H, W, cfg.total_dim))
     out = jnp.concatenate(outs, axis=-1)
-    if int8_proj:
-        return conv1x1_w8a8(params["proj"]["qconv"], out,
-                            interpret=plan.interpret)
     if "qconv" in params["proj"]:
         return _conv_any(params["proj"], out)  # BN folded by quantization
     out = pwconv(params["proj"], out)

@@ -15,7 +15,7 @@ Cache location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
 
 Inside a ``jax.jit`` trace there is nothing to time, so callers that may
 be under tracing pass ``bench=None`` and get the cached choice or the
-first (heuristic-default) candidate.  ``repro.core.fusion.build_plan``
+first (heuristic-default) candidate.  ``repro.core.fusion.plan_program``
 tunes ahead of time, outside jit, which is where the sweeps actually run.
 A sweep in which every candidate fails raises on a compiled backend (the
 chip's compiler refused every tile) and falls back to the default only

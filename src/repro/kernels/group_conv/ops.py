@@ -5,9 +5,8 @@ module's quantized ``aggreg`` list ({'dw','pw'} each holding a ``qconv``
 from ``core.quantization.quantize_efficientvit``) and runs the fused
 Pallas branch kernel — the FIX8 MSA module
 (``kernels.relu_attn.ops.msa_fused_apply``) calls it instead of falling
-back to the reference ``conv2d_int8``, which closes the ROADMAP item
-and moves ``core.fusion.EXPECTED_B1_FUSED_LAUNCHES_INT8`` to 29
-(one aggregation launch per scale next to the single attention core).
+back to the reference ``conv2d_int8``: one aggregation launch per scale
+next to the single attention core.
 
 This package is also the registry's worked "new kind" example
 (``("group_agg", "int8")``, an int8-only registration): a custom IR

@@ -72,15 +72,14 @@ reference ``conv2d_int8``).  The additive recipe it followed:
    (or, as here, fold it into the msa site's apply) and add the module
    to ``_BUILTIN_MODULES`` below.
 
-No changes to ``build_plan``, ``execute``, the benchmarks or the cycle
-model: any non-structural ``Site`` kind is fusible, the planner's
-generic loop resolves the impl by key (unknown kinds default to
-enabled), ``execute`` runs ``apply`` when the decision fuses and the
-impl's ``ref`` otherwise, and the drift-gate tests pin the launch-count
-consequences explicitly (``core.fusion.EXPECTED_B1_FUSED_LAUNCHES_INT8``
-moved 22 -> 29 when group_agg landed).  ``tests/test_program.py::
-test_registry_new_kernel_plans_and_executes`` exercises this flow
-end-to-end with a dummy kind.
+No changes to ``plan_program``, ``execute``, the benchmarks or the
+cycle model: any non-structural ``Site`` kind is fusible, the planner's
+generic loop resolves the impl by key, ``execute`` runs ``apply`` when
+the decision fuses and the impl's ``ref`` otherwise, and the drift-gate
+tests pin the launch-count consequences explicitly
+(``core.fusion.EXPECTED_B1_SUPERSITE_LAUNCHES_INT8``).
+``tests/test_program.py::test_registry_new_kernel_plans_and_executes``
+exercises this flow end-to-end with a dummy kind.
 """
 from __future__ import annotations
 

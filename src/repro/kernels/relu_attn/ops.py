@@ -220,14 +220,12 @@ class MsaKernel(KernelBase):
                                int8_proj=self.int8_proj,
                                epilogue=epilogue)
 
-    def ref(self, params, x, site, *, attention_fn=None, epilogue=None,
-            **kw):
+    def ref(self, params, x, site, *, epilogue=None, **kw):
         from repro.core.quantization import quantize_act
         from repro.core.relu_attention import MSAConfig, msa
         mcfg = MSAConfig(x.shape[-1], site.attrs["head_dim"],
                          site.attrs["scales"])
-        akw = {} if attention_fn is None else {"attention_fn": attention_fn}
-        out = msa(params, x, mcfg, **akw)
+        out = msa(params, x, mcfg)
         if epilogue is not None and epilogue.emits_q:
             return quantize_act(out, keep_fp=epilogue.residual == "keep-fp")
         return out

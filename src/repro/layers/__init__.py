@@ -1,43 +1,3 @@
-from repro.layers.attention import (  # noqa: F401
-    ATTN_RULES,
-    AttnConfig,
-    attention,
-    attention_decode,
-    cross_attention,
-    init_attention,
-    init_kv_cache,
-    relu_linear_attention_causal,
-    relu_linear_attention_noncausal,
-    sliding_attention,
-    softmax_attention,
-)
-from repro.layers.conv import (  # noqa: F401
-    conv2d,
-    dwconv2d,
-    init_conv2d,
-    init_dwconv2d,
-    init_pwconv,
-    pwconv,
-)
-from repro.layers.linear import embed, init_embedding, init_linear, linear, unembed  # noqa: F401
-from repro.layers.mamba2 import (  # noqa: F401
-    MAMBA2_RULES,
-    Mamba2Config,
-    init_mamba2,
-    init_mamba2_cache,
-    mamba2,
-    mamba2_decode,
-    ssd_chunked,
-)
-from repro.layers.mlp import MLP_RULES, MlpConfig, init_mlp, mlp  # noqa: F401
-from repro.layers.moe import MOE_RULES, MoeConfig, init_moe, moe  # noqa: F401
-from repro.layers.norms import (  # noqa: F401
-    batchnorm,
-    bn_fold_scale_bias,
-    init_batchnorm,
-    init_layernorm,
-    init_rmsnorm,
-    layernorm,
-    rmsnorm,
-)
-from repro.layers.rope import apply_rope, rope_freqs  # noqa: F401
+"""Layer building blocks.  Import each module directly
+(``repro.layers.conv``, ``repro.layers.norms``, ...): the package
+imports none of them, so the vision path loads only what it uses."""

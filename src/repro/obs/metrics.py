@@ -161,7 +161,7 @@ class Histogram:
 
 
 def _bucket_labels(key: tuple) -> Dict[str, object]:
-    names = ("bucket", "resolution", "precision", "epilogues")
+    names = ("bucket", "resolution", "precision")
     out = {}
     for i, part in enumerate(key):
         out[names[i] if i < len(names) else f"key{i}"] = part

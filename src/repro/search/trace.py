@@ -94,16 +94,15 @@ def workload(trace, buckets: Sequence[int], *,
     one scheduler step per arrival (full largest buckets dispatch
     immediately, a deadline-due tail flushes to the smallest covering
     bucket), then the straggler step after the deadline elapses, then
-    the final drain.  Uses the scheduler's own ``BucketedPolicy.form``,
+    the final drain.  Uses the scheduler's own ``form_batches``,
     so the model cannot drift from what serving actually does —
     ``tests/test_search.py`` pins the smoke trace's key set to
     ``serving_bench.EXPECTED_SMOKE_KEYS``.
     """
-    from repro.serving.scheduler import BucketedPolicy
+    from repro.serving.scheduler import form_batches
 
     buckets = tuple(sorted(set(int(b) for b in buckets)))
     assert buckets and buckets[0] >= 1, buckets
-    form = BucketedPolicy().form
     queues: dict[int, collections.deque] = {}
     counts: dict[Tuple[int, int], int] = collections.Counter()
 
@@ -111,7 +110,7 @@ def workload(trace, buckets: Sequence[int], *,
         for res, q in queues.items():
             due = drain or (deadline_ms is not None and any(
                 now >= at + deadline_ms / 1e3 for at in q))
-            for size in form(len(q), buckets, due):
+            for size in form_batches(len(q), buckets, due):
                 take = min(size, len(q))
                 if take == 0:
                     break

@@ -1,11 +1,7 @@
-from repro.serving.engine import ServeConfig, ServingEngine, Request  # noqa: F401
 from repro.serving.executors import (  # noqa: F401
     Executor, ExecutorCache, ExecutorKey)
-from repro.serving.sampler import SamplerConfig, sample  # noqa: F401
 from repro.serving.scheduler import (  # noqa: F401
-    BucketedPolicy, FixedMicrobatchPolicy, ManualClock, MicroBatchScheduler,
-    ResultCache)
-from repro.serving.scheduler import Request as VisionRequest  # noqa: F401
+    ManualClock, MicroBatchScheduler, ResultCache, Request, form_batches)
 from repro.serving.sharding import (  # noqa: F401
     DeviceHealth, ShardSpec, shard_width, sharded_forward)
 from repro.serving.telemetry import Telemetry  # noqa: F401

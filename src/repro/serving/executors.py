@@ -94,10 +94,6 @@ class ExecutorKey:
     batch: int        # bucket size (the compiled batch dimension)
     resolution: int   # square image size
     precision: str    # requested plan precision: "auto" | "fp" | "int8"
-    epilogues: bool = True   # producer-side int8 emission assigned by the
-    #                          plan (the int8 dataflow); False compiles the
-    #                          legacy consumer-side-quantize pipeline, so
-    #                          both dataflows can be cached side by side
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,11 +208,10 @@ class ExecutorCache:
 
     def __init__(self, params, cfg: EfficientViTConfig, *,
                  buckets: Tuple[int, ...] = (1, 2, 4, 8),
-                 precision: str = "auto", use_plan: bool = True,
-                 autotune: bool = True, interpret: bool | None = None,
+                 precision: str = "auto", autotune: bool = True,
+                 interpret: bool | None = None,
                  capacity: int | None = None,
                  telemetry: Telemetry | None = None,
-                 epilogues: bool = True,
                  faults=None, neg_ttl_s: float = 1.0, clock=None,
                  devices=None, artifact=None, tracer=None):
         assert buckets and all(b >= 1 for b in buckets), buckets
@@ -240,11 +235,9 @@ class ExecutorCache:
         self.artifact = artifact
         self.buckets = tuple(sorted(set(int(b) for b in buckets)))
         self.precision = precision
-        self.use_plan = use_plan
         self.autotune = autotune
         self.interpret = interpret
         self.capacity = capacity
-        self.epilogues = epilogues
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self.faults = faults
         self.neg_ttl_s = float(neg_ttl_s)
@@ -271,22 +264,9 @@ class ExecutorCache:
                 return b
         return self.buckets[-1]
 
-    def chunks_for(self, n: int) -> list[int]:
-        """Greedy bucket cover of ``n`` requests: full largest buckets,
-        then the smallest bucket that fits the ragged tail."""
-        out = []
-        big = self.buckets[-1]
-        while n >= big:
-            out.append(big)
-            n -= big
-        if n:
-            out.append(self.bucket_for(n))
-        return out
-
     # -- the cache -------------------------------------------------------
     def _key(self, batch: int, resolution: int) -> ExecutorKey:
-        return ExecutorKey(int(batch), int(resolution), self.precision,
-                           self.epilogues)
+        return ExecutorKey(int(batch), int(resolution), self.precision)
 
     def get(self, batch: int, resolution: int) -> Executor:
         key = self._key(batch, resolution)
@@ -396,7 +376,7 @@ class ExecutorCache:
                         image_size=key.resolution)
         self._t_end(lspan)
         plan = None
-        if self.use_plan and not (state is not None and state.level >= 2):
+        if not (state is not None and state.level >= 2):
             precision = "fp" if (state is not None and state.pinned_fp) \
                 else self.precision
             donor = self._donor_plans.get(key.resolution)
@@ -419,7 +399,6 @@ class ExecutorCache:
                                 autotune=self.autotune,
                                 interpret=self.interpret,
                                 precision=precision, reuse=donor,
-                                epilogues=key.epilogues,
                                 demote=(state.demoted if state is not None
                                         else ()),
                                 overrides=overrides)

@@ -98,8 +98,8 @@ from repro.common.errors import (
 from repro.serving.executors import ExecutorCache
 from repro.serving.telemetry import Telemetry
 
-__all__ = ["Request", "BucketedPolicy", "FixedMicrobatchPolicy",
-           "ManualClock", "MicroBatchScheduler", "ResultCache"]
+__all__ = ["Request", "form_batches", "ManualClock", "MicroBatchScheduler",
+           "ResultCache"]
 
 
 @dataclasses.dataclass
@@ -201,34 +201,19 @@ class ResultCache:
         return len(self._lru)
 
 
-class BucketedPolicy:
-    """Group into the largest ready bucket; flush the ragged tail to the
-    smallest bucket >= tail only when due (deadline or drain)."""
-
-    def form(self, qlen: int, buckets, due: bool) -> List[int]:
-        sizes = []
-        big = buckets[-1]
-        while qlen >= big:
-            sizes.append(big)
-            qlen -= big
-        if due and qlen:
-            sizes.append(next(b for b in buckets if b >= qlen))
-        return sizes
-
-
-class FixedMicrobatchPolicy:
-    """Legacy behavior: every dispatch is the full microbatch, the tail
-    padded up to it.  Kept as the A/B baseline (and the back-compat
-    ``VisionEngine`` policy)."""
-
-    def __init__(self, microbatch: int):
-        self.microbatch = int(microbatch)
-
-    def form(self, qlen: int, buckets, due: bool) -> List[int]:
-        sizes = [self.microbatch] * (qlen // self.microbatch)
-        if due and qlen % self.microbatch:
-            sizes.append(self.microbatch)
-        return sizes
+def form_batches(qlen: int, buckets, due: bool) -> List[int]:
+    """Batch formation: the bucket sizes to dispatch for ``qlen`` queued
+    requests over the ascending ``buckets``.  Full largest buckets go
+    at once; the ragged tail goes to the smallest bucket >= tail only
+    when ``due`` (deadline or drain)."""
+    sizes = []
+    big = buckets[-1]
+    while qlen >= big:
+        sizes.append(big)
+        qlen -= big
+    if due and qlen:
+        sizes.append(next(b for b in buckets if b >= qlen))
+    return sizes
 
 
 @dataclasses.dataclass(eq=False)
@@ -276,7 +261,7 @@ class MicroBatchScheduler:
     """
 
     def __init__(self, cache: ExecutorCache, params, *,
-                 policy=None, telemetry: Telemetry | None = None,
+                 telemetry: Telemetry | None = None,
                  clock=None, max_queue_depth: int | None = None,
                  max_retries: int = 4, backoff_ms: float = 10.0,
                  backoff_base: float = 2.0, faults=None,
@@ -288,7 +273,6 @@ class MicroBatchScheduler:
         # — begin/end cost two clock reads and a deque append; nothing
         # on the dispatch path synchronizes with the device.
         self.tracer = tracer
-        self.policy = policy if policy is not None else BucketedPolicy()
         self.telemetry = (telemetry if telemetry is not None
                           else cache.telemetry)
         self.clock = clock if clock is not None else time.monotonic
@@ -475,8 +459,7 @@ class MicroBatchScheduler:
             dispatched = 0
             for res, q in list(self._queues.items()):
                 due = drain or self._due(q)
-                for size in self.policy.form(len(q), self.cache.buckets,
-                                             due):
+                for size in form_batches(len(q), self.cache.buckets, due):
                     take = min(size, len(q))
                     if take == 0:
                         break

@@ -1,8 +1,6 @@
 """Vision serving: a thin façade over the serving runtime.
 
-``VisionEngine`` used to own one lowering, one plan and one jitted
-forward at a fixed microbatch, padding every request group up to it.
-It is now a façade over the runtime subsystem:
+``VisionEngine`` is a façade over the runtime subsystem:
 
     ``serving.executors.ExecutorCache``   shape-bucketed compiled
                                           executables, plans shared
@@ -11,14 +9,12 @@ It is now a façade over the runtime subsystem:
                                           deadline-aware flush
     ``serving.telemetry``                 per-bucket counters
 
-The constructor keeps the old contract — lower + plan once, outside the
-request loop, exposed as ``.program`` / ``.plan`` for the primary
-microbatch shape — and ``logits`` / ``classify`` / ``quantized`` behave
-as before, except the ragged tail of a batch now routes to the smallest
-cached bucket that fits it (policy ``"bucketed"``, the default) instead
-of padding to the full microbatch, and chunks dispatch without host
-synchronization between them.  ``policy="fixed"`` restores the legacy
-pad-to-microbatch behavior exactly.
+The constructor lowers and plans the primary microbatch shape once,
+outside the request loop, exposed as ``.program`` / ``.plan``.
+``logits`` / ``classify`` chunk a batch the way the scheduler forms
+batches (``scheduler.form_batches``: full largest buckets, the ragged
+tail to the smallest cached bucket that fits it), and chunks dispatch
+without host synchronization between them.
 """
 from __future__ import annotations
 
@@ -31,7 +27,7 @@ import numpy as np
 from repro.core.efficientvit import EfficientViTConfig
 from repro.serving.executors import ExecutorCache
 from repro.serving.scheduler import (
-    BucketedPolicy, FixedMicrobatchPolicy, MicroBatchScheduler, Request)
+    MicroBatchScheduler, Request, form_batches)
 from repro.serving.telemetry import Telemetry
 
 __all__ = ["VisionServeConfig", "VisionEngine"]
@@ -49,20 +45,14 @@ def _default_buckets(microbatch: int) -> tuple:
 
 @dataclasses.dataclass(frozen=True)
 class VisionServeConfig:
-    microbatch: int = 8       # largest batch bucket (and the fixed size
-    #                           under policy="fixed")
-    use_plan: bool = True     # False -> reference path (A/B and debugging)
+    microbatch: int = 8       # largest batch bucket
     autotune: bool = True
     precision: str = "auto"   # "auto" | "fp" | "int8" (FIX8 serving mode:
     #                           pass a quantize_efficientvit tree and the
     #                           plan routes the int8 megakernels)
-    policy: str = "bucketed"  # "bucketed" | "fixed" (legacy pad-to-mb)
     buckets: tuple | None = None   # None -> powers of 2 up to microbatch
     capacity: int | None = None    # executor-cache LRU capacity (None =
     #                                unbounded)
-    epilogues: bool = True    # producer-side int8 emission (the int8
-    #                           dataflow); False serves the legacy
-    #                           consumer-side-quantize pipeline (A/B)
     devices: tuple | None = None   # device mesh for batch-axis sharding
     #                                + per-device fault domains; None =
     #                                classic single-device serving
@@ -81,7 +71,6 @@ class VisionEngine:
     def __init__(self, params, cfg: EfficientViTConfig,
                  serve_cfg: VisionServeConfig = VisionServeConfig(), *,
                  faults=None, tracer=None):
-        assert serve_cfg.policy in ("bucketed", "fixed"), serve_cfg.policy
         self.params = params
         self.cfg = cfg
         self.serve_cfg = serve_cfg
@@ -109,8 +98,7 @@ class VisionEngine:
             mb = serve_cfg.microbatch
             buckets = serve_cfg.buckets
             if buckets is None:
-                buckets = (mb,) if serve_cfg.policy == "fixed" \
-                    else _default_buckets(mb)
+                buckets = _default_buckets(mb)
             # the microbatch is always a bucket: it is the primary
             # compiled shape, and chunking must never hand an n-row
             # batch to an executor compiled for fewer rows
@@ -119,9 +107,8 @@ class VisionEngine:
         self.telemetry = Telemetry()
         self.cache = ExecutorCache(
             params, cfg, buckets=buckets, precision=serve_cfg.precision,
-            use_plan=serve_cfg.use_plan, autotune=serve_cfg.autotune,
-            capacity=serve_cfg.capacity, telemetry=self.telemetry,
-            epilogues=serve_cfg.epilogues, faults=faults,
+            autotune=serve_cfg.autotune, capacity=serve_cfg.capacity,
+            telemetry=self.telemetry, faults=faults,
             devices=serve_cfg.devices, artifact=artifact, tracer=tracer)
         # primary executor built eagerly: plan construction (autotune
         # sweeps included) happens here, outside the request loop, and
@@ -140,26 +127,21 @@ class VisionEngine:
         return cls(quantize_efficientvit(params, cfg), cfg,
                    dataclasses.replace(serve_cfg, precision="int8"))
 
-    # -- batch API (back-compat) ----------------------------------------
+    # -- batch API -------------------------------------------------------
     def logits(self, images) -> jax.Array:
         """images: (n, H, W, 3), any n -> (n, num_classes).
 
         Chunks dispatch asynchronously (no host sync between them); the
-        ragged tail routes to the smallest cached bucket >= its size
-        under the bucketed policy, so a 9-image call with microbatch 8
-        runs an 8-bucket and a 1-bucket instead of padding 8+8.
+        ragged tail routes to the smallest cached bucket >= its size,
+        so a 9-image call with microbatch 8 runs an 8-bucket and a
+        1-bucket instead of padding 8+8.
         """
         images = jnp.asarray(images)
         n = int(images.shape[0])
         res = int(images.shape[1])
-        mb = self.microbatch
-        if self.serve_cfg.policy == "fixed":
-            sizes = [mb] * -(-n // mb)           # pad every chunk to mb
-        else:
-            sizes = self.cache.chunks_for(n)     # tail -> smallest bucket
         outs = []
         i = 0
-        for bucket in sizes:
+        for bucket in form_batches(n, self.cache.buckets, due=True):
             take = min(bucket, n - i)
             chunk = images[i:i + take]
             if bucket > take:
@@ -178,22 +160,17 @@ class VisionEngine:
         return np.asarray(jnp.argmax(self.logits(images), axis=-1))
 
     # -- request API (the serving runtime) ------------------------------
-    def scheduler(self, *, clock=None, policy=None,
-                  **kw) -> MicroBatchScheduler:
+    def scheduler(self, *, clock=None, **kw) -> MicroBatchScheduler:
         """A continuous micro-batching scheduler bound to this engine's
         executor cache, params and telemetry.  Extra keywords
         (``max_queue_depth``, ``max_retries``, ``backoff_ms``, ...) pass
         through to ``MicroBatchScheduler``; the engine's fault plan is
         installed unless overridden."""
-        if policy is None:
-            policy = (FixedMicrobatchPolicy(self.microbatch)
-                      if self.serve_cfg.policy == "fixed"
-                      else BucketedPolicy())
         kw.setdefault("faults", self.faults)
         kw.setdefault("result_cache", self.serve_cfg.result_cache)
         kw.setdefault("watchdog_ms", self.serve_cfg.watchdog_ms)
         kw.setdefault("tracer", self.tracer)
-        return MicroBatchScheduler(self.cache, self.params, policy=policy,
+        return MicroBatchScheduler(self.cache, self.params,
                                    telemetry=self.telemetry, clock=clock,
                                    **kw)
 

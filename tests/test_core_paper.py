@@ -9,9 +9,10 @@ from numpy.testing import assert_allclose
 
 from proptest import sweep
 
-from repro.core.accelerator_model import HwConfig, TABLE_II, analyze
+from repro.core.accelerator_model import TABLE_II, analyze_program
 from repro.core.efficientvit import (
-    B1, B1_SMOKE, efficientvit, init_efficientvit, layer_manifest, total_macs)
+    B1, B1_SMOKE, efficientvit, init_efficientvit, total_macs)
+from repro.core.program import lower, manifest
 from repro.core.quantization import (
     conv2d_int8, fold_bn_into_conv, quantization_error, quantize_efficientvit,
     quantize_tensor)
@@ -124,7 +125,7 @@ def test_conv2d_int8_matches_fp():
 # ---------------------------------------------------------------------------
 
 def test_table2_reproduction():
-    rep, stages, _ = analyze(B1)
+    rep, stages, _ = analyze_program(lower(B1))
     paper = TABLE_II["Paper (ZCU102)"]
     assert abs(rep.gops - paper["gops"]) / paper["gops"] < 0.05, rep.gops
     assert abs(rep.gops_per_w - paper["eff"]) / paper["eff"] < 0.05
@@ -133,7 +134,7 @@ def test_table2_reproduction():
 
 
 def test_fig6_stage_profile():
-    rep, stages, sched = analyze(B1)
+    rep, stages, sched = analyze_program(lower(B1))
     # Fig. 6 observation (1): the 3-channel stem conv has low utilization
     first = next(s for s in sched if s.name == "conv1")
     assert first.util < 0.5
@@ -144,8 +145,8 @@ def test_fig6_stage_profile():
 
 def test_tmp_fusion_ablation():
     """Fusion must strictly help both cycles and DRAM traffic (§III-D)."""
-    fused, _, _ = analyze(B1, fuse=True)
-    unfused, _, _ = analyze(B1, fuse=False)
+    fused, _, _ = analyze_program(lower(B1), fuse=True)
+    unfused, _, _ = analyze_program(lower(B1), fuse=False)
     assert fused.total_cycles < unfused.total_cycles
     assert fused.dram_bytes <= unfused.dram_bytes
     assert fused.gops > unfused.gops
@@ -153,7 +154,7 @@ def test_tmp_fusion_ablation():
 
 def test_speedups_vs_cpu_baseline():
     """Paper: 14.3x throughput / 21.1x efficiency vs Snapdragon CPU."""
-    rep, _, _ = analyze(B1)
+    rep, _, _ = analyze_program(lower(B1))
     cpu = TABLE_II["EfficientViT [8] (CPU)"]
     speedup = rep.gops / cpu["gops"]
     eff_gain = rep.gops_per_w / cpu["eff"]
@@ -162,7 +163,7 @@ def test_speedups_vs_cpu_baseline():
 
 
 def test_manifest_macs_consistent():
-    ops = layer_manifest(B1)
+    ops = manifest(lower(B1))
     assert sum(o.macs for o in ops) == total_macs(B1)
     assert all(o.macs > 0 for o in ops)
 

@@ -16,6 +16,7 @@ from proptest import sweep
 from repro.common.errors import PlanError
 from repro.core.efficientvit import (
     B1_SMOKE, efficientvit, init_efficientvit, init_mbconv, mbconv)
+from repro.core.program import execute, lower
 from repro.core.relu_attention import MSAConfig, init_msa, msa
 from repro.kernels import autotune as autotune_mod
 from repro.kernels.autotune import autotune, pad_to_multiple
@@ -101,13 +102,15 @@ def test_msa_batched_matches_per_branch():
 
 
 def test_msa_plan_matches_reference(tmp_autotune_cache):
-    from repro.core.fusion import FusionPlan
+    """The fused MSA module (what a plan routes a fused msa site to)
+    matches the reference module, multi-scale and ragged."""
+    from repro.kernels.relu_attn.ops import msa_fused_apply
     key = jax.random.PRNGKey(1)
     cfg = MSAConfig(channels=32, head_dim=16, scales=(3, 5))
     params = init_msa(key, cfg)
     x = jax.random.normal(key, (2, 7, 7, 32))     # ragged N = 49
-    ref = msa(params, x, cfg)                     # plan=None: reference
-    out = msa(params, x, cfg, plan=FusionPlan(decisions={}))
+    ref = msa(params, x, cfg)
+    out = msa_fused_apply(params, x, cfg.n_heads, cfg.head_dim)
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
@@ -116,15 +119,16 @@ def test_msa_plan_matches_reference(tmp_autotune_cache):
 # ---------------------------------------------------------------------------
 
 def test_efficientvit_fused_forward_matches_reference(tmp_autotune_cache):
-    from repro.core.fusion import build_plan, launch_counts
+    from repro.core.fusion import launch_counts, plan_program
     key = jax.random.PRNGKey(0)
     params = init_efficientvit(key, B1_SMOKE)
     x = jax.random.normal(key, (2, 64, 64, 3))
-    plan = build_plan(params, B1_SMOKE, batch=2, autotune=False)
+    program = lower(B1_SMOKE, batch=2)
+    plan = plan_program(program, params, autotune=False)
     assert plan.n_fused() == len(plan.decisions)  # everything qualifies
     ref = jax.jit(lambda p, x: efficientvit(p, x, B1_SMOKE))(params, x)
     fus = jax.jit(
-        lambda p, x: efficientvit(p, x, B1_SMOKE, plan=plan))(params, x)
+        lambda p, x: execute(program, p, x, plan=plan))(params, x)
     assert_allclose(np.asarray(fus), np.asarray(ref), rtol=1e-3, atol=1e-3)
     lc = launch_counts(plan)
     assert lc["fused"] == len(plan.decisions)     # one launch per site
@@ -138,20 +142,20 @@ def test_quantized_blocks_forced_fp_route_to_reference(tmp_autotune_cache):
     """precision="fp" on a FIX8 tree preserves the old demotion behavior
     (the fp megakernels can't consume int8 weights) — and the plan-routed
     forward still matches the reference quantized path."""
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     from repro.core.quantization import quantize_efficientvit
     key = jax.random.PRNGKey(2)
     params = init_efficientvit(key, B1_SMOKE)
     qparams = quantize_efficientvit(params)
-    plan = build_plan(qparams, B1_SMOKE, batch=1, autotune=False,
-                      precision="fp")
+    program = lower(B1_SMOKE, batch=1)
+    plan = plan_program(program, qparams, autotune=False, precision="fp")
     conv_sites = [d for d in plan.decisions.values()
                   if d.kind in ("dsconv", "mbconv")]
     assert conv_sites and all(not d.fused and d.reason == "quantized"
                               for d in conv_sites)
     x = jax.random.normal(key, (1, 64, 64, 3))
     ref = efficientvit(qparams, x, B1_SMOKE)
-    out = efficientvit(qparams, x, B1_SMOKE, plan=plan)
+    out = execute(program, qparams, x, plan=plan)
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-3, atol=1e-3)
 
 

@@ -72,21 +72,6 @@ def test_fp_plan_assigns_no_epilogues(tmp_autotune_cache):
     assert not any(d.q_in for d in plan.decisions.values())
 
 
-def test_epilogues_opt_out(tmp_autotune_cache):
-    """plan_program(..., epilogues=False) keeps the legacy consumer-side
-    quantize dataflow — and matches the epilogue chain bit-for-bit at
-    batch 1 (the arithmetic only moved across the boundary)."""
-    qparams = _qtree(2)
-    program = lower(B1_SMOKE, batch=1, image_size=64)
-    on = plan_program(program, qparams, autotune=False)
-    off = plan_program(program, qparams, autotune=False, epilogues=False)
-    assert on.epilogues and not off.epilogues
-    x = jax.random.normal(jax.random.PRNGKey(3), (1, 64, 64, 3))
-    np.testing.assert_array_equal(
-        np.asarray(execute(program, qparams, x, plan=on)),
-        np.asarray(execute(program, qparams, x, plan=off)))
-
-
 # ---------------------------------------------------------------------------
 # producer-epilogue kernel parity vs the XLA-quantize reference
 # ---------------------------------------------------------------------------
@@ -325,9 +310,9 @@ def test_vision_engine_quantized_epilogue_dataflow(tmp_autotune_cache):
     params = init_efficientvit(key, B1_SMOKE)
     eng = VisionEngine.quantized(
         params, B1_SMOKE, VisionServeConfig(microbatch=2, autotune=False))
-    # the compiled executors carry the epilogue mode in their cache key,
-    # and the cached (annotated) program exposes the delivered dtypes
-    assert all(k.epilogues for k in eng.cache.keys())
+    # the plan assigns producer epilogues, and the cached (annotated)
+    # program exposes the delivered dtypes
+    assert eng.plan.epilogues
     assert any(s.epilogue.emits_q for s in eng.program.sites)
     imgs = jax.random.normal(key, (3, 64, 64, 3))
     logits = eng.logits(imgs)
@@ -340,14 +325,6 @@ def test_vision_engine_quantized_epilogue_dataflow(tmp_autotune_cache):
                     rtol=1e-5, atol=1e-7)
     assert_allclose(np.asarray(logits), np.asarray(ref),
                     rtol=1e-4, atol=1e-4)
-    # legacy dataflow stays available as an A/B lever, same answers
-    eng_off = VisionEngine.quantized(
-        params, B1_SMOKE, VisionServeConfig(microbatch=2, autotune=False,
-                                            epilogues=False))
-    assert not any(k.epilogues for k in eng_off.cache.keys())
-    assert_allclose(np.asarray(logits[2:]),
-                    np.asarray(eng_off.logits(imgs)[2:]),
-                    rtol=1e-5, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ from repro.obs import (
     escape_label, load_result, request_chains, validate_chrome_trace,
     validate_result, write_result)
 from repro.serving.scheduler import (
-    BucketedPolicy, ManualClock, MicroBatchScheduler, Request)
+    ManualClock, MicroBatchScheduler, Request, form_batches)
 from repro.serving.telemetry import Telemetry
 
 
@@ -407,7 +407,7 @@ def test_clock_reads_without_a_tracer_are_pinned(n):
         sched.step(drain=True)
         sched.finalize()
         reads[traced] = clock.reads
-    batches = len(BucketedPolicy().form(n, (1, 2, 4), due=True))
+    batches = len(form_batches(n, (1, 2, 4), due=True))
     assert reads[False] == n + 2 + 2 * batches
     assert reads[True] == reads[False] + 2 * len(tracer.spans())
 

@@ -5,8 +5,8 @@ truth — the fusion plan's site set, the layer manifest's MACs, and the
 analytic HBM accounting all derive from the same ``Site`` sequence and
 therefore cannot drift from what ``execute`` actually runs.  Plus the
 launch-count drift gate: EfficientViT-B1 @224 fuses to exactly
-``core.fusion.EXPECTED_B1_FUSED_LAUNCHES`` launches until someone
-updates that expectation explicitly.
+``core.fusion.EXPECTED_B1_SUPERSITE_LAUNCHES`` (fp) / ``_INT8`` launches
+until someone updates that expectation explicitly.
 """
 import jax
 import jax.numpy as jnp
@@ -15,17 +15,15 @@ import pytest
 from numpy.testing import assert_allclose
 
 from repro.core.efficientvit import (
-    B1, B1_SMOKE, efficientvit, init_efficientvit, layer_manifest,
-    total_macs)
+    B1, B1_SMOKE, efficientvit, init_efficientvit, total_macs)
 from repro.core.fusion import (
-    EXPECTED_B1_FUSED_LAUNCHES, EXPECTED_B1_FUSED_LAUNCHES_INT8,
     EXPECTED_B1_SUPERSITE_LAUNCHES, EXPECTED_B1_SUPERSITE_LAUNCHES_INT8,
-    build_plan, launch_counts, plan_program, plan_report, site_traffic)
+    launch_counts, plan_program, plan_report, site_traffic)
 from repro.core.program import FUSIBLE_KINDS, execute, lower, manifest, params_at
 from repro.core.quantization import quantize_efficientvit
 from repro.kernels import registry
 
-# Legacy hand-written layer_manifest totals (pre-IR walk), pinned: the
+# Hand-written manifest totals of the pre-IR walk, pinned: the
 # IR-derived manifest must reproduce them exactly.
 LEGACY_TOTAL_MACS = {"B1": 518_963_712, "B1_SMOKE": 2_038_080}
 LEGACY_N_RECORDS = {"B1": 90, "B1_SMOKE": 36}
@@ -37,13 +35,13 @@ LEGACY_N_RECORDS = {"B1": 90, "B1_SMOKE": 36}
 
 @pytest.mark.parametrize("cfg", [B1, B1_SMOKE], ids=["b1", "smoke"])
 def test_program_sites_match_plan_keys(cfg, tmp_autotune_cache):
-    """Fusible site set == build_plan decision keys (both precisions)."""
+    """Fusible site set == plan_program decision keys (both precisions)."""
     program = lower(cfg, batch=1)
     params = init_efficientvit(jax.random.PRNGKey(0), cfg)
     ir_names = [s.name for s in program.fusible()]
     assert len(set(ir_names)) == len(ir_names)      # unique
     for tree in (params, quantize_efficientvit(params)):
-        plan = build_plan(tree, cfg, batch=1, autotune=False)
+        plan = plan_program(program, tree, autotune=False)
         assert list(plan.decisions) == ir_names
         for s in program.fusible():
             d = plan.get(s.name)
@@ -54,9 +52,8 @@ def test_program_sites_match_plan_keys(cfg, tmp_autotune_cache):
 def test_ir_manifest_matches_legacy(name, cfg):
     """total_macs / record count from the IR == the legacy manifest."""
     assert total_macs(cfg) == LEGACY_TOTAL_MACS[name]
-    records = layer_manifest(cfg)
+    records = manifest(lower(cfg))
     assert len(records) == LEGACY_N_RECORDS[name]
-    assert records == manifest(lower(cfg))          # shim is the IR
 
 
 def test_param_paths_resolve():
@@ -99,7 +96,7 @@ def test_plan_report_matches_site_traffic(precision, tmp_autotune_cache):
 
 
 def test_execute_is_the_forward(tmp_autotune_cache):
-    """The efficientvit() shim and raw execute() are the same function,
+    """efficientvit() and raw execute() are the same function,
     and the plan-routed program agrees with the reference program."""
     key = jax.random.PRNGKey(3)
     params = init_efficientvit(key, B1_SMOKE)
@@ -119,21 +116,18 @@ def test_execute_is_the_forward(tmp_autotune_cache):
 
 def test_b1_fused_launch_drift_gate(tmp_autotune_cache):
     """19 fused launches at B1/224 fp and 26 at int8: super-site
-    grouping collapses the S1 pair (-1) and the S2 triple (-2) into one
-    launch each at both precisions, down from the per-site 22/29 (the
+    grouping collapses the S1 pair (-1) and the S2 triple (-2) of B1's
+    22 fusible sites into one launch each at both precisions (the
     grouped aggregation kernel adds one launch per scale per fused MSA
-    module).  If a lowering or planner change moves any of these,
-    update EXPECTED_B1_SUPERSITE_LAUNCHES / _INT8 (or, with
-    supersites=False, EXPECTED_B1_FUSED_LAUNCHES / _INT8) and the
+    module at int8).  If a lowering or planner change moves any of
+    these, update EXPECTED_B1_SUPERSITE_LAUNCHES / _INT8 and the
     EXPERIMENTS.md narrative explicitly — this test failing is the
     drift alarm, not an inconvenience to silence."""
     program = lower(B1, batch=1)
-    assert len(program.fusible()) == EXPECTED_B1_FUSED_LAUNCHES
+    assert len(program.fusible()) == 22
     params = init_efficientvit(jax.random.PRNGKey(4), B1)
     expected = {"fp": EXPECTED_B1_SUPERSITE_LAUNCHES,
                 "int8": EXPECTED_B1_SUPERSITE_LAUNCHES_INT8}
-    persite = {"fp": EXPECTED_B1_FUSED_LAUNCHES,
-               "int8": EXPECTED_B1_FUSED_LAUNCHES_INT8}
     for prec, tree in (("fp", params),
                        ("int8", quantize_efficientvit(params))):
         plan = plan_program(program, tree, autotune=False)
@@ -143,12 +137,6 @@ def test_b1_fused_launch_drift_gate(tmp_autotune_cache):
         assert {g.name: list(g.members) for g in plan.groups.values()} \
             == {"S1.ss0": ["S1.mb0", "S1.mb1"],
                 "S2.ss0": ["S2.mb0", "S2.mb1", "S2.mb2"]}
-        # the per-site expectation is still what the planner produces
-        # with the grouping pass disabled
-        flat = plan_program(program, tree, autotune=False,
-                            supersites=False)
-        assert launch_counts(flat)["fused"] == persite[prec], prec
-        assert not flat.groups
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """FIX8 fused path: int8 megakernels with in-kernel requantization.
 
 The contract under test: a ``quantize_efficientvit`` tree routed through
-a ``build_plan(..., precision="auto"|"int8")`` plan must fuse every site
+a ``plan_program(..., precision="auto"|"int8")`` plan must fuse every site
 the fp plan fuses (zero ``"quantized"`` fallbacks) and agree with the
 int8 *reference* path — bit-exactly at batch 1, where the in-kernel
 per-batch-element requantization scales coincide with the reference
@@ -17,6 +17,7 @@ from proptest import sweep
 from repro.core.efficientvit import (
     B1_SMOKE, dsconv, efficientvit, init_dsconv, init_efficientvit,
     init_mbconv, mbconv)
+from repro.core.program import execute, lower
 from repro.core.quantization import (
     calibrate_act_scale, quantize_efficientvit, quantize_tensor)
 from repro.kernels.dsconv.kernel import dsconv_fused_int8
@@ -130,20 +131,24 @@ def test_conv1x1_w8a8_matches_conv2d_int8():
 
 
 def test_quantized_msa_fused_matches_reference(tmp_autotune_cache):
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     from repro.core.relu_attention import MSAConfig, msa
+    from repro.kernels.registry import get_kernel
     key = jax.random.PRNGKey(4)
     params = init_efficientvit(key, B1_SMOKE)
     qparams = quantize_efficientvit(params)
-    plan = build_plan(qparams, B1_SMOKE, batch=1, autotune=False)
+    program = lower(B1_SMOKE, batch=1)
+    plan = plan_program(program, qparams, autotune=False)
     site = "S3.evit0.msa"
-    assert plan.get(site).precision == "int8"
+    d = plan.get(site)
+    assert d.fused and d.precision == "int8"
     c = B1_SMOKE.widths[3]
     mcfg = MSAConfig(c, B1_SMOKE.head_dim, tuple(B1_SMOKE.msa_scales))
     p = qparams["stage3"]["blocks"][0]["msa"]
     x = jax.random.normal(key, (1, 8, 8, c))
     ref = msa(p, x, mcfg)                       # reference quantized path
-    out = msa(p, x, mcfg, plan=plan, site=site)
+    out = get_kernel("msa", d.precision).apply(      # the fused route
+        p, x, program.site(site), d, interpret=plan.interpret)
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
 
@@ -152,25 +157,26 @@ def test_quantized_msa_fused_matches_reference(tmp_autotune_cache):
 # ---------------------------------------------------------------------------
 
 def test_plan_fuses_every_quantized_site(tmp_autotune_cache):
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     key = jax.random.PRNGKey(5)
     params = init_efficientvit(key, B1_SMOKE)
     qparams = quantize_efficientvit(params)
-    fp_plan = build_plan(params, B1_SMOKE, batch=1, autotune=False)
-    q_plan = build_plan(qparams, B1_SMOKE, batch=1, autotune=False)
+    program = lower(B1_SMOKE, batch=1)
+    fp_plan = plan_program(program, params, autotune=False)
+    q_plan = plan_program(program, qparams, autotune=False)
     assert not any(d.reason == "quantized"
                    for d in q_plan.decisions.values())
     assert q_plan.n_fused() >= fp_plan.n_fused()
     assert all(d.precision == "int8"
                for d in q_plan.decisions.values() if d.fused)
     # explicit int8 request on the quantized tree: identical routing
-    q_plan2 = build_plan(qparams, B1_SMOKE, batch=1, autotune=False,
-                         precision="int8")
+    q_plan2 = plan_program(program, qparams, autotune=False,
+                           precision="int8")
     assert {d.name: d.precision for d in q_plan2.decisions.values()} == \
         {d.name: d.precision for d in q_plan.decisions.values()}
     # int8 requested on an fp tree -> conv sites demote to reference
-    fp_forced = build_plan(params, B1_SMOKE, batch=1, autotune=False,
-                           precision="int8")
+    fp_forced = plan_program(program, params, autotune=False,
+                             precision="int8")
     conv = [d for d in fp_forced.decisions.values()
             if d.kind in ("dsconv", "mbconv")]
     assert conv and all(not d.fused and d.reason == "not-quantized"
@@ -181,7 +187,7 @@ def test_mixed_tree_demotes_gracefully(tmp_autotune_cache):
     """Hand-edited trees (site part-quantized) must fall back, not crash:
     conv sites demote with reason="mixed", an MSA with an fp proj keeps
     its projections on the reference path (precision "fp")."""
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     key = jax.random.PRNGKey(11)
     params = init_efficientvit(key, B1_SMOKE)
     qparams = quantize_efficientvit(params)
@@ -197,26 +203,28 @@ def test_mixed_tree_demotes_gracefully(tmp_autotune_cache):
                                   ["msa"]["proj_bn"]),
                       "mbconv": qparams["stage3"]["blocks"][0]["mbconv"]}]}
     mixed["stage3"] = s3
-    plan = build_plan(mixed, B1_SMOKE, batch=1, autotune=False)
+    program = lower(B1_SMOKE, batch=1)
+    plan = plan_program(program, mixed, autotune=False)
     d_mb = plan.get("S1.mb0")
     assert not d_mb.fused and d_mb.reason == "mixed"
     d_msa = plan.get("S3.evit0.msa")
     assert d_msa.fused and d_msa.precision == "fp"
     x = jax.random.normal(key, (1, 64, 64, 3))
-    out = efficientvit(mixed, x, B1_SMOKE, plan=plan)   # must not crash
+    out = execute(program, mixed, x, plan=plan)   # must not crash
     ref = efficientvit(mixed, x, B1_SMOKE)
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-3, atol=1e-3)
 
 
 def test_quantized_full_forward_bit_exact_batch1(tmp_autotune_cache):
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     key = jax.random.PRNGKey(6)
     qparams = quantize_efficientvit(init_efficientvit(key, B1_SMOKE))
-    plan = build_plan(qparams, B1_SMOKE, batch=1, autotune=False)
+    program = lower(B1_SMOKE, batch=1)
+    plan = plan_program(program, qparams, autotune=False)
     x = jax.random.normal(key, (1, 64, 64, 3))
     ref = jax.jit(lambda p, x: efficientvit(p, x, B1_SMOKE))(qparams, x)
     fus = jax.jit(
-        lambda p, x: efficientvit(p, x, B1_SMOKE, plan=plan))(qparams, x)
+        lambda p, x: execute(program, p, x, plan=plan))(qparams, x)
     assert bool((jnp.argmax(ref, -1) == jnp.argmax(fus, -1)).all())
     assert float(jnp.max(jnp.abs(ref - fus))) < 1e-2
     # conv megakernel sites are bit-identical at batch 1; the msa qkv/proj
@@ -225,13 +233,14 @@ def test_quantized_full_forward_bit_exact_batch1(tmp_autotune_cache):
 
 
 def test_quantized_full_forward_batch2_within_noise(tmp_autotune_cache):
-    from repro.core.fusion import build_plan
+    from repro.core.fusion import plan_program
     key = jax.random.PRNGKey(7)
     qparams = quantize_efficientvit(init_efficientvit(key, B1_SMOKE))
-    plan = build_plan(qparams, B1_SMOKE, batch=2, autotune=False)
+    program = lower(B1_SMOKE, batch=2)
+    plan = plan_program(program, qparams, autotune=False)
     x = jax.random.normal(key, (2, 64, 64, 3))
     ref = efficientvit(qparams, x, B1_SMOKE)
-    fus = efficientvit(qparams, x, B1_SMOKE, plan=plan)
+    fus = execute(program, qparams, x, plan=plan)
     assert bool((jnp.argmax(ref, -1) == jnp.argmax(fus, -1)).all())
     assert float(jnp.max(jnp.abs(ref - fus))) < 1e-2
 

@@ -26,8 +26,7 @@ from repro.core.program import execute, lower
 from repro.core.quantization import quantize_efficientvit
 from repro.serving.executors import ExecutorCache, ExecutorKey
 from repro.serving.scheduler import (
-    BucketedPolicy, FixedMicrobatchPolicy, ManualClock, MicroBatchScheduler,
-    Request)
+    ManualClock, MicroBatchScheduler, Request, form_batches)
 from repro.serving.telemetry import Telemetry, percentile
 
 
@@ -191,14 +190,16 @@ def test_executor_cache_plan_reuse_across_buckets(smoke_params,
 
 
 def test_bucket_cover(smoke_params):
-    cache = ExecutorCache(smoke_params, B1_SMOKE, buckets=(1, 2, 4),
-                          use_plan=False)
+    cache = ExecutorCache(smoke_params, B1_SMOKE, buckets=(1, 2, 4))
     assert cache.bucket_for(1) == 1 and cache.bucket_for(3) == 4
     assert cache.bucket_for(9) == 4          # caller splits
-    assert cache.chunks_for(7) == [4, 4]     # tail 3 -> smallest bucket >= 3
-    assert cache.chunks_for(5) == [4, 1]
-    assert cache.chunks_for(4) == [4]
-    assert cache.chunks_for(3) == [4]        # 3 pads into one 4-bucket
+    # VisionEngine.logits chunks a batch as a due queue
+    def chunks(n):
+        return form_batches(n, cache.buckets, due=True)
+    assert chunks(7) == [4, 4]               # tail 3 -> smallest bucket >= 3
+    assert chunks(5) == [4, 1]
+    assert chunks(4) == [4]
+    assert chunks(3) == [4]                  # 3 pads into one 4-bucket
 
 
 def test_executor_warmup_compiles_working_set(smoke_params,
@@ -215,18 +216,17 @@ def test_executor_warmup_compiles_working_set(smoke_params,
 # scheduler
 # ---------------------------------------------------------------------------
 
-def _scheduler(params, buckets=(1, 2, 4), policy=None, clock=None,
-               precision="auto"):
+def _scheduler(params, buckets=(1, 2, 4), clock=None, precision="auto"):
     cache = ExecutorCache(params, B1_SMOKE, buckets=buckets,
                           precision=precision, autotune=False)
-    return MicroBatchScheduler(cache, params, policy=policy, clock=clock)
+    return MicroBatchScheduler(cache, params, clock=clock)
 
 
 def test_scheduler_bucketed_tail_no_padding(smoke_params,
                                             tmp_autotune_cache):
     """5 same-resolution requests over buckets (1,2,4) dispatch as a
-    full 4-bucket plus a 1-bucket tail — zero padded slots (the fixed
-    policy pads 3) — and match the reference forward."""
+    full 4-bucket plus a 1-bucket tail — zero padded slots — and match
+    the reference forward."""
     sched = _scheduler(smoke_params)
     imgs = _images(5, 32)
     out = sched.serve([Request(rid=i, image=imgs[i]) for i in range(5)])
@@ -280,28 +280,12 @@ def test_scheduler_mixed_resolutions(smoke_params, tmp_autotune_cache):
     assert_allclose(out[[1, 3]], ref64, rtol=1e-3, atol=1e-3)
 
 
-def test_fixed_policy_pads_to_microbatch(smoke_params, tmp_autotune_cache):
-    """The legacy baseline: 5 requests at microbatch 4 dispatch 4+4 with
-    3 padded slots (vs 0 for the bucketed policy)."""
-    sched = _scheduler(smoke_params, policy=FixedMicrobatchPolicy(4))
-    imgs = _images(5, 32)
-    sched.serve([Request(rid=i, image=imgs[i]) for i in range(5)])
-    tel = sched.telemetry
-    assert tel.total("padded") == 3
-    assert tel.total("dispatches") == 2
-    assert {key[0] for key in tel.buckets} == {4}
-
-
 def test_bucketed_policy_formation():
     buckets = (1, 2, 4)
-    p = BucketedPolicy()
-    assert p.form(9, buckets, due=False) == [4, 4]
-    assert p.form(9, buckets, due=True) == [4, 4, 1]
-    assert p.form(3, buckets, due=False) == []
-    assert p.form(3, buckets, due=True) == [4]
-    f = FixedMicrobatchPolicy(4)
-    assert f.form(9, buckets, due=False) == [4, 4]
-    assert f.form(9, buckets, due=True) == [4, 4, 4]
+    assert form_batches(9, buckets, due=False) == [4, 4]
+    assert form_batches(9, buckets, due=True) == [4, 4, 1]
+    assert form_batches(3, buckets, due=False) == []
+    assert form_batches(3, buckets, due=True) == [4]
 
 
 # ---------------------------------------------------------------------------
@@ -321,21 +305,6 @@ def test_vision_engine_tail_routes_to_small_bucket(smoke_params,
     used = {(k.batch, k.resolution) for k in eng.cache.keys()}
     assert (1, 64) in used                     # tail bucket, not pad-to-4
     assert eng.telemetry.total("padded") == 0
-
-
-def test_vision_engine_fixed_policy_back_compat(smoke_params,
-                                                tmp_autotune_cache):
-    from repro.serving.vision import VisionEngine, VisionServeConfig
-    eng = VisionEngine(smoke_params, B1_SMOKE,
-                       VisionServeConfig(microbatch=2, autotune=False,
-                                         policy="fixed"))
-    imgs = _images(3, 64)
-    logits = eng.logits(imgs)
-    ref = efficientvit(smoke_params, imgs, B1_SMOKE)
-    assert_allclose(np.asarray(logits), np.asarray(ref),
-                    rtol=1e-3, atol=1e-3)
-    assert {(k.batch, k.resolution) for k in eng.cache.keys()} == {(2, 64)}
-    assert eng.telemetry.total("padded") == 1  # tail padded 1 -> 2
 
 
 def test_vision_engine_quantized_serve(smoke_params, tmp_autotune_cache):

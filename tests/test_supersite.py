@@ -18,6 +18,8 @@ The contracts under test (ISSUE 10 / ROADMAP item 2):
     survivors regroup or run per-site, the key does not fall straight
     to the reference interpreter.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -56,6 +58,15 @@ def _groups(plan):
     return {g.name: tuple(g.members) for g in plan.groups.values()}
 
 
+def _per_site(plan):
+    """The plan with its super-site groups taken out: every member
+    launches its own per-site kernel, the baseline the chain parity
+    tests compare against."""
+    return dataclasses.replace(plan, groups={}, decisions={
+        n: dataclasses.replace(d, group="")
+        for n, d in plan.decisions.items()})
+
+
 # ---------------------------------------------------------------------------
 # SuperSite validation
 # ---------------------------------------------------------------------------
@@ -84,9 +95,7 @@ def test_grouping_pass_forms_expected_chains(params, tmp_autotune_cache):
             "stem.ss0": ("stem.ds0", "stem.ds1"),
             "S1.ss0": ("S1.mb0", "S1.mb1"),
             "S2.ss0": ("S2.mb0", "S2.mb1", "S2.mb2")}
-        flat = plan_program(program, tree, autotune=False,
-                            supersites=False)
-        assert not flat.groups
+        flat = _per_site(plan)
         # each chain of k members collapses k launches into 1
         saved = sum(len(g.members) - 1 for g in plan.groups.values())
         assert launch_counts(flat)["fused"] \
@@ -99,7 +108,7 @@ def test_supersite_chain_parity_fp(params, tmp_autotune_cache):
     program = lower(CFG, batch=batch, image_size=64)
     x = _images(batch)
     grouped = plan_program(program, params, autotune=False)
-    flat = plan_program(program, params, autotune=False, supersites=False)
+    flat = _per_site(grouped)
     assert grouped.groups and not flat.groups
     ref = execute(program, params, x)
     y_grouped = execute(program, params, x, plan=grouped)
@@ -118,8 +127,7 @@ def test_supersite_chain_parity_int8_bit_exact(params, tmp_autotune_cache):
         program = lower(CFG, batch=batch, image_size=64)
         x = _images(batch)
         grouped = plan_program(program, qparams, autotune=False)
-        flat = plan_program(program, qparams, autotune=False,
-                            supersites=False)
+        flat = _per_site(grouped)
         assert all(g.precision == "int8" for g in grouped.groups.values())
         y_grouped = execute(program, qparams, x, plan=grouped)
         y_flat = execute(program, qparams, x, plan=flat)
@@ -191,8 +199,7 @@ def test_plan_report_counts_group_weights_once(params, tmp_autotune_cache):
     deliver ZERO activation bytes."""
     program = lower(CFG, batch=1, image_size=64)
     plan = plan_program(program, params, autotune=False)
-    flat_plan = plan_program(program, params, autotune=False,
-                             supersites=False)
+    flat_plan = _per_site(plan)
     rep, flat_rep = plan_report(plan), plan_report(flat_plan)
     assert sum(r["hbm_w"] for r in rep) \
         == sum(r["hbm_w"] for r in flat_rep)
